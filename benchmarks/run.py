@@ -398,6 +398,9 @@ def emit_wire_bench(rounds: int = 3, clients: int = 6,
 
 def main() -> None:
     from repro.data.ingest import registry as datasets
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")

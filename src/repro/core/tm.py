@@ -192,12 +192,18 @@ def _feedback_one_class(ta: jnp.ndarray, w: jnp.ndarray, lits: jnp.ndarray,
     negative-polarity Type II; for the sampled negative class it is the
     mirror image.
     """
+    from repro.kernels import draws as kdraws
     m, L = ta.shape
     k_act, k_s1, k_s2 = jax.random.split(key, 3)
 
+    # clause resampling: active w.p. (T ∓ v) / 2T.  Compared in the
+    # integer domain against the host-built table of that f32 quotient
+    # (same draws as bernoulli), so no backend divides on the device and
+    # the Pallas epoch kernel makes the identical choice
     v = jnp.clip(votes, -cfg.T, cfg.T)
-    p_act = ((cfg.T - v) if is_target else (cfg.T + v)) / (2.0 * cfg.T)
-    active = jax.random.bernoulli(k_act, p_act, (m,))  # clause resampling
+    thr = jnp.asarray(kdraws.activation_thresholds(cfg.T))[
+        (cfg.T - v) if is_target else (cfg.T + v)]
+    active = kdraws.act_bits(k_act, (m,)) < thr
 
     pol = clause_polarity(cfg)  # (m,)
     pos = pol > 0
@@ -278,14 +284,14 @@ def train_epoch(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
         from repro.kernels import draws as kdraws
         from repro.kernels import ops as kops
         p_inc, p_dec = _feedback_probs(cfg)
-        offs, u_act, coin = kdraws.epoch_draws(
+        offs, act, coin = kdraws.epoch_draws(
             key, xs.shape[0], cfg.n_clauses, cfg.n_literals,
             cfg.n_classes, p_inc, p_dec)
         ys32 = ys.astype(jnp.int32)
         cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes], axis=-1)
         ta, w = kops.train_epoch_fused(
             params.ta_state[None], params.weights[None],
-            literals(xs)[None], cls2[None], u_act[None], coin[None],
+            literals(xs)[None], cls2[None], act[None], coin[None],
             n_states=cfg.n_states, T=cfg.T)
         return TMParams(ta_state=ta[0], weights=w[0])
 
@@ -338,12 +344,12 @@ def train_batched(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
 
     def epoch_body(carry, ek):
         ta, w = carry
-        offs, u_act, coin = jax.vmap(
+        offs, act, coin = jax.vmap(
             lambda k: kdraws.epoch_draws(k, n_samples, cfg.n_clauses,
                                          cfg.n_literals, cfg.n_classes,
                                          p_inc, p_dec))(ek)
         cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes], axis=-1)
-        ta, w = kops.train_epoch_fused(ta, w, lits, cls2, u_act, coin,
+        ta, w = kops.train_epoch_fused(ta, w, lits, cls2, act, coin,
                                        n_states=cfg.n_states, T=cfg.T)
         return (ta, w), None
 

@@ -64,6 +64,7 @@ def build_manifest(config: Any = None, seed: int | None = None,
         "devices": {
             "count": len(devices),
             "platform": devices[0].platform if devices else None,
+            "device_kind": devices[0].device_kind if devices else None,
         },
         "git_sha": git_sha(pathlib.Path(__file__).resolve().parents[4]),
         "jax_version": jax.__version__,

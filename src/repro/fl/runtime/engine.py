@@ -268,6 +268,17 @@ class Engine:
         # are untouched — the flag is a no-op for the MLP baselines.
         if cfg.tm_backend == "pallas" and \
                 getattr(strategy, "tm_cfg", None) is not None:
+            from repro.kernels import train_epoch
+            tc = strategy.tm_cfg
+            need = train_epoch.vmem_bytes(tc.n_classes, tc.n_clauses,
+                                          tc.n_literals)
+            if need > train_epoch.VMEM_BUDGET:
+                raise ValueError(
+                    f"tm_backend='pallas' keeps one client's TA bank in "
+                    f"VMEM: C={tc.n_classes}, m={tc.n_clauses}, "
+                    f"L={tc.n_literals} needs {need} B, over the "
+                    f"{train_epoch.VMEM_BUDGET} B budget — run "
+                    f"tm_backend='ref' or fewer clauses")
             strategy = dataclasses.replace(
                 strategy, tm_cfg=dataclasses.replace(
                     strategy.tm_cfg, use_kernel=True))

@@ -93,7 +93,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import clustering
@@ -319,13 +318,13 @@ def build_sharded_round(strategy, mesh, axis_name: str = "clients",
     n_valid = None if n_clients is None else n_clients * strategy.j_slots
     body = _sync_round_body(strategy, axis_name, collective, n_valid)
     spec = P(axis_name)
-    # check_rep=False: the 0.4.x replication checker cannot infer that
+    # check_vma=False: the varying-axes checker cannot infer that
     # all_gather→slice→einsum yields a replicated value (it does, by
     # construction — every shard reduces the same gathered array)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, P(), spec, spec, spec),
-        out_specs=(spec, P(), P(), spec, spec, spec), check_rep=False)
+        out_specs=(spec, P(), P(), spec, spec, spec), check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -339,9 +338,10 @@ def _train_program(strategy, mesh, axis, sub_cs, server, sub_data, keys):
     def body(cs, srv, d, k):
         return _client_step_block(strategy)(cs, srv, d, k)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(spec, P(), spec, spec),
-                     out_specs=(spec, spec))(sub_cs, server, sub_data, keys)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, P(), spec, spec),
+                         out_specs=(spec, spec))(sub_cs, server, sub_data,
+                                                 keys)
 
 
 @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
@@ -355,10 +355,10 @@ def _agg_program(n_slots, dim, mesh, axis, collective, n_valid,
             dec_.reshape(-1, dim), masked.reshape(-1), n_slots, axis,
             collective, n_valid)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(spec, spec, spec),
-                     out_specs=(P(), P()),
-                     check_rep=False)(dec, slots, arrive)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, spec, spec),
+                         out_specs=(P(), P()),
+                         check_vma=False)(dec, slots, arrive)
 
 
 @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
@@ -386,9 +386,9 @@ def _assign_program(strategy, mesh, axis, k, k_padded,
         i = jax.lax.axis_index(axis)
         return jax.lax.dynamic_slice_in_dim(new, i * blk, blk)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), spec, spec, spec),
-                     out_specs=spec, check_rep=False)(
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), spec, spec, spec),
+                         out_specs=spec, check_vma=False)(
         server, dec, slots, arrive)
 
 
@@ -400,16 +400,16 @@ def _apply_program(strategy, mesh, axis, new_sub, applied, rx_server,
     def body(ns, ap, srv, old, rc):
         return _broadcast_apply_merge(strategy, ns, ap, srv, old, rc)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(spec, spec, P(), spec, spec),
-                     out_specs=spec)(new_sub, applied, rx_server, old_sub,
-                                     recv)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, spec, P(), spec, spec),
+                         out_specs=spec)(new_sub, applied, rx_server,
+                                         old_sub, recv)
 
 
 @partial(jax.jit, static_argnums=(0, 1, 2))
 def _eval_program(strategy, mesh, axis, cs, x_test, y_test):
     spec = P(axis)
-    return shard_map(
+    return jax.shard_map(
         _evaluate_block(strategy),
         mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=spec)(cs, x_test, y_test)
@@ -420,10 +420,10 @@ def _fused_program(strategy, mesh, axis, collective, n_valid,
                    sub_cs, server, sub_data, keys, arrive):
     spec = P(axis)
     body = _sync_round_body(strategy, axis, collective, n_valid)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(spec, P(), spec, spec, spec),
-                     out_specs=(spec, P(), P(), spec, spec, spec),
-                     check_rep=False)(
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, P(), spec, spec, spec),
+                         out_specs=(spec, P(), P(), spec, spec, spec),
+                         check_vma=False)(
         sub_cs, server, sub_data, keys, arrive)
 
 
@@ -577,12 +577,12 @@ def build_sharded_async_update(strategy, mesh, axis_name: str = "clients",
             buf, round_idx, n_slots, min_uploads, prev, mean_fn)
         return server, counts, n_agg, n_buf, evicted, buf
 
-    # check_rep=False: every shard computes the same replicated insert /
-    # gate from the same gathered uploads (the 0.4.x checker cannot see
-    # through all_gather→scan→einsum to infer that)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), spec, P(), P()),
-                     out_specs=P(), check_rep=False)
+    # check_vma=False: every shard computes the same replicated insert /
+    # gate from the same gathered uploads (the varying-axes checker
+    # cannot see through all_gather→scan→einsum to infer that)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), spec, P(), P()),
+                         out_specs=P(), check_vma=False)
 
 
 @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
@@ -607,8 +607,8 @@ class ShardMapExecutor:
         if collective not in COLLECTIVES:
             raise ValueError(f"unknown collective {collective!r}")
         if mesh is None:
-            from repro.sharding import compat
-            mesh = compat.make_mesh((len(jax.devices()),), (axis,))
+            from repro.launch.mesh import make_clients_mesh
+            mesh = make_clients_mesh(axis=axis)
         if axis not in mesh.shape:
             raise ValueError(f"mesh has no {axis!r} axis: {mesh}")
         self.mesh = mesh

@@ -76,6 +76,9 @@ class SocketTransport:
         src_root = str(pathlib.Path(__file__).resolve().parents[3])
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (src_root, env.get("PYTHONPATH")) if p)
+        # workers stand in for client devices: the server process holds
+        # the accelerator, and a chip belongs to one process at a time
+        env["JAX_PLATFORMS"] = "cpu"
         # -c instead of -m: the package __init__ imports .worker, so
         # runpy would warn about re-executing an already-imported module
         entry = "from repro.fl.transport.worker import main; main()"

@@ -4,8 +4,8 @@ The TM hot-spot is the conjunctive clause evaluation: for every sample `b`
 and clause `j`, count how many *included* literals are violated
 (`included & literal==0`) — the clause fires iff the count is zero
 (paper §4.1).  On TPU this is a boolean-matmul-shaped reduction that maps
-straight onto the MXU: we cast the {0,1} operands to f32 and accumulate the
-violation counts as an f32 dot (exact for counts < 2^24).
+straight onto the MXU: the {0,1} operands are exact in bf16/f32 and the
+violation counts accumulate exactly in f32 (counts < 2^24).
 
 Two kernels:
 
@@ -15,26 +15,23 @@ Two kernels:
   include tile resident in VMEM per grid step; `bt, ct, lt` default to
   MXU/VPU-aligned multiples of (8, 128).
 
-* :func:`fused_votes_pallas` — fuses clause eval with the Eq.-1 weighted
-  class vote: grid is `(B tiles, classes)`; each step loads the whole
-  `(m, L)` clause bank of one class into VMEM (m·L ≤ a few hundred KB for
-  paper-scale machines), computes fired clauses, and reduces
-  `votes = fired @ (polarity·weight)` without materializing the `(B, C, m)`
-  clause tensor in HBM.
+* :func:`fused_votes_batched_pallas` — clause eval fused with the Eq.-1
+  weighted class vote, for a stack of N clients in one launch (the
+  engine's evaluate/confidence paths and the serving plane; a single
+  client is N = 1).  Grid `(client, class)`: each step holds one client's
+  `(B, L)` literal plane and one class's `(m, L)` include bank in VMEM,
+  counts violations on the MXU, and sums `fired · (polarity·weight)` over
+  the class's clauses in int32 — exact for any weight, so the vote never
+  passes through a rounded float product.  The class axis of the output
+  is lane-padded to 128: the `(B, 128)` output block stays resident
+  across the class steps and step `c` writes lane `c`, which keeps every
+  block's last two dims (8, 128)-aligned as the TPU lowering requires.
+  The predict-mode empty-clause rule is folded into the weight plane
+  (`wpol · nonempty`).
 
-* :func:`fused_votes_batched_pallas` — the same fused vote with a leading
-  client axis, one launch for a whole federated round (`grid=(1,)`,
-  whole-array blocks).  The per-class reduction is a `(CM, C)` selector
-  matmul so the kernel body needs no reshape; the predict-mode
-  empty-clause rule is folded into the weight plane (`wpol · nonempty`,
-  exact in f32).  This is what the engine's `tm_backend="pallas"`
-  evaluate/confidence paths call — batching *inside* the kernel instead
-  of vmapping `fused_votes_pallas` (vmap of a `pallas_call` prepends a
-  grid axis, serializing clients).
-
-On this CPU-only container the kernels run under ``interpret=True``
-(exercised by the test suite against :mod:`repro.kernels.ref`); on real
-TPUs the same `pallas_call`s compile to Mosaic.
+Interpret mode (CPU) or Mosaic (TPU) is chosen by the caller, see
+:mod:`repro.kernels.ops`; the test suite pins both kernels against
+:mod:`repro.kernels.ref`.
 """
 from __future__ import annotations
 
@@ -76,7 +73,7 @@ def _clause_kernel(nlit_ref, inc_ref, out_ref):
                    static_argnames=("predict", "bt", "ct", "lt", "interpret"))
 def clause_outputs_pallas(include: jnp.ndarray, lits: jnp.ndarray,
                           predict: bool = False, bt: int = 8, ct: int = 128,
-                          lt: int = 128, interpret: bool = True) -> jnp.ndarray:
+                          lt: int = 128, *, interpret: bool) -> jnp.ndarray:
     """include: (CM, L) {0,1}; lits: (B, L) {0,1} → fired (B, CM) int32."""
     CM, L = include.shape
     B = lits.shape[0]
@@ -108,110 +105,66 @@ def clause_outputs_pallas(include: jnp.ndarray, lits: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Kernel 2: fused clause eval + weighted class vote (Eq. 1)
+# Kernel 2: client-batched fused clause eval + weighted class vote (Eq. 1)
 # ---------------------------------------------------------------------------
 
-def _votes_kernel(nlit_ref, inc_ref, wpol_ref, nonempty_ref, out_ref):
-    nlit = nlit_ref[...].astype(jnp.float32)          # (bt, L)
-    inc = inc_ref[0].astype(jnp.float32)              # (m, L)
-    viol = jax.lax.dot_general(                        # (bt, m)
-        nlit, inc, (((1,), (1,)), ((), ())),
+_LANES = 128
+
+
+def _votes_kernel(nlit_ref, inc_ref, wp_ref, out_ref):
+    """Grid (client n, class c): out[n, :, c] = Σ_j fired[:, j]·wp[c, j]."""
+    c = pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    viol = jax.lax.dot_general(                        # (Bp, mp)
+        nlit_ref[0], inc_ref[0, 0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    fired = (viol == 0.0).astype(jnp.float32)
-    fired = fired * nonempty_ref[0].astype(jnp.float32)  # (bt, m)·(1, m)
-    wpol = wpol_ref[0].astype(jnp.float32)            # (1, m)
-    out_ref[...] = jax.lax.dot_general(                # (bt, 1)
-        fired, wpol, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("predict", "bt", "interpret"))
-def fused_votes_pallas(include: jnp.ndarray, lits: jnp.ndarray,
-                       wpol: jnp.ndarray, predict: bool = True,
-                       bt: int = 8, interpret: bool = True) -> jnp.ndarray:
-    """include: (C, m, L); lits: (B, L); wpol: (C, m) → votes (B, C) int32."""
-    C, m, L = include.shape
-    B = lits.shape[0]
-    Bp, mp, Lp = _ceil_to(B, bt), _ceil_to(m, 128), _ceil_to(L, 128)
-
-    nlit = _pad2((1 - lits).astype(jnp.int8), Bp, Lp)
-    inc = jnp.pad(include.astype(jnp.int8),
-                  ((0, 0), (0, mp - m), (0, Lp - L)))
-    # padded clauses have empty includes → viol 0 → would fire: kill them via
-    # the nonempty mask (also implements the predict-mode empty-clause rule).
-    if predict:
-        ne = (include.sum(-1) > 0)
-    else:
-        ne = jnp.ones((C, m), dtype=bool)
-    ne = jnp.pad(ne.astype(jnp.int8), ((0, 0), (0, mp - m)))[:, None, :]
-    wp = jnp.pad(wpol.astype(jnp.float32), ((0, 0), (0, mp - m)))[:, None, :]
-
-    votes = pl.pallas_call(
-        _votes_kernel,
-        grid=(Bp // bt, C),
-        in_specs=[
-            pl.BlockSpec((bt, Lp), lambda b, c: (b, 0)),
-            pl.BlockSpec((1, mp, Lp), lambda b, c: (c, 0, 0)),
-            pl.BlockSpec((1, 1, mp), lambda b, c: (c, 0, 0)),
-            pl.BlockSpec((1, 1, mp), lambda b, c: (c, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bt, 1), lambda b, c: (b, c)),
-        out_shape=jax.ShapeDtypeStruct((Bp, C), jnp.float32),
-        interpret=interpret,
-        name="tm_fused_votes",
-    )(nlit, inc, wp, ne)
-    return votes[:B].astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# Kernel 3: client-batched fused votes (one launch per federated round)
-# ---------------------------------------------------------------------------
-
-def _votes_batched_kernel(nlit_ref, inc_ref, wp_ref, sel_ref, out_ref):
-    nlit = nlit_ref[...].astype(jnp.float32)          # (N, B, L)
-    inc = inc_ref[...].astype(jnp.float32)            # (N, CM, L)
-    viol = jax.lax.dot_general(                        # (N, B, CM)
-        nlit, inc, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    fired = (viol == 0.0).astype(jnp.float32)
-    contrib = fired * wp_ref[...][:, None, :]          # wp: (N, CM)
-    out_ref[...] = jax.lax.dot_general(                # (N, B, C)
-        contrib, sel_ref[...], (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    contrib = jnp.where(viol == 0.0, wp_ref[0, 0], 0)  # wp: (1, mp) int32
+    vote = jnp.sum(contrib, axis=1, keepdims=True)     # (Bp, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 1)
+    out_ref[0] = jnp.where(lane == c, vote, out_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("predict", "interpret"))
 def fused_votes_batched_pallas(include: jnp.ndarray, lits: jnp.ndarray,
-                               wpol: jnp.ndarray, predict: bool = True,
-                               interpret: bool = True) -> jnp.ndarray:
+                               wpol: jnp.ndarray, predict: bool = True, *,
+                               interpret: bool) -> jnp.ndarray:
     """include: (N,C,m,L); lits: (N,B,L); wpol: (N,C,m) → votes (N,B,C) i32.
 
-    Empty clauses are killed by zeroing their weight instead of their
-    clause output — ``fired·(wpol·nonempty) == (fired·nonempty)·wpol``
-    exactly (small-int products are exact in f32), which keeps the kernel
-    a pair of dot_generals with no masking pass.
+    Unclipped Eq.-1 votes.  Empty clauses are killed by zeroing their
+    weight instead of their clause output — ``fired·(wpol·nonempty) ==
+    (fired·nonempty)·wpol`` exactly in int32.  Padded clauses (rows
+    m..mp) have empty includes and zero weight; padded literal columns
+    are 0 in the negated-literal plane, so they never count as
+    violations.
     """
     N, C, m, L = include.shape
     B = lits.shape[1]
-    CM = C * m
-    inc = include.reshape(N, CM, L).astype(jnp.int8)
-    nlit = (1 - lits).astype(jnp.int8)
-    wp = wpol.astype(jnp.float32)
+    Bp, mp, Lp = _ceil_to(B, 16), _ceil_to(m, _LANES), _ceil_to(L, _LANES)
+    inc = jnp.pad(include.astype(jnp.bfloat16),
+                  ((0, 0), (0, 0), (0, mp - m), (0, Lp - L)))
+    nlit = jnp.pad((1 - lits).astype(jnp.bfloat16),
+                   ((0, 0), (0, Bp - B), (0, Lp - L)))
+    wp = wpol.astype(jnp.int32)
     if predict:
-        wp = wp * (include.sum(-1) > 0).astype(jnp.float32)
-    wp = wp.reshape(N, CM)
-    sel = jax.nn.one_hot(jnp.arange(CM) // m, C, dtype=jnp.float32)
+        wp = wp * (include.sum(-1) > 0).astype(jnp.int32)
+    wp = jnp.pad(wp, ((0, 0), (0, 0), (0, mp - m)))[:, :, None, :]
+    Cp = _ceil_to(C, _LANES)
 
-    whole = [pl.BlockSpec(a.shape, lambda i, nd=a.ndim: (0,) * nd)
-             for a in (nlit, inc, wp, sel)]
     votes = pl.pallas_call(
-        _votes_batched_kernel,
-        grid=(1,),
-        in_specs=whole,
-        out_specs=pl.BlockSpec((N, B, C), lambda i: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, B, C), jnp.float32),
+        _votes_kernel,
+        grid=(N, C),
+        in_specs=[
+            pl.BlockSpec((1, Bp, Lp), lambda n, c: (n, 0, 0)),
+            pl.BlockSpec((1, 1, mp, Lp), lambda n, c: (n, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, mp), lambda n, c: (n, c, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, Bp, Cp), lambda n, c: (n, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, Bp, Cp), jnp.int32),
         interpret=interpret,
         name="tm_fused_votes_batched",
-    )(nlit, inc, wp, sel)
-    return votes.astype(jnp.int32)
+    )(nlit, inc, wp)
+    return votes[:, :B, :C]

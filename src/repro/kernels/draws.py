@@ -11,7 +11,7 @@ with exactly the reference key discipline:
   ``keys = split(epoch_key, n_samples)`` — the negative class is
   ``(y + randint(k_neg, 1, C)) % C``;
 * per feedback role (target ``k_t`` / negative ``k_n``):
-  ``k_act, k_s1, k_s2 = split(k, 3)`` — clause-activation uniforms from
+  ``k_act, k_s1, k_s2 = split(k, 3)`` — clause-activation draws from
   ``k_act``, the Type-I increment/decrement coin flips from ``k_s1`` /
   ``k_s2``.
 
@@ -28,8 +28,15 @@ bit-for-bit (both sides of the float compare are exact f32 values;
 f32 compare for the two (m, L) planes per role — the dominant draw
 volume — while staying bit-identical to the reference path.
 
-The activation uniforms stay f32: their compare threshold ``p_act`` is
-vote-dependent and computed inside the kernel.
+The clause-activation draws use the same trick.  Their probability
+``p_act = (T ∓ v) / 2T`` depends on the clipped vote ``v``, so it can only
+be formed inside the kernel; but the numerator ``T ∓ v`` is an integer in
+``[0, 2T]``, so :func:`activation_thresholds` tabulates the integer
+threshold of every possible f32 quotient once, on the host, with
+IEEE-rounded numpy division.  The reference trainer and the kernel both
+compare the 23-bit draw against that table — neither divides on the
+device, so the two agree bit for bit on any backend (a device's f32
+divide need not round like the host's).
 """
 from __future__ import annotations
 
@@ -48,16 +55,32 @@ def int_threshold(p: float) -> int:
     return math.ceil(float(np.float32(p)) * _MANTISSA)
 
 
+def act_bits(key: jax.Array, shape) -> jnp.ndarray:
+    """The 23-bit integer behind ``jax.random.uniform(key, shape)``."""
+    return (jax.random.bits(key, shape, jnp.uint32) >> 9).astype(jnp.int32)
+
+
+def activation_thresholds(T: int) -> np.ndarray:
+    """(2T+1,) int32: entry ``n`` is ``int_threshold(f32(n) / f32(2T))``.
+
+    ``act_bits(k) < table[T - v]`` is ``bernoulli(k, (T - v) / 2T)`` for
+    the target class, ``table[T + v]`` for the sampled negative.
+    """
+    n = np.arange(2 * T + 1, dtype=np.float32)
+    p = n / np.float32(2 * T)
+    return np.array([int_threshold(float(q)) for q in p], dtype=np.int32)
+
+
 def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
                 n_literals: int, n_classes: int,
                 p_inc: float, p_dec: float):
     """One epoch's randomness, reference key discipline (see module doc).
 
-    Returns ``(offsets, u_act, coin)``:
+    Returns ``(offsets, act, coin)``:
 
     * ``offsets`` (S,) int32 — negative-class offset in [1, C);
-    * ``u_act``   (S, 2, m) float32 — clause-activation uniforms, role
-      0 = target, 1 = negative;
+    * ``act``     (S, 2, m) int32 — clause-activation draws as 23-bit
+      integers (:func:`act_bits`), role 0 = target, 1 = negative;
     * ``coin``    (S, 2, m, L) int8 — bit 1: Type-I increment draw hit
       (``u < p_inc``), bit 2: decrement draw hit (``u < p_dec``).
     """
@@ -71,17 +94,16 @@ def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
 
         def role(kr):
             k_act, k_s1, k_s2 = jax.random.split(kr, 3)
-            ua = jax.random.uniform(k_act, (m,))
             h1 = jax.random.bits(k_s1, (m, L), jnp.uint32) >> 9
             h2 = jax.random.bits(k_s2, (m, L), jnp.uint32) >> 9
-            return ua, ((h1 < t_inc).astype(jnp.int8)
-                        + 2 * (h2 < t_dec).astype(jnp.int8))
+            return act_bits(k_act, (m,)), ((h1 < t_inc).astype(jnp.int8)
+                                          + 2 * (h2 < t_dec).astype(jnp.int8))
 
-        ua_t, c_t = role(k_t)
-        ua_n, c_n = role(k_n)
+        a_t, c_t = role(k_t)
+        a_n, c_n = role(k_n)
         off = jax.random.randint(k_neg, (), 1, n_classes)
-        return 0, (off.astype(jnp.int32), jnp.stack([ua_t, ua_n]),
+        return 0, (off.astype(jnp.int32), jnp.stack([a_t, a_n]),
                    jnp.stack([c_t, c_n]))
 
-    _, (offsets, u_act, coin) = jax.lax.scan(per_sample, 0, keys)
-    return offsets, u_act, coin
+    _, (offsets, act, coin) = jax.lax.scan(per_sample, 0, keys)
+    return offsets, act, coin

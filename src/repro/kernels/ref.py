@@ -2,8 +2,8 @@
 
 Each function here is the semantic reference for the identically-named
 kernel in :mod:`repro.kernels.clause_eval` / :mod:`repro.kernels.ta_update`.
-Tests sweep shapes/dtypes and assert the kernels (run in ``interpret=True``
-on this CPU container; compiled on real TPUs) match these bit-exactly.
+Tests sweep shapes/dtypes and assert the kernels (interpret mode on the
+CPU backend; compiled on TPUs) match these bit-exactly.
 """
 from __future__ import annotations
 

@@ -49,8 +49,8 @@ def ta_update_pallas(ta: jnp.ndarray, lit: jnp.ndarray, fired: jnp.ndarray,
                      type1: jnp.ndarray, type2: jnp.ndarray,
                      u_inc: jnp.ndarray, u_dec: jnp.ndarray,
                      p_inc: float, p_dec: float, n_states: int,
-                     mt: int = 256, lt: int = 512,
-                     interpret: bool = True) -> jnp.ndarray:
+                     mt: int = 256, lt: int = 512, *,
+                     interpret: bool) -> jnp.ndarray:
     """See :func:`repro.kernels.ref.ta_update_ref` for exact semantics."""
     m, L = ta.shape
     mt = min(mt, _ceil_to(m, 8))

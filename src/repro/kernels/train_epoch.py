@@ -3,31 +3,39 @@
 The reference ``tm.train_epoch`` scans samples on the host side of the
 kernel boundary: each scan step re-launches batch-1 clause evaluation
 and two TA updates, so the clause banks round-trip HBM every sample.
-This kernel inverts that — the whole parameter state (every client's
-``ta_state`` and ``weights``) is resident in VMEM for the full epoch,
-and the per-sample feedback loop runs *inside* the kernel body
-(``grid=(1,)``, whole-array blocks; the no-intermediate-HBM idiom).
+This kernel inverts that: a client's whole parameter state (its
+``(C, m, L)`` TA bank and ``(C, m)`` weights) is copied into VMEM
+scratch once, stays resident while the per-sample feedback loop runs
+over it, and is copied back once.
 
 Layout is client-batched: a leading ``N`` axis carries all clients of a
-federated round through one launch.  This is deliberately *not* a
-per-client kernel under ``jax.vmap`` — vmap of a ``pallas_call`` batches
-by prepending a grid axis, which serializes clients and re-slices blocks
-every grid step; one launch over the stacked clients is the fast shape
-on both CPU interpret mode and a TPU core.
+federated round through one launch, as a grid ``(client, sample)``.
+The sample axis is sequential (``arbitrary``): the TA bank and weights
+live in scratch across it, loaded by DMA at the client's first sample
+and stored at its last.  Per-sample inputs — the literal row, the two
+roles' activation draws and their ``(2, m, L)`` int8 coin planes — are
+ordinary blocks, so the pipeline streams them from HBM one sample ahead
+of the compute.  The per-(client, sample) class pair is scalar-prefetched
+into SMEM and indexes the resident bank directly (a dynamic index on its
+leading, untiled axis).
+
+VMEM holds one client's bank and weights, two samples' coin planes
+and one feedback step's ``(m, L)`` temporaries — 35 MiB at the paper's
+MNIST widths (C=10, m=300, L=1568).  :func:`vmem_bytes` computes it;
+:func:`repro.fl.runtime.engine.Engine` refuses ``tm_backend="pallas"``
+for a machine whose need exceeds :data:`VMEM_BUDGET`.
 
 Bit-parity with the reference scan (pinned in ``tests/test_tm.py`` and
 ``tests/test_fl_conformance.py``) holds because:
 
 * randomness is pre-generated outside with the reference key discipline
-  (:mod:`repro.kernels.draws`);
+  (:mod:`repro.kernels.draws`), and the clause-activation compare runs
+  against the same host-built integer threshold table on both paths;
 * class votes are per-class independent — ``votes[c]`` reads only class
   ``c``'s clauses/weights, and the negative class ``ȳ ≠ y`` — so
-  processing (sample, target-role) then (sample, negative-role) as two
-  loop iterations recomputes exactly the reference's pre-sample values;
-* count accumulation uses f32 ``dot_general`` on 0/1 operands: integer
-  values below 2²⁴ are exact in f32, so ``viol == 0.0`` and the vote
-  sums match the int32 einsum bit-for-bit (same contract as
-  ``clause_eval.py``).
+  processing (sample, target-role) then (sample, negative-role) in turn
+  recomputes exactly the reference's pre-sample values;
+* violation counts and votes are int32 sums: no float rounding anywhere.
 """
 from __future__ import annotations
 
@@ -36,87 +44,103 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import draws
+
+# Scoped-VMEM ceiling the kernel may request.  A v5e TensorCore has
+# 128 MiB of VMEM (jax.experimental.pallas.tpu.get_tpu_info); the rest is
+# left to the compiler's own buffers.
+VMEM_BUDGET = 96 * 1024 * 1024
+_LANES = 128
 
 
-def _epoch_kernel(ta_ref, w_ref, lits_ref, cls2_ref, uact_ref, coin_ref,
-                  ta_out, w_out, *, n_states: int, T: int, n_samples: int):
-    ta_all = ta_ref[...]          # (N, C, m, L) int32
-    w_all = w_ref[...]            # (N, C, m)    int32
-    lits = lits_ref[...]          # (N, S, L)    int32 0/1
-    cls2 = cls2_ref[...]          # (N, S, 2)    int32 — [target, negative]
-    uact = uact_ref[...]          # (N, S, 2, m) float32
-    coin = coin_ref[...]          # (N, S, 2, m, L) int8 — bit1 inc, bit2 dec
+def vmem_bytes(n_classes: int, n_clauses: int, n_literals: int) -> int:
+    """VMEM the epoch kernel holds for one client: the int32 TA bank, the
+    lane-padded weight column, two samples' double-buffered coin planes
+    and activation columns, and the (m, L) int32 temporaries of one
+    feedback step."""
+    C, m, L = n_classes, _ceil_to(n_clauses, 8), _ceil_to(n_literals, _LANES)
+    bank = 4 * C * m * L
+    weights = 4 * C * m * _LANES
+    stream = 2 * (2 * m * L + 2 * 4 * m * _LANES + 4 * 8 * L)
+    temps = 6 * 4 * m * L
+    return bank + weights + stream + temps
 
-    N, C, m, L = ta_all.shape
-    rows = jnp.arange(N)
-    pol = jnp.where(jnp.arange(m) % 2 == 0, 1, -1)
-    pos = pol > 0
-    polf = pol.astype(jnp.float32)
-    tf = jnp.float32(T)
 
-    def body(i, carry):
-        ta_all, w_all = carry
-        s, role = i // 2, i % 2
-        is_target = role == 0
+def _ceil_to(x: int, mult: int) -> int:
+    return (x + mult - 1) // mult * mult
 
-        cls = jax.lax.dynamic_slice(cls2, (0, s, role), (N, 1, 1))[:, 0, 0]
-        lit = jax.lax.dynamic_slice(lits, (0, s, 0), (N, 1, L))[:, 0]
-        ua = jax.lax.dynamic_slice(uact, (0, s, role, 0), (N, 1, 1, m))[:, 0, 0]
-        cn = jax.lax.dynamic_slice(
-            coin, (0, s, role, 0, 0), (N, 1, 1, m, L))[:, 0, 0]
 
-        ta = ta_all[rows, cls]    # (N, m, L)
-        w = w_all[rows, cls]      # (N, m)
+def _epoch_kernel(cls_ref, ta_hbm, w_hbm, lits_ref, act_ref, coin_ref,
+                  thr_ref, ta_out, w_out, bank, wbank, sem, *,
+                  n_states: int, T: int, n_samples: int, n_clauses: int):
+    n, s = pl.program_id(0), pl.program_id(1)
 
-        # clause outputs on this sample's literals (learning mode: empty
-        # clauses fire) — violations counted in exact-f32 dot_general
-        inc = (ta > n_states).astype(jnp.float32)
-        nlit = (1 - lit).astype(jnp.float32)
-        viol = jax.lax.dot_general(
-            inc, nlit, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        fired = viol == 0.0       # (N, m)
+    @pl.when(s == 0)
+    def _load():
+        cp_ta = pltpu.make_async_copy(ta_hbm.at[n], bank, sem.at[0])
+        cp_w = pltpu.make_async_copy(w_hbm.at[n], wbank, sem.at[1])
+        cp_ta.start()
+        cp_w.start()
+        cp_ta.wait()
+        cp_w.wait()
 
-        votes = jnp.sum(
-            fired.astype(jnp.float32) * polf[None] * w.astype(jnp.float32),
-            axis=1)
-        v = jnp.clip(votes, -tf, tf)
-        p_act = jnp.where(is_target, tf - v, tf + v) / (2.0 * tf)
-        active = ua < p_act[:, None]                     # (N, m)
+    m = bank.shape[1]
+    lit = lits_ref[0, 0] != 0                          # (1, L)
+    row = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+    pos = row % 2 == 0                                 # positive polarity
+    real = row < n_clauses                             # padded rows stay put
+    pol = jnp.where(pos, 1, -1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, thr_ref.shape, 1)
 
+    for role in (0, 1):                                # target, negative
+        cls = cls_ref[(n * n_samples + s) * 2 + role]
+        ta = bank[cls]                                 # (m, L)
+        w = wbank[cls][:, :1]                          # (m, 1)
+        inc = ta > n_states
+        viol = jnp.sum(jnp.where(inc & ~lit, 1, 0), axis=1, keepdims=True)
+        fired = viol == 0                              # (m, 1)
+        votes = jnp.sum(jnp.where(fired, pol * w, 0), axis=0,
+                        keepdims=True)                 # (1, 1)
+        v = jnp.clip(votes, -T, T)
+        idx = T - v if role == 0 else T + v
+        thr = jnp.sum(jnp.where(lane == idx, thr_ref[...], 0), axis=1,
+                      keepdims=True)                   # (1, 1)
+        active = (act_ref[0, 0, role] < thr) & real    # (m, 1)
         # Type I goes to same-polarity clauses on the target, opposite on
         # the negative; Type II is the complement
-        t1 = jnp.where(is_target, pos[None], ~pos[None]) & active
-        t2 = jnp.where(is_target, ~pos[None], pos[None]) & active
+        t1 = (pos if role == 0 else ~pos) & active
+        t2 = (~pos if role == 0 else pos) & active
+        t1f, t2f = t1 & fired, t2 & fired
 
-        litb = (lit != 0)[:, None, :]                    # (N, 1, L)
-        fb = fired[:, :, None]                           # (N, m, 1)
-        up1 = t1[:, :, None] & fb & litb & ((cn & 1) == 1)
-        down1 = t1[:, :, None] & ((fb & ~litb) | ~fb) & ((cn & 2) == 2)
-        up2 = t2[:, :, None] & fb & ~litb & (ta <= n_states)
+        cn = coin_ref[0, 0, role].astype(jnp.int32)    # (m, L)
+        up1 = t1f & lit & ((cn & 1) != 0)
+        down1 = t1 & ~(fired & lit) & ((cn & 2) != 0)
+        up2 = t2f & ~lit & ~inc
         delta = (up1.astype(jnp.int32) - down1.astype(jnp.int32)
                  + up2.astype(jnp.int32))
-        ta_all = ta_all.at[rows, cls].set(
-            jnp.clip(ta + delta, 1, 2 * n_states))
+        bank[cls] = jnp.clip(ta + delta, 1, 2 * n_states)
+        wbank[cls] = jnp.broadcast_to(jnp.maximum(
+            w + t1f.astype(jnp.int32) - t2f.astype(jnp.int32), 0),
+            wbank.shape[1:])
 
-        winc = (t1 & fired).astype(jnp.int32)
-        wdec = (t2 & fired).astype(jnp.int32)
-        w_all = w_all.at[rows, cls].set(jnp.maximum(w + winc - wdec, 0))
-        return ta_all, w_all
-
-    ta_all, w_all = jax.lax.fori_loop(0, 2 * n_samples, body,
-                                      (ta_all, w_all))
-    ta_out[...] = ta_all
-    w_out[...] = w_all
+    @pl.when(s == n_samples - 1)
+    def _store():
+        cp_ta = pltpu.make_async_copy(bank, ta_out.at[n], sem.at[0])
+        cp_w = pltpu.make_async_copy(wbank, w_out.at[n], sem.at[1])
+        cp_ta.start()
+        cp_w.start()
+        cp_ta.wait()
+        cp_w.wait()
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_states", "T", "interpret"))
 def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
                        lits: jax.Array, cls2: jax.Array,
-                       u_act: jax.Array, coin: jax.Array,
-                       *, n_states: int, T: int,
-                       interpret: bool = True):
+                       act: jax.Array, coin: jax.Array,
+                       *, n_states: int, T: int, interpret: bool):
     """One TM epoch over all clients in a single kernel launch.
 
     Args:
@@ -124,28 +148,66 @@ def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
       weights:  (N, C, m) int32 — per-client clause weights.
       lits:     (N, S, L) int32 0/1 — per-client literal planes.
       cls2:     (N, S, 2) int32 — per (client, sample): [target, negative].
-      u_act:    (N, S, 2, m) float32 — activation uniforms per role.
+      act:      (N, S, 2, m) int32 — 23-bit activation draws per role.
       coin:     (N, S, 2, m, L) int8 — pre-compared Type-I coin flips.
 
     Returns ``(ta_state, weights)`` after the sample-sequential epoch,
     bit-identical to the reference ``tm.train_epoch`` per client.
     """
-    n_samples = lits.shape[1]
-    whole = [pl.BlockSpec(a.shape, lambda i, nd=a.ndim: (0,) * nd)
-             for a in (ta_state, weights, lits, cls2, u_act, coin)]
-    out_specs = [pl.BlockSpec(ta_state.shape,
-                              lambda i, nd=ta_state.ndim: (0,) * nd),
-                 pl.BlockSpec(weights.shape,
-                              lambda i, nd=weights.ndim: (0,) * nd)]
+    N, C, m0, L0 = ta_state.shape
+    S = lits.shape[1]
+    # Mosaic slices VMEM only along (8, 128) tiles: pad clauses to 8 and
+    # literals to 128.  Padded clauses are never activated (the kernel
+    # masks them); padded literals are excluded (state 1) and read as
+    # true, so no feedback reaches them.
+    m, L = _ceil_to(m0, 8), _ceil_to(L0, _LANES)
+    pad_m, pad_l = m - m0, L - L0
+    ta_p = jnp.pad(ta_state.astype(jnp.int32),
+                   ((0, 0), (0, 0), (0, pad_m), (0, pad_l)),
+                   constant_values=1)
+    # weights travel as lane-replicated (m, 128) columns: a DMA moves
+    # whole (8, 128) tiles, so a width-1 lane slice cannot be copied
+    w_p = jnp.broadcast_to(
+        jnp.pad(weights.astype(jnp.int32),
+                ((0, 0), (0, 0), (0, pad_m)))[..., None],
+        (N, C, m, _LANES))
+    lits_p = jnp.pad(lits, ((0, 0), (0, 0), (0, pad_l)),
+                     constant_values=1)[:, :, None, :]
+    act_p = jnp.pad(act, ((0, 0), (0, 0), (0, 0), (0, pad_m)))[..., None]
+    coin_p = jnp.pad(coin, ((0, 0), (0, 0), (0, 0), (0, pad_m), (0, pad_l)))
+    table = draws.activation_thresholds(T)
+    thr = jnp.zeros((1, _ceil_to(table.size, _LANES)), jnp.int32)
+    thr = thr.at[0, :table.size].set(table)
     kernel = functools.partial(_epoch_kernel, n_states=n_states, T=T,
-                               n_samples=n_samples)
-    return pl.pallas_call(
+                               n_samples=S, n_clauses=m0)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(N, S),
+        in_specs=[
+            hbm,
+            hbm,
+            pl.BlockSpec((1, 1, 1, L), lambda n, s, c: (n, s, 0, 0)),
+            pl.BlockSpec((1, 1, 2, m, 1), lambda n, s, c: (n, s, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 2, m, L), lambda n, s, c: (n, s, 0, 0, 0)),
+            pl.BlockSpec(thr.shape, lambda n, s, c: (0, 0)),
+        ],
+        out_specs=[hbm, hbm],
+        scratch_shapes=[pltpu.VMEM((C, m, L), jnp.int32),
+                        pltpu.VMEM((C, m, _LANES), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    need = vmem_bytes(C, m, L)
+    ta, w = pl.pallas_call(
         kernel,
-        grid=(1,),
-        in_specs=whole,
-        out_specs=out_specs,
-        out_shape=[jax.ShapeDtypeStruct(ta_state.shape, jnp.int32),
-                   jax.ShapeDtypeStruct(weights.shape, jnp.int32)],
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(ta_p.shape, jnp.int32),
+                   jax.ShapeDtypeStruct(w_p.shape, jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=min(VMEM_BUDGET, max(need, 32 << 20))),
         interpret=interpret,
         name="tm_train_epoch_fused",
-    )(ta_state, weights, lits, cls2, u_act, coin)
+    )(cls2.reshape(-1).astype(jnp.int32), ta_p, w_p, lits_p, act_p, coin_p,
+      thr)
+    return ta[:, :, :m0, :L0], w[:, :, :m0, 0]

@@ -25,10 +25,8 @@ import jax           # noqa: E402
 
 from repro.configs import registry                      # noqa: E402
 from repro.launch import hlo_analysis, steps            # noqa: E402
-from repro.sharding import compat                        # noqa: E402
-from repro.launch.mesh import (HBM_BW, ICI_BW,          # noqa: E402
-                               PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh       # noqa: E402
+from repro.launch.peaks import V5E, peaks               # noqa: E402
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts"
 
@@ -48,7 +46,7 @@ def dryrun(arch: str, shape_name: str, multi_pod: bool = False,
     t0 = time.time()
     ins = steps.input_specs(cfg, shape, mesh, opt_cfg)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = steps.make_train_step(cfg, opt_cfg)
             lowered = jax.jit(step).lower(ins["params"], ins["opt_state"],
@@ -80,9 +78,10 @@ def dryrun(arch: str, shape_name: str, multi_pod: bool = False,
         tokens = shape.global_batch           # one token per sequence
         model_flops = 2.0 * n_active * tokens
 
+    chip = peaks(V5E)
     rf = hlo_analysis.roofline(
-        cost, coll, peak_flops=PEAK_FLOPS_BF16, hbm_bw=HBM_BW,
-        ici_bw=ICI_BW, model_flops=model_flops, chips=chips,
+        cost, coll, peak_flops=chip.bf16_flops, hbm_bw=chip.hbm_bw,
+        ici_bw=chip.ici_bw_per_link, model_flops=model_flops, chips=chips,
         arg_bytes=mem.argument_size_in_bytes)
 
     result = {

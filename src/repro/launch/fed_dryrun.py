@@ -30,8 +30,8 @@ import jax       # noqa: E402
 
 from repro.core import federation, tm                     # noqa: E402
 from repro.launch import fed_train, hlo_analysis          # noqa: E402
-from repro.launch.mesh import ICI_BW, make_production_mesh  # noqa: E402
-from repro.sharding import compat  # noqa: E402
+from repro.launch.mesh import make_production_mesh       # noqa: E402
+from repro.launch.peaks import V5E, peaks                # noqa: E402
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts"
 
@@ -129,7 +129,7 @@ def run(multi_pod: bool = False, n_clients: int = 256,
     out = {"mesh": "2x16x16" if multi_pod else "16x16",
            "n_clients": n_clients, "clauses": clauses,
            "buffer_capacity": buffer_capacity}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name, build, args in (
             ("tpfl", engine_round, (params, cw, data, keys, arrive)),
             ("tpfl_async", async_update, (buf, up, round_idx, prev)),
@@ -142,7 +142,7 @@ def run(multi_pod: bool = False, n_clients: int = 256,
             total = sum(coll.values())
             out[name] = {
                 "collective_bytes_per_device": total,
-                "collective_s": total / ICI_BW,
+                "collective_s": total / peaks(V5E).ici_bw_per_link,
                 "breakdown": coll,
                 "compile_s": round(time.time() - t0, 1),
             }

@@ -74,7 +74,10 @@ def main(argv: list[str] | None = None) -> dict:
     from repro.fl.runtime import (CodecConfig, Engine, RuntimeConfig,
                                   checkpointing)
     from repro.fl.serve import ModelRegistry, ServeTelemetry, ServingPlane
+    from repro.launch import compile_cache
     from repro.launch.fed_train import STRATEGY_CHOICES, build_scenario
+
+    compile_cache.configure()
 
     ap = argparse.ArgumentParser(
         description="Federated serving plane: personalized inference "

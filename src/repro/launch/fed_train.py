@@ -49,6 +49,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import clustering, federation, tm
 from repro.data.partition import ClientData
@@ -274,6 +275,9 @@ def main(argv: list[str] | None = None) -> dict:
     from repro.data.ingest import registry as datasets
     from repro.fl.runtime import (CodecConfig, Engine, RuntimeConfig,
                                   SchedulerConfig, checkpointing)
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
 
     ap = argparse.ArgumentParser(
         description="Federated runtime scenario runner")
@@ -660,7 +664,31 @@ def main(argv: list[str] | None = None) -> dict:
                 reports[-1].per_client_accuracy),
             "upload_bytes": up, "download_bytes_broadcast": down_bc,
             "download_bytes_per_client": down_pc,
-            "store_read_bytes": st_rd, "store_written_bytes": st_wr}
+            "store_read_bytes": st_rd, "store_written_bytes": st_wr,
+            "widths": {"classes": tm_cfg.n_classes,
+                       "clauses": tm_cfg.n_clauses,
+                       "literals": tm_cfg.n_literals,
+                       "clients": n_clients},
+            # what two runs must share to be the same run: every
+            # client's accuracy and slot assignment per round, and a
+            # digest of the final engine state's bytes
+            "per_client_accuracy": [
+                np.asarray(r.per_client_accuracy).tolist() for r in reports],
+            "assignment": [np.asarray(r.assignment).tolist()
+                           for r in reports],
+            "state_sha256": _state_digest(state)}
+
+
+def _state_digest(state) -> str:
+    """sha256 over every leaf of a pytree: dtype, shape and bytes."""
+    import hashlib
+
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(state):
+        a = np.asarray(leaf)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
 
 
 if __name__ == "__main__":

@@ -10,7 +10,10 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-from repro.sharding import compat
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -24,12 +27,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     if len(devices) > n:       # dry-run forces 512; single-pod uses 256
         import numpy as np
         return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
     """Single-device mesh for CPU smoke runs (same axis names)."""
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_clients_mesh(n_devices: int | None = None,
@@ -48,8 +51,3 @@ def make_clients_mesh(n_devices: int | None = None,
     import numpy as np
     return Mesh(np.asarray(devices[:n]), (axis,))
 
-
-# TPU v5e hardware constants (per chip) — §Roofline sources.
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link

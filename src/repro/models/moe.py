@@ -38,9 +38,8 @@ def _constrain_ep(buf: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
             or cfg.moe.sharding != "ep":
         return buf
     from jax.sharding import PartitionSpec as P
-    from repro.sharding import compat
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" not in mesh.axis_names:
         return buf
     if cfg.moe.n_experts % mesh.shape["model"] != 0:
         return buf

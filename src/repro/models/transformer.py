@@ -150,10 +150,9 @@ def _constrain_logits(logits: jnp.ndarray) -> jnp.ndarray:
     Without this SPMD sometimes materializes the *full-batch* logits per
     device at the unembed/loss boundary (§Perf: 2×12.9 GB/device/step
     measured on granite-moe train_4k)."""
-    from repro.sharding import compat
-    mesh = compat.get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ())
-    if mesh is None or "model" not in names:
+    mesh = jax.sharding.get_abstract_mesh()
+    names = mesh.axis_names
+    if "model" not in names:
         return logits
     from jax.sharding import PartitionSpec as P
     fsdp = tuple(a for a in ("pod", "data") if a in names)
@@ -171,10 +170,9 @@ def _constrain_batch_only(x: jnp.ndarray) -> jnp.ndarray:
     """Pin (B, T, d) activations to batch-over-FSDP, d replicated — stops
     SPMD from resharding the unembed input to a d-over-data layout whose
     contraction partial-sums all-reduce the full-batch logits."""
-    from repro.sharding import compat
-    mesh = compat.get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ())
-    if mesh is None or "model" not in names:
+    mesh = jax.sharding.get_abstract_mesh()
+    names = mesh.axis_names
+    if "model" not in names:
         return x
     from jax.sharding import PartitionSpec as P
     fsdp = tuple(a for a in ("pod", "data") if a in names)
@@ -246,10 +244,9 @@ def _sharded_ce(params: Params, x: jnp.ndarray, labels: jnp.ndarray,
     embeddings / non-dividing shapes) — caller falls back to the
     auto-sharded path.
     """
-    from repro.sharding import compat
-    mesh = compat.get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ())
-    if mesh is None or "model" not in names or cfg.tie_embeddings \
+    mesh = jax.sharding.get_abstract_mesh()
+    names = mesh.axis_names
+    if "model" not in names or cfg.tie_embeddings \
             or "lm_head" not in params:
         return None
     from jax.sharding import PartitionSpec as P

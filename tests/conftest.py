@@ -1,8 +1,14 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — smoke tests must see the
 real single CPU device; only launch/dryrun.py forces 512 host devices.
+
+The persistent compilation cache is off for the test run: the entry
+points point it into the checkout (launch/compile_cache.py), and a test
+run must leave nothing there.
 """
 import jax
 import pytest
+
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture(scope="session", autouse=True)

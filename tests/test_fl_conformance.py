@@ -20,11 +20,11 @@ import pytest
 from repro.core import baselines, federation, tm
 from repro.data import partition, synthetic
 from repro.fl import masked_collectives
+from repro.launch.mesh import make_clients_mesh
 from repro.fl.runtime import (CodecConfig, Engine, FedAvgStrategy,
                               FedTMStrategy, FLISStrategy, IFCAStrategy,
                               RuntimeConfig, Scheduler, SchedulerConfig,
                               TPFLStrategy, codec)
-from repro.sharding import compat
 
 TM_CFG = tm.TMConfig(n_classes=10, n_clauses=20, n_features=100,
                      n_states=63, s=5.0, T=20)
@@ -201,6 +201,17 @@ def test_pallas_tm_backend_is_bit_identical_to_ref(case, backend, data):
     _assert_bitwise_equal_runs(sa, ra, sb, rb)
 
 
+def test_pallas_tm_backend_refuses_bank_over_vmem_budget(data):
+    """The fused epoch kernel keeps one client's TA bank in VMEM; a
+    machine too wide for the budget is refused at init, never run on the
+    reference path behind the caller's back."""
+    import dataclasses as _dc
+    wide = _dc.replace(TM_CFG, n_clauses=2000, n_features=784)
+    with pytest.raises(ValueError, match="VMEM"):
+        Engine(TPFLStrategy(wide, local_epochs=1), data,
+               RuntimeConfig(tm_backend="pallas"))
+
+
 # ---------------------------------------------------------------------------
 # conf_threshold byte metering: masked uploads ship nothing
 # ---------------------------------------------------------------------------
@@ -236,15 +247,17 @@ def test_conf_threshold_zeroes_masked_rows_and_bytes(tm_backend, data):
     # and every metered byte maps onto a surviving assignment entry.
     # The gate compares raw confidence margins, so a fixed constant can
     # land outside the data's range — derive the threshold from a probe
-    # training pass instead (median of the clients' top-2 margins masks
-    # roughly half the slots).
+    # training pass instead.  One local epoch leaves most top-2 margins
+    # at 0, so the median gates nothing; the upper quartile of the
+    # clients' top-2 margins masks most slots and keeps the confident.
     probe = TPFLStrategy(cfg, local_epochs=1, top_classes=2)
     trained, _ = jax.vmap(probe.client_step, in_axes=(0, None, 0, 0))(
         cs, server.slots, data,
         jax.random.split(jax.random.PRNGKey(2), N_CLIENTS))
     conf = jax.vmap(lambda p, x: tm.confidence_scores(p, x, cfg))(
         trained, data.x_conf)
-    mid = float(jnp.median(jax.lax.top_k(conf, 2)[0]))
+    mid = float(jnp.quantile(jax.lax.top_k(conf, 2)[0].astype(jnp.float32),
+                             0.75))
     strat = TPFLStrategy(cfg, local_epochs=1, top_classes=2,
                          conf_threshold=mid)
     eng = Engine(strat, data, RuntimeConfig(rounds=ROUNDS))
@@ -270,8 +283,7 @@ def test_sharded_weighted_mean_matches_host_form():
     """The staleness-discounted sharded mean (one psum) agrees with the
     host ``clustered_weighted_mean`` it lowers."""
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("clients",))
-    from jax.experimental.shard_map import shard_map
+    mesh = make_clients_mesh(n_dev)
     from jax.sharding import PartitionSpec as P
 
     n, d, c = 4 * n_dev, 7, 3
@@ -282,11 +294,11 @@ def test_sharded_weighted_mean_matches_host_form():
     weights = 0.5 ** stale.astype(jnp.float32)
 
     host = masked_collectives.clustered_weighted_mean(vals, slots, weights, c)
-    means, total = jax.jit(shard_map(
+    means, total = jax.jit(jax.shard_map(
         lambda v, s, w: masked_collectives.clustered_weighted_mean_sharded(
             v, s, w, c, "clients"),
         mesh=mesh, in_specs=(P("clients"), P("clients"), P("clients")),
-        out_specs=(P(), P()), check_rep=False))(vals, slots, weights)
+        out_specs=(P(), P()), check_vma=False))(vals, slots, weights)
     assert np.allclose(np.asarray(host), np.asarray(means), atol=1e-5)
     onehot = jax.nn.one_hot(slots, c) * weights[:, None]
     assert np.allclose(np.asarray(total), np.asarray(onehot.sum(0)),
@@ -400,8 +412,7 @@ def test_buffered_weighted_mean_sharded_matches_host_form():
     same lanes the host form reduces — means must agree allclose for
     any capacity, including one that does not divide the mesh."""
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("clients",))
-    from jax.experimental.shard_map import shard_map
+    mesh = make_clients_mesh(n_dev)
     from jax.sharding import PartitionSpec as P
 
     cap, d, c = 4 * n_dev + 3, 6, 4          # deliberately non-divisible
@@ -411,11 +422,11 @@ def test_buffered_weighted_mean_sharded_matches_host_form():
     weights = jax.random.uniform(jax.random.fold_in(key, 2), (cap,))
 
     host = masked_collectives.clustered_weighted_mean(vals, slots, weights, c)
-    means, total = jax.jit(shard_map(
+    means, total = jax.jit(jax.shard_map(
         lambda v, s, w: masked_collectives.buffered_weighted_mean_sharded(
             v, s, w, c, "clients", n_dev),
         mesh=mesh, in_specs=(P(), P(), P()),
-        out_specs=(P(), P()), check_rep=False))(vals, slots, weights)
+        out_specs=(P(), P()), check_vma=False))(vals, slots, weights)
     assert np.allclose(np.asarray(host), np.asarray(means), atol=1e-5)
     onehot = jax.nn.one_hot(slots, c) * weights[:, None]
     assert np.allclose(np.asarray(total), np.asarray(onehot.sum(0)),
@@ -580,12 +591,18 @@ def test_flis_sparse_uplink_encodes_against_prev_slot_reference(data):
     assert (np.asarray(state.ref_round) >= 0).any()
 
 
-def test_flis_requires_sync_aggregation(data):
-    """Dynamic per-round assignment has no meaning against a cross-round
-    upload buffer — the engine rejects the combination at init."""
-    with pytest.raises(ValueError, match="sync"):
-        Engine(FLISStrategy(**FLIS_KW), data,
-               RuntimeConfig(aggregation="async"))
+def test_flis_runs_under_async_aggregation(data):
+    """Async × FLIS runs: the engine aggregates on the host buffer and
+    re-runs the strategy's ``assign`` over the matured uploads when it
+    folds them in, so membership is decided at aggregation time."""
+    eng = Engine(FLISStrategy(**FLIS_KW), data,
+                 RuntimeConfig(rounds=3, aggregation="async",
+                               async_min_uploads=2))
+    state, reports = eng.run(jax.random.PRNGKey(0))
+    assert len(reports) == 3
+    assert sum(r.aggregated_uploads for r in reports) > 0
+    assert all(np.isfinite(float(r.mean_accuracy)) for r in reports)
+    assert np.isfinite(np.asarray(state.server.slots)).all()
 
 
 def test_stringly_downloads_typo_is_rejected(data):
@@ -759,7 +776,7 @@ def test_engine_metered_bytes_equal_reencoded_buffer_lengths(data):
         keys = jax.random.split(jax.random.PRNGKey(1), N_CLIENTS)
         _, vecs, slots = engine.executor.train(
             strat, state.client_state, state.server.slots, data, keys)
-        _, up_bytes = engine._wire_uplink(state, vecs, slots, part)
+        _, up_bytes, _ = engine._wire_uplink(state, vecs, slots, part)
         expect = 0
         np_vecs, np_slots = np.asarray(vecs), np.asarray(slots)
         for c in range(N_CLIENTS):

@@ -403,12 +403,14 @@ def _digest(tree) -> str:
 
 # sha256 over every ClientData leaf (dtype + shape + bytes) of the
 # load("synthmnist")→dirichlet_clients chain below, as produced by the
-# CPU threefry PRNG.  If a jax upgrade legitimately changes a sampler,
+# threefry PRNG under the installed JAX's default key stream
+# (jax_threefry_partitionable=True).  If a jax upgrade legitimately
+# changes a sampler,
 # regenerate with: PYTHONPATH=src python -c "from tests.test_ingest
 # import _golden; print(_golden(None))"  (pass a tmp dir to pin the
 # file path too).
 GOLDEN_SYNTHMNIST_CLIENTDATA = (
-    "9f3fdb2f746df9cb5c6e55b2ec968db4ae5387e14ec04438a29a56a2a7d8a0ee")
+    "2034421175d46e128c101775c9522a0ca5fedd4d0ca20f6d947245340f563902")
 
 
 def _golden(data_dir) -> str:
@@ -441,7 +443,7 @@ def test_golden_synthmnist_clientdata_digest(tmp_path):
 #   PYTHONPATH=src python -c "from tests.test_ingest import \
 #     _golden_stream; print(_golden_stream())"
 GOLDEN_SYNTHFEMNIST_STREAM = (
-    "5e1e8fa7b1225f2fcdc90fa00ebe01aa35968fa7cfe2fbad9509e5c2c9ee8d73")
+    "d4d6ae4cf7347a341583161dded2db6033b0011d7e014fcafb59838fe1dcbf84")
 
 _STREAM_KW = dict(side=8, n_samples=600, seed=6, n_writers=12)
 _BUDGET = dict(n_clients=5, n_train=24, n_test=8, n_conf=8)

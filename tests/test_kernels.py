@@ -1,4 +1,5 @@
-"""Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracle.
+"""Per-kernel exact sweeps: Pallas (interpret mode on the CPU backend,
+as :func:`repro.kernels.ops.interpret_mode` decides) vs pure-jnp oracle.
 
 Shape sweep covers unaligned sizes (padding paths), paper-scale machines,
 and both int/bool-ish dtype inputs.
@@ -7,7 +8,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import clause_eval, draws, ref, ta_update
+from repro.kernels import clause_eval, draws, ops, ref, ta_update
+
+INTERPRET = dict(interpret=ops.interpret_mode())
 
 SHAPES_CLAUSE = [
     # (CM, L, B)
@@ -26,7 +29,8 @@ def test_clause_outputs_kernel_vs_ref(cm, L, B, seed, predict):
     include = jax.random.bernoulli(k1, 0.1, (cm, L)).astype(jnp.int32)
     lits = jax.random.bernoulli(k2, 0.5, (B, L)).astype(jnp.int32)
     r = ref.clause_outputs_ref(include, lits, predict=predict)
-    k = clause_eval.clause_outputs_pallas(include, lits, predict=predict)
+    k = clause_eval.clause_outputs_pallas(include, lits, predict=predict,
+                                          **INTERPRET)
     assert r.shape == k.shape
     assert (r == k).all()
 
@@ -40,7 +44,7 @@ def test_fused_votes_kernel_vs_ref(C, m, L, B, seed):
     lits = jax.random.bernoulli(ks[1], 0.5, (B, L)).astype(jnp.int32)
     wpol = jax.random.randint(ks[2], (C, m), -7, 8)
     r = ref.fused_votes_ref(include, lits, wpol, predict=True)
-    k = clause_eval.fused_votes_pallas(include, lits, wpol, predict=True)
+    k = ops.fused_votes(include, lits, wpol, predict=True)
     assert (r == k).all()
 
 
@@ -57,7 +61,8 @@ def test_ta_update_kernel_vs_ref(m, L, seed):
     u2 = jax.random.uniform(ks[6], (m, L))
     args = (ta, lit, fired, t1, t2, u1, u2)
     r = ref.ta_update_ref(*args, p_inc=0.9, p_dec=0.1, n_states=127)
-    k = ta_update.ta_update_pallas(*args, p_inc=0.9, p_dec=0.1, n_states=127)
+    k = ta_update.ta_update_pallas(*args, p_inc=0.9, p_dec=0.1, n_states=127,
+                                   **INTERPRET)
     assert (r == k).all()
     assert int(k.min()) >= 1 and int(k.max()) <= 254
 
@@ -74,22 +79,27 @@ def test_ta_update_kernel_extreme_probs():
     u2 = jax.random.uniform(ks[6], (m, L))
     # p_inc = 1.0 (boost_true_positive): every (fired, lit) TA moves up
     out = ta_update.ta_update_pallas(ta, lit, fired, t1, t2, u1, u2,
-                                     p_inc=1.0, p_dec=0.0, n_states=127)
+                                     p_inc=1.0, p_dec=0.0, n_states=127,
+                                     **INTERPRET)
     expect = jnp.clip(ta + 1, 1, 254)
     assert (out == expect).all()
 
 
-@pytest.mark.parametrize("C,m,L,B,N", [(4, 16, 32, 8, 3), (3, 33, 130, 5, 4)])
+@pytest.mark.parametrize("C,m,L,B,N,wmax", [(4, 16, 32, 8, 3, 8),
+                                             (3, 33, 130, 5, 4, 8),
+                                             (2, 40, 64, 3, 2, 100_000)])
 @pytest.mark.parametrize("seed", [0, 1])
-def test_fused_votes_batched_kernel_vs_ref(C, m, L, B, N, seed):
+def test_fused_votes_batched_kernel_vs_ref(C, m, L, B, N, wmax, seed):
     """The client-batched votes kernel row-for-row equals the per-client
-    fused-votes reference (including unaligned shapes — no padding)."""
+    fused-votes reference (unaligned shapes; weights far beyond bf16's
+    exact integers — the vote is an int32 sum, never a rounded float
+    product)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     include = jax.random.bernoulli(ks[0], 0.1, (N, C, m, L)).astype(jnp.int32)
     lits = jax.random.bernoulli(ks[1], 0.5, (N, B, L)).astype(jnp.int32)
-    wpol = jax.random.randint(ks[2], (N, C, m), -7, 8)
+    wpol = jax.random.randint(ks[2], (N, C, m), -wmax + 1, wmax)
     k = clause_eval.fused_votes_batched_pallas(include, lits, wpol,
-                                               predict=True)
+                                               predict=True, **INTERPRET)
     for i in range(N):
         r = ref.fused_votes_ref(include[i], lits[i], wpol[i], predict=True)
         assert (r == k[i]).all()
@@ -107,6 +117,20 @@ def test_int_threshold_matches_uniform_compare(p):
     assert (a == b).all()
 
 
+@pytest.mark.parametrize("T", [1, 15, 40, 1000])
+def test_activation_table_matches_bernoulli(T):
+    """Clause resampling compares the 23-bit draw against the host-built
+    table of f32((T ∓ v) / 2T) thresholds; pin it against the
+    ``bernoulli`` draw it replaced, over every numerator in [0, 2T]."""
+    table = draws.activation_thresholds(T)
+    k = jax.random.PRNGKey(T)
+    bits = draws.act_bits(k, (4096,))
+    for n in range(0, 2 * T + 1, max(1, T // 20)):
+        p = jnp.int32(n) / (2.0 * T)
+        want = jax.random.bernoulli(k, p, (4096,))
+        assert (want == (bits < table[n])).all(), n
+
+
 @pytest.mark.parametrize("bt,ct,lt", [(8, 128, 128), (16, 256, 256)])
 def test_clause_kernel_tile_invariance(bt, ct, lt):
     """Result must not depend on BlockSpec tiling choices."""
@@ -115,5 +139,5 @@ def test_clause_kernel_tile_invariance(bt, ct, lt):
     lits = jax.random.bernoulli(k2, 0.5, (24, 300)).astype(jnp.int32)
     base = ref.clause_outputs_ref(include, lits)
     out = clause_eval.clause_outputs_pallas(include, lits, bt=bt, ct=ct,
-                                            lt=lt)
+                                            lt=lt, **INTERPRET)
     assert (base == out).all()

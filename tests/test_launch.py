@@ -12,7 +12,6 @@ from repro.core import federation, tm
 from repro.checkpoint import ckpt
 from repro.launch import fed_train, hlo_analysis, mesh as mesh_mod, steps
 from repro.models import config as mcfg
-from repro.sharding import compat
 
 
 @pytest.fixture(scope="module")
@@ -30,7 +29,7 @@ def test_lower_compile_reduced_on_host_mesh(host_mesh, shape_name):
     shape = dataclasses.replace(steps.SHAPES[shape_name],
                                 seq_len=64, global_batch=2)
     ins = steps.input_specs(cfg, shape, host_mesh)
-    with compat.set_mesh(host_mesh):
+    with jax.set_mesh(host_mesh):
         if shape.kind == "train":
             lowered = jax.jit(steps.make_train_step(cfg)).lower(
                 ins["params"], ins["opt_state"], ins["batch"])
@@ -40,8 +39,6 @@ def test_lower_compile_reduced_on_host_mesh(host_mesh, shape_name):
                 ins["params"], ins["token"], ins["caches"])
         compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # jax ≤0.4.x wraps it in a list
-        ca = ca[0]
     assert ca["flops"] > 0
     coll = hlo_analysis.collective_bytes(compiled.as_text())
     assert all(v >= 0 for v in coll.values())
@@ -62,7 +59,7 @@ def test_trip_count_weighting_scales_with_scan_length():
         y, _ = jax.lax.scan(body, x, None, length=5)
         return y.sum()
 
-    with compat.set_mesh(m):
+    with jax.set_mesh(m):
         txt = jax.jit(f).lower(
             jax.ShapeDtypeStruct((8,), jnp.float32,
                                  sharding=NamedSharding(m, P()))
