@@ -344,11 +344,15 @@ def train_batched(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
 
     def epoch_body(carry, ek):
         ta, w = carry
-        offs, act, coin = jax.vmap(
-            lambda k: kdraws.epoch_draws(k, n_samples, cfg.n_clauses,
-                                         cfg.n_literals, cfg.n_classes,
-                                         p_inc, p_dec))(ek)
-        cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes], axis=-1)
+        # the scope names the epoch's randomness in the device trace
+        # (op metadata only: the compiled program is the same)
+        with jax.named_scope("tm.draws"):
+            offs, act, coin = jax.vmap(
+                lambda k: kdraws.epoch_draws(k, n_samples, cfg.n_clauses,
+                                             cfg.n_literals, cfg.n_classes,
+                                             p_inc, p_dec))(ek)
+            cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes],
+                             axis=-1)
         ta, w = kops.train_epoch_fused(ta, w, lits, cls2, act, coin,
                                        n_states=cfg.n_states, T=cfg.T)
         return (ta, w), None

@@ -29,6 +29,9 @@ already returned:
   in-process engine, where staleness is an injected schedule).
 * ``phases``        — the round's phase-span wall times (tracer),
   including the ``wire_tx`` / ``wire_rx`` transport spans.
+* ``compiles`` / ``compile_s`` — backend compiles and compile seconds
+  (trace + lowering + backend compile) the tracer charged to each span
+  (``"(none)"``: outside any span) — which stage recompiled.
 
 Serialization is numpy-safe by construction: :func:`to_jsonable`
 coerces numpy/jax scalars and arrays (int64 included — ``json`` alone
@@ -125,10 +128,13 @@ def _cluster_gauges(report, prev_assignment) -> dict:
 
 
 def round_event(report, spans: dict | None = None,
-                prev_assignment=None) -> dict:
+                prev_assignment=None, compiles: dict | None = None,
+                compile_s: dict | None = None) -> dict:
     """Build one structured event from a ``RoundReport`` (duck-typed —
-    the obs layer has no import edge into the runtime).  Pure
-    derivation: nothing here feeds back into the round."""
+    the obs layer has no import edge into the runtime).  ``compiles`` /
+    ``compile_s`` are the backend compiles and compile seconds the
+    tracer charged to each span.  Pure derivation: nothing here feeds
+    back into the round."""
     part = report.participation
     ev = {
         "schema": SCHEMA_VERSION,
@@ -160,6 +166,8 @@ def round_event(report, spans: dict | None = None,
         },
         "transport": _transport_gauges(report),
         "phases": dict(spans) if spans else None,
+        "compiles": dict(compiles) if compiles is not None else None,
+        "compile_s": dict(compile_s) if compile_s is not None else None,
     }
     return ev
 

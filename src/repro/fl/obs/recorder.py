@@ -52,8 +52,9 @@ class RunRecorder(PhaseTracer):
     ``jax.profiler`` capture via :func:`start`'s ``profile_dir``)."""
 
     def __init__(self, run_dir: str | pathlib.Path | None = None,
-                 profile_dir: str | pathlib.Path | None = None):
-        super().__init__()
+                 profile_dir: str | pathlib.Path | None = None,
+                 fence: bool = True):
+        super().__init__(fence=fence)
         self.run_dir = pathlib.Path(run_dir) if run_dir else None
         self.events_path = (self.run_dir / mf.EVENTS_NAME
                             if self.run_dir else None)
@@ -80,7 +81,9 @@ class RunRecorder(PhaseTracer):
         return self
 
     def close(self) -> None:
-        """Stop the profiler capture (events are flushed per round)."""
+        """Stop the profiler capture and the compile listener (events
+        are flushed per round)."""
+        super().close()
         if self._profile_ctx is not None:
             ctx, self._profile_ctx = self._profile_ctx, None
             ctx.__exit__(None, None, None)
@@ -90,7 +93,10 @@ class RunRecorder(PhaseTracer):
     def on_round(self, report) -> dict:
         """Derive this round's event from the report + the spans
         accumulated since the last call, and append it to the log."""
-        event = ev.round_event(report, spans=self.take(),
+        spans = self.take()
+        event = ev.round_event(report, spans=spans,
+                               compiles=spans.compiles,
+                               compile_s=spans.compile_s,
                                prev_assignment=self._prev_assignment)
         self._prev_assignment = np.array(report.assignment)
         if self.events_path is not None:

@@ -8,8 +8,9 @@ and prints three views:
 
 * the **round table** — accuracy (mean and worst-decile), wire bytes by
   direction, participation, async buffer counters, per round;
-* the **phase breakdown** — median wall time per round stage and its
-  share of the round, the where-does-round-time-go view every perf PR
+* the **phase breakdown** — median wall time per round stage, its
+  share of the round and the backend compiles charged to it over the
+  run, the where-does-round-time-go view every performance change
   reports against;
 * the **client-accuracy deciles** of the final round — the
   distributional (worst-k) personalization metric, not just the mean.
@@ -98,19 +99,33 @@ def phase_medians(events: list[dict]) -> dict[str, float]:
     return {name: float(np.median(v)) for name, v in acc.items()}
 
 
+def phase_compiles(events: list[dict]) -> dict[str, int]:
+    """Backend compiles charged to each span, summed over the rounds."""
+    acc: dict[str, int] = {}
+    for e in events:
+        for name, n in (e.get("compiles") or {}).items():
+            acc[name] = acc.get(name, 0) + int(n)
+    return acc
+
+
 def _phase_table(events: list[dict]) -> list[str]:
     med = phase_medians(events)
     if not med:
         return ["(no phase spans recorded)"]
+    comp = phase_compiles(events)
     total = med.get("round") or sum(
         v for k, v in med.items() if k != "round")
-    lines = [f"{'phase':<18} {'median_s':>10} {'share':>7}",
-             "-" * 37]
+    lines = [f"{'phase':<18} {'median_s':>10} {'share':>7} {'compiles':>8}",
+             "-" * 46]
     stages = {k: v for k, v in med.items() if k != "round"}
     for name, dt in sorted(stages.items(), key=lambda kv: -kv[1]):
         share = f"{100.0 * dt / total:>6.1f}%" if total else "      -"
-        lines.append(f"{name:<18} {dt:>10.4f} {share}")
-    lines.append("-" * 37)
+        lines.append(f"{name:<18} {dt:>10.4f} {share} "
+                     f"{comp.get(name, 0):>8}")
+    # compiles outside every stage: in the round span itself, or "(none)"
+    for name in sorted(set(comp) - set(stages)):
+        lines.append(f"{name:<18} {'-':>10} {'-':>7} {comp[name]:>8}")
+    lines.append("-" * 46)
     lines.append(f"{'Σ stages':<18} {sum(stages.values()):>10.4f}")
     if "round" in med:
         lines.append(f"{'round total':<18} {med['round']:>10.4f}")

@@ -511,6 +511,8 @@ class Engine:
         start = int(state.round_idx)
         n_rounds = self.cfg.rounds if rounds is None else rounds
         for r in range(start, start + n_rounds):
+            if self.obs.enabled:
+                self.obs.begin_round(r)
             with self.obs.span("round"):
                 state, rep = self.run_round(
                     state, jax.random.fold_in(k_rounds, r))
@@ -539,6 +541,8 @@ class Engine:
                   ) -> tuple[EngineState, RoundReport]:
         obs = self.obs            # telemetry spans/fences — no-ops when off
         r = int(state.round_idx)
+        if obs.enabled:
+            obs.begin_round(r)    # the round stat of the stage annotations
         store = self.store
         if self._mmap:
             io0 = (store.io_read_bytes, store.io_written_bytes)

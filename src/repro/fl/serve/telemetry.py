@@ -49,6 +49,8 @@ NULL_SERVE = NullServeTelemetry()
 class ServeTelemetry(PhaseTracer):
     """Span timing + JSONL event sink for one serving run."""
 
+    annotation_prefix = ""          # span names carry ``serve/`` already
+
     def __init__(self, run_dir: str | pathlib.Path):
         super().__init__()
         self.run_dir = pathlib.Path(run_dir)

@@ -93,6 +93,8 @@ class TransportEngine:
             reports: list[RoundReport] = []
             n_rounds = self.cfg.rounds if rounds is None else rounds
             for r in range(n_rounds):
+                if self.obs.enabled:
+                    self.obs.begin_round(r)
                 with self.obs.span("round"):
                     state, rep = self._round(
                         transport, state, jax.random.fold_in(k_rounds, r),

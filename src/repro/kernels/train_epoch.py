@@ -162,19 +162,24 @@ def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
     # true, so no feedback reaches them.
     m, L = _ceil_to(m0, 8), _ceil_to(L0, _LANES)
     pad_m, pad_l = m - m0, L - L0
-    ta_p = jnp.pad(ta_state.astype(jnp.int32),
-                   ((0, 0), (0, 0), (0, pad_m), (0, pad_l)),
-                   constant_values=1)
-    # weights travel as lane-replicated (m, 128) columns: a DMA moves
-    # whole (8, 128) tiles, so a width-1 lane slice cannot be copied
-    w_p = jnp.broadcast_to(
-        jnp.pad(weights.astype(jnp.int32),
-                ((0, 0), (0, 0), (0, pad_m)))[..., None],
-        (N, C, m, _LANES))
-    lits_p = jnp.pad(lits, ((0, 0), (0, 0), (0, pad_l)),
-                     constant_values=1)[:, :, None, :]
-    act_p = jnp.pad(act, ((0, 0), (0, 0), (0, 0), (0, pad_m)))[..., None]
-    coin_p = jnp.pad(coin, ((0, 0), (0, 0), (0, 0), (0, pad_m), (0, pad_l)))
+    # the pads and re-layouts are named in the device trace (op metadata
+    # only: the compiled program is the same)
+    with jax.named_scope("tm.epoch_pad"):
+        ta_p = jnp.pad(ta_state.astype(jnp.int32),
+                       ((0, 0), (0, 0), (0, pad_m), (0, pad_l)),
+                       constant_values=1)
+        # weights travel as lane-replicated (m, 128) columns: a DMA moves
+        # whole (8, 128) tiles, so a width-1 lane slice cannot be copied
+        w_p = jnp.broadcast_to(
+            jnp.pad(weights.astype(jnp.int32),
+                    ((0, 0), (0, 0), (0, pad_m)))[..., None],
+            (N, C, m, _LANES))
+        lits_p = jnp.pad(lits, ((0, 0), (0, 0), (0, pad_l)),
+                         constant_values=1)[:, :, None, :]
+        act_p = jnp.pad(act,
+                        ((0, 0), (0, 0), (0, 0), (0, pad_m)))[..., None]
+        coin_p = jnp.pad(coin,
+                         ((0, 0), (0, 0), (0, 0), (0, pad_m), (0, pad_l)))
     table = draws.activation_thresholds(T)
     thr = jnp.zeros((1, _ceil_to(table.size, _LANES)), jnp.int32)
     thr = thr.at[0, :table.size].set(table)
@@ -210,4 +215,5 @@ def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
         name="tm_train_epoch_fused",
     )(cls2.reshape(-1).astype(jnp.int32), ta_p, w_p, lits_p, act_p, coin_p,
       thr)
-    return ta[:, :, :m0, :L0], w[:, :, :m0, 0]
+    with jax.named_scope("tm.epoch_pad"):
+        return ta[:, :, :m0, :L0], w[:, :, :m0, 0]

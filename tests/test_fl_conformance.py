@@ -986,13 +986,8 @@ def test_async_buffer_never_holds_an_upload_older_than_max_staleness(data):
 # telemetry neutrality: obs-on == obs-off, bit for bit
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("aggregation", ["sync", "async"])
-@pytest.mark.parametrize("backend", ["inprocess", "shardmap"])
-def test_telemetry_is_bit_neutral(backend, aggregation, data, tmp_path):
-    """The obs plane only reads: a fully instrumented run (RunRecorder
-    writing a run dir, spans + fences live) produces bit-identical
-    RoundReports and final state to the un-instrumented run, on both
-    backends and both aggregation modes."""
+def _assert_telemetry_neutral(backend, aggregation, data, tmp_path,
+                              fence):
     from repro.fl.obs import RunRecorder, build_manifest, read_events
 
     cfg = RuntimeConfig(
@@ -1004,7 +999,7 @@ def test_telemetry_is_bit_neutral(backend, aggregation, data, tmp_path):
                           data, cfg).run(jax.random.PRNGKey(0))
 
     run_dir = tmp_path / f"{backend}-{aggregation}"
-    rec = RunRecorder(run_dir=run_dir)
+    rec = RunRecorder(run_dir=run_dir, fence=fence)
     rec.start(build_manifest(config=cfg, seed=0))
     try:
         s_on, r_on = Engine(TPFLStrategy(TM_CFG, local_epochs=1),
@@ -1019,6 +1014,27 @@ def test_telemetry_is_bit_neutral(backend, aggregation, data, tmp_path):
     events = read_events(run_dir / "events.jsonl")
     assert [e["round"] for e in events] == [0, 1, 2]
     assert all(e["phases"] for e in events)
+
+
+@pytest.mark.parametrize("aggregation", ["sync", "async"])
+@pytest.mark.parametrize("backend", ["inprocess", "shardmap"])
+def test_telemetry_is_bit_neutral(backend, aggregation, data, tmp_path):
+    """The obs plane only reads: a fully instrumented run (RunRecorder
+    writing a run dir, spans + fences live) produces bit-identical
+    RoundReports and final state to the un-instrumented run, on both
+    backends and both aggregation modes."""
+    _assert_telemetry_neutral(backend, aggregation, data, tmp_path,
+                              fence=True)
+
+
+@pytest.mark.parametrize("aggregation", ["sync", "async"])
+@pytest.mark.parametrize("backend", ["inprocess", "shardmap"])
+def test_unfenced_telemetry_is_bit_neutral(backend, aggregation, data,
+                                           tmp_path):
+    """The same pin for the unfenced tracer a profiled run uses: spans,
+    stage annotations and compile counts live, no fences."""
+    _assert_telemetry_neutral(backend, aggregation, data, tmp_path,
+                              fence=False)
 
 
 # ---------------------------------------------------------------------------

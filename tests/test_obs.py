@@ -7,6 +7,7 @@ both backends and both aggregation modes) lives in
 ``tests/test_fl_conformance.py`` next to the rest of the parity matrix;
 this file covers the obs layer's own behaviour.
 """
+import dataclasses
 import io
 import json
 import pathlib
@@ -200,6 +201,125 @@ def test_null_tracer_is_inert():
     assert obs.NULL.manifest is None
     obs.NULL.on_round(object())                           # no-op, no raise
     obs.NULL.close()
+
+
+# ---------------------------------------------------------------------------
+# tracer: fences, the profiler's clock, the compiles of each stage
+# ---------------------------------------------------------------------------
+
+ENGINE_STAGES = {"schedule", "gather", "broadcast_encode", "client_step",
+                 "uplink_codec", "aggregate", "server_update", "downlink",
+                 "apply_merge", "ref_track", "eval"}
+
+
+def test_fence_is_the_tracers_choice(monkeypatch):
+    blocked = []
+    monkeypatch.setattr(jax, "block_until_ready", blocked.append)
+    PhaseTracer(fence=False).fence(np.zeros(2))
+    assert blocked == []
+    PhaseTracer().fence(np.zeros(2), None)
+    assert len(blocked) == 1
+
+
+def test_unfenced_tracer_puts_stages_on_the_profiler_clock(tmp_path, data):
+    """A CPU profiler capture of in-process rounds under an unfenced
+    recorder: the host plane holds every engine stage as an
+    ``engine.<stage>`` event inside its round's ``engine.round`` event,
+    each with the round index as its ``round`` stat."""
+    rec = obs.RunRecorder(fence=False)
+    engine = Engine(TPFLStrategy(TM_CFG, local_epochs=1), data,
+                    RuntimeConfig(rounds=2), telemetry=rec)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.run(jax.random.PRNGKey(0))
+    finally:
+        jax.profiler.stop_trace()
+        rec.close()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("engine.")]
+    rounds = {st["round"]: (lo, hi) for name, lo, hi, st in spans
+              if name == "engine.round"}
+    assert sorted(rounds) == [0, 1]
+    for r, (lo, hi) in rounds.items():
+        inside = {name[len("engine."):] for name, s, e, st in spans
+                  if name != "engine.round" and lo <= s and e <= hi
+                  and st["round"] == r}
+        assert inside >= ENGINE_STAGES
+        assert set(rec.history[r]["phases"]) - {"round"} <= inside
+
+
+def test_compiles_are_charged_to_the_innermost_open_span():
+    tr = PhaseTracer(fence=False)
+    x = jax.numpy.arange(5.0)
+    f = jax.jit(lambda v: v * 3.0 + 1.0)
+    with tr.span("outer"):
+        with tr.span("x"):
+            f(x).block_until_ready()
+    first = tr.take()
+    assert first.compiles["x"] == 1 and first.compile_s["x"] > 0.0
+    assert first.compiles.get("outer", 0) == 0
+    with tr.span("x"):
+        f(x).block_until_ready()                      # cached: no compile
+    again = tr.take()
+    assert again.compiles == {} and again.compile_s == {}
+    jax.jit(lambda v: v - 2.0)(x).block_until_ready()  # outside any span
+    assert tr.take().compiles == {"(none)": 1}
+    tr.close()
+    with tr.span("x"):
+        jax.jit(lambda v: v + 5.0)(x).block_until_ready()
+    assert tr.take().compiles == {}
+
+
+def test_tracer_compiles_match_a_separate_listener_over_a_round(data):
+    from repro.fl.obs import tracer as tracer_mod
+    seen = {"n": 0, "s": 0.0}
+
+    def listener(event, duration, **kwargs):
+        if event in tracer_mod.COMPILE_EVENTS:
+            seen["s"] += duration
+            seen["n"] += event == tracer_mod.BACKEND_COMPILE
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    rec = obs.RunRecorder(fence=False)
+    try:
+        # fresh sizes, so the round compiles its programs
+        cfg = dataclasses.replace(TM_CFG, n_clauses=14)
+        Engine(TPFLStrategy(cfg, local_epochs=1), data,
+               RuntimeConfig(rounds=1), telemetry=rec
+               ).run(jax.random.PRNGKey(0))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+        rec.close()
+    (event,) = rec.history
+    assert seen["n"] >= 1
+    assert sum(event["compiles"].values()) == seen["n"]
+    assert sum(event["compile_s"].values()) == pytest.approx(seen["s"])
+    assert set(event["compiles"]) <= set(event["phases"]) | {"(none)"}
+
+
+def test_summary_shows_the_compiles_of_each_stage(tmp_path):
+    path = tmp_path / "events.jsonl"
+    for r in range(2):
+        ev.append_event(path, ev.round_event(
+            _FakeReport(round_idx=r),
+            spans={"round": 1.0, "client_step": 0.6, "apply_merge": 0.2},
+            compiles={"apply_merge": 1, "(none)": r},
+            compile_s={"apply_merge": 0.05, "(none)": 0.01 * r}))
+    events = ev.read_events(path)
+    assert events[1]["compiles"] == {"apply_merge": 1, "(none)": 1}
+    assert obs.phase_compiles(events) == {"apply_merge": 2, "(none)": 1}
+    buf = io.StringIO()
+    obs.summarize(tmp_path, out=buf)
+    rows = {ln.split()[0]: ln.split() for ln in buf.getvalue().splitlines()
+            if ln.strip()}
+    assert rows["phase"][-1] == "compiles"
+    assert rows["apply_merge"][-1] == "2"
+    assert rows["client_step"][-1] == "0"
+    assert rows["(none)"][-1] == "1"
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,7 @@
 """Tsetlin Machine unit + property(seed-swept) tests."""
+import contextlib
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -188,3 +190,53 @@ def test_predict_kernel_clips_votes_before_argmax():
     y = jnp.zeros((1, 1), jnp.int32)
     stack = jax.tree.map(lambda a: a[None], p)
     assert float(tm.accuracy_batched(stack, x[None], y, kcfg)[0]) == 1.0
+
+
+# ---------------------------------------------------------------------------
+# names in the device trace: scopes change op metadata, not the program
+# ---------------------------------------------------------------------------
+
+_METADATA = re.compile(r",? metadata=\{[^}]*\}")
+# the source tables an optimized module opens with, each ended by a
+# blank line: FileNames, FunctionNames, FileLocations, StackFrames
+_SOURCE_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n.*?\n\n",
+    re.M | re.S)
+
+
+def _program(hlo: str) -> str:
+    """An optimized HLO module without its metadata and source tables."""
+    return _METADATA.sub("", _SOURCE_TABLES.sub("", hlo))
+
+
+def _lower_train_batched(kcfg, N=2, S=5):
+    p = jax.vmap(lambda k: tm.init_params(kcfg, k))(
+        jax.random.split(jax.random.PRNGKey(0), N))
+    xs = jnp.zeros((N, S, kcfg.n_features), jnp.int32)
+    ys = jnp.zeros((N, S), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(1), N)
+    jax.clear_caches()              # trace anew: scopes live in the trace
+    return tm.train_batched.lower(p, xs, ys, keys, kcfg, epochs=2)
+
+
+def test_train_batched_names_draws_and_pads_and_keeps_its_program(
+        monkeypatch):
+    """The pallas path's epoch draws and the kernel wrapper's pads carry
+    the ``tm.draws`` / ``tm.epoch_pad`` scopes in their locations, and
+    the optimized program, metadata stripped, is the one compiled with
+    the scopes removed."""
+    kcfg = tm.TMConfig(n_classes=3, n_clauses=9, n_features=20,
+                       n_states=63, s=3.0, T=15, use_kernel=True)
+    named = _lower_train_batched(kcfg)
+    locs = named.as_text(debug_info=True)
+    assert "tm.draws" in locs and "tm.epoch_pad" in locs
+    with_scopes = named.compile().as_text()
+    assert "tm.draws" in with_scopes                  # kept to the end
+
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _lower_train_batched(kcfg)
+    assert "tm.draws" not in bare.as_text(debug_info=True)
+    without = bare.compile().as_text()
+    assert "FileNames" not in _program(with_scopes)
+    assert _program(with_scopes) == _program(without)

@@ -80,3 +80,48 @@ def test_train_epoch_compiles_within_vmem(one_chip):
         ((n, C, M, L), jnp.int32), ((n, C, M), jnp.int32),
         ((n, s, L), jnp.int32), ((n, s, 2), jnp.int32),
         ((n, s, 2, M), jnp.int32), ((n, s, 2, M, L), jnp.int8))
+
+
+def test_train_batched_scopes_leave_the_chip_program_unchanged(
+        one_chip, monkeypatch):
+    """The ``tm.draws`` / ``tm.epoch_pad`` scopes name the round's
+    training work in the device trace; the program the TPU compiler
+    makes of ``train_batched`` (pallas path, one client, 8 samples, the
+    paper's widths) is the same without them, metadata stripped."""
+    import contextlib
+    import re
+
+    from repro.core import tm
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    cfg = tm.TMConfig(n_classes=C, n_clauses=M, n_features=L // 2,
+                      n_states=127, s=10.0, T=1000, use_kernel=True)
+    n, s = 1, 8
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = (tm.TMParams(ta_state=sds((n, C, M, L), jnp.int32),
+                        weights=sds((n, C, M), jnp.int32)),
+            sds((n, s, L // 2), jnp.int32), sds((n, s), jnp.int32),
+            sds((n, 2), jnp.uint32))
+
+    def program() -> str:
+        jax.clear_caches()
+        text = tm.train_batched.lower(*args, cfg, epochs=1).compile(
+            ).as_text()
+        assert "tpu_custom_call" in text
+        text = re.sub(r"^(FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n.*?\n\n", "", text, flags=re.M | re.S)
+        return re.sub(r",? metadata=\{[^}]*\}", "", text)
+
+    # both from one line: the kernel's serialized body records where it
+    # was traced from
+    texts = []
+    for scoped in (True, False):
+        if not scoped:
+            monkeypatch.setattr(jax, "named_scope",
+                                lambda name: contextlib.nullcontext())
+        texts.append(program())
+    assert texts[0] == texts[1]
