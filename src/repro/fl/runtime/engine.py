@@ -71,6 +71,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
+import logging
 import tempfile
 from typing import Any, NamedTuple
 
@@ -268,7 +269,7 @@ class Engine:
         # are untouched — the flag is a no-op for the MLP baselines.
         if cfg.tm_backend == "pallas" and \
                 getattr(strategy, "tm_cfg", None) is not None:
-            from repro.kernels import train_epoch
+            from repro.kernels import draws, train_epoch
             tc = strategy.tm_cfg
             need = train_epoch.vmem_bytes(tc.n_classes, tc.n_clauses,
                                           tc.n_literals)
@@ -279,6 +280,11 @@ class Engine:
                     f"L={tc.n_literals} needs {need} B, over the "
                     f"{train_epoch.VMEM_BUDGET} B budget — run "
                     f"tm_backend='ref' or fewer clauses")
+            logging.getLogger(__name__).info(
+                "tm_backend='pallas': epoch coin planes %s",
+                "merged (one threefry plane a sample for both roles)"
+                if draws.merged_coins() else
+                "drawn per role (non-partitionable threefry)")
             strategy = dataclasses.replace(
                 strategy, tm_cfg=dataclasses.replace(
                     strategy.tm_cfg, use_kernel=True))

@@ -25,8 +25,25 @@ hit), via the **int-domain compare trick**: jax's float32
 bit-for-bit (both sides of the float compare are exact f32 values;
 :func:`int_threshold` is pinned against ``jax.random.uniform`` by
 ``tests/test_kernels.py``).  This skips the uint32→f32 convert and the
-f32 compare for the two (m, L) planes per role — the dominant draw
-volume — while staying bit-identical to the reference path.
+f32 compare for the (m, L) coin planes — the dominant draw volume —
+while staying bit-identical to the reference path.
+
+**One coin plane a sample, not one a role.**  Both coin bits are read
+only on Type-I rows, and Type I goes to the even (positive-polarity)
+clauses on the target role and to the odd ones on the negative role.
+So a single (m, L) plane carries both roles: row ``r`` holds the coins
+of the role whose Type-I clauses own it — the target's ``k_s1`` /
+``k_s2`` words for even ``r``, the negative's for odd ``r``.  Under the
+partitionable threefry (``jax_threefry_partitionable``, the default),
+word ``(r, l)`` of ``bits(k, (m, L))`` is ``x0 ^ x1`` of
+``threefry2x32(k, (0, r·L + l))``, a function of its own index alone,
+so the plane is hashed once with each row's key chosen by parity: half
+the hashes of drawing both roles' planes, and every word the kernel
+reads is the word it would read from them.  Under the original threefry
+a word pairs with the one half a plane away, which need not share its
+row's parity; there both roles' planes are drawn in full and their rows
+picked by parity — the same plane, at the old cost.
+:func:`merged_coins` says which, from ``jax.config`` at trace time.
 
 The clause-activation draws use the same trick.  Their probability
 ``p_act = (T ∓ v) / 2T`` depends on the clipped vote ``v``, so it can only
@@ -45,6 +62,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.random import threefry2x32_p
 
 # f32 uniforms carry exactly 23 mantissa bits: u = (bits >> 9) * 2^-23
 _MANTISSA = float(1 << 23)
@@ -71,6 +89,33 @@ def activation_thresholds(T: int) -> np.ndarray:
     return np.array([int_threshold(float(q)) for q in p], dtype=np.int32)
 
 
+def merged_coins() -> bool:
+    """Whether :func:`epoch_draws` hashes each coin plane once for both
+    roles (partitionable threefry) or draws both roles' planes and picks
+    rows by parity (any other stream).  Read at trace time."""
+    return (jax.config.jax_threefry_partitionable
+            and jax.config.jax_default_prng_impl == "threefry2x32")
+
+
+def _coin_words(k_even: jax.Array, k_odd: jax.Array, m: int, L: int
+                ) -> jnp.ndarray:
+    """(m, L) uint32: row ``r`` is row ``r`` of ``bits(k, (m, L))`` with
+    ``k = k_even`` for even ``r`` and ``k_odd`` for odd ``r`` — the
+    partitionable threefry's word ``(r, l)``, hashed once."""
+    if m * L >= 2 ** 32:
+        raise ValueError(f"a coin plane of {m}×{L} words needs the "
+                         "counter's high word, which is taken as 0")
+    row = jax.lax.broadcasted_iota(jnp.uint32, (m, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.uint32, (m, L), 1)
+    even = row % 2 == 0
+    k_even = jax.random.key_data(k_even)
+    k_odd = jax.random.key_data(k_odd)
+    k1 = jnp.where(even, k_even[0], k_odd[0])
+    k2 = jnp.where(even, k_even[1], k_odd[1])
+    x0, x1 = threefry2x32_p.bind(k1, k2, jnp.zeros_like(row), row * L + col)
+    return x0 ^ x1
+
+
 def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
                 n_literals: int, n_classes: int,
                 p_inc: float, p_dec: float):
@@ -81,29 +126,36 @@ def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
     * ``offsets`` (S,) int32 — negative-class offset in [1, C);
     * ``act``     (S, 2, m) int32 — clause-activation draws as 23-bit
       integers (:func:`act_bits`), role 0 = target, 1 = negative;
-    * ``coin``    (S, 2, m, L) int8 — bit 1: Type-I increment draw hit
-      (``u < p_inc``), bit 2: decrement draw hit (``u < p_dec``).
+    * ``coin``    (S, m, L) int8 — bit 1: Type-I increment draw hit
+      (``u < p_inc``), bit 2: decrement draw hit (``u < p_dec``); even
+      rows from the target role's coin keys, odd rows from the
+      negative role's.
     """
     m, L = n_clauses, n_literals
     t_inc = int_threshold(p_inc)
     t_dec = int_threshold(p_dec)
     keys = jax.random.split(key, n_samples)
+    merged = merged_coins()
+    even = (jnp.arange(m) % 2 == 0)[:, None]
+
+    def plane(kt, kn):
+        """23-bit words: the target's (kt) on even rows, kn's on odd."""
+        if merged:
+            return _coin_words(kt, kn, m, L) >> 9
+        return jnp.where(even, jax.random.bits(kt, (m, L), jnp.uint32),
+                         jax.random.bits(kn, (m, L), jnp.uint32)) >> 9
 
     def per_sample(_, k):
         k_neg, k_t, k_n = jax.random.split(k, 3)
-
-        def role(kr):
-            k_act, k_s1, k_s2 = jax.random.split(kr, 3)
-            h1 = jax.random.bits(k_s1, (m, L), jnp.uint32) >> 9
-            h2 = jax.random.bits(k_s2, (m, L), jnp.uint32) >> 9
-            return act_bits(k_act, (m,)), ((h1 < t_inc).astype(jnp.int8)
-                                          + 2 * (h2 < t_dec).astype(jnp.int8))
-
-        a_t, c_t = role(k_t)
-        a_n, c_n = role(k_n)
+        ka_t, k1_t, k2_t = jax.random.split(k_t, 3)
+        ka_n, k1_n, k2_n = jax.random.split(k_n, 3)
+        h1 = plane(k1_t, k1_n)
+        h2 = plane(k2_t, k2_n)
+        coin = ((h1 < t_inc).astype(jnp.int8)
+                + 2 * (h2 < t_dec).astype(jnp.int8))
+        act = jnp.stack([act_bits(ka_t, (m,)), act_bits(ka_n, (m,))])
         off = jax.random.randint(k_neg, (), 1, n_classes)
-        return 0, (off.astype(jnp.int32), jnp.stack([a_t, a_n]),
-                   jnp.stack([c_t, c_n]))
+        return 0, (off.astype(jnp.int32), act, coin)
 
     _, (offsets, act, coin) = jax.lax.scan(per_sample, 0, keys)
     return offsets, act, coin

@@ -13,14 +13,14 @@ federated round through one launch, as a grid ``(client, sample)``.
 The sample axis is sequential (``arbitrary``): the TA bank and weights
 live in scratch across it, loaded by DMA at the client's first sample
 and stored at its last.  Per-sample inputs — the literal row, the two
-roles' activation draws and their ``(2, m, L)`` int8 coin planes — are
+roles' activation draws and the sample's ``(m, L)`` int8 coin plane — are
 ordinary blocks, so the pipeline streams them from HBM one sample ahead
 of the compute.  The per-(client, sample) class pair is scalar-prefetched
 into SMEM and indexes the resident bank directly (a dynamic index on its
 leading, untiled axis).
 
 VMEM holds one client's bank and weights, two samples' coin planes
-and one feedback step's ``(m, L)`` temporaries — 35 MiB at the paper's
+and one feedback step's ``(m, L)`` temporaries — 34 MiB at the paper's
 MNIST widths (C=10, m=300, L=1568).  :func:`vmem_bytes` computes it;
 :func:`repro.fl.runtime.engine.Engine` refuses ``tm_backend="pallas"``
 for a machine whose need exceeds :data:`VMEM_BUDGET`.
@@ -31,6 +31,9 @@ Bit-parity with the reference scan (pinned in ``tests/test_tm.py`` and
 * randomness is pre-generated outside with the reference key discipline
   (:mod:`repro.kernels.draws`), and the clause-activation compare runs
   against the same host-built integer threshold table on both paths;
+* both roles read one coin plane: the coins matter only on Type-I rows,
+  the even rows on the target role and the odd rows on the negative,
+  and the plane holds each role's words on exactly those rows;
 * class votes are per-class independent — ``votes[c]`` reads only class
   ``c``'s clauses/weights, and the negative class ``ȳ ≠ y`` — so
   processing (sample, target-role) then (sample, negative-role) in turn
@@ -63,7 +66,7 @@ def vmem_bytes(n_classes: int, n_clauses: int, n_literals: int) -> int:
     C, m, L = n_classes, _ceil_to(n_clauses, 8), _ceil_to(n_literals, _LANES)
     bank = 4 * C * m * L
     weights = 4 * C * m * _LANES
-    stream = 2 * (2 * m * L + 2 * 4 * m * _LANES + 4 * 8 * L)
+    stream = 2 * (m * L + 2 * 4 * m * _LANES + 4 * 8 * L)
     temps = 6 * 4 * m * L
     return bank + weights + stream + temps
 
@@ -114,7 +117,8 @@ def _epoch_kernel(cls_ref, ta_hbm, w_hbm, lits_ref, act_ref, coin_ref,
         t2 = (~pos if role == 0 else pos) & active
         t1f, t2f = t1 & fired, t2 & fired
 
-        cn = coin_ref[0, 0, role].astype(jnp.int32)    # (m, L)
+        # one coin plane serves both roles: each reads its Type-I rows
+        cn = coin_ref[0, 0].astype(jnp.int32)          # (m, L)
         up1 = t1f & lit & ((cn & 1) != 0)
         down1 = t1 & ~(fired & lit) & ((cn & 2) != 0)
         up2 = t2f & ~lit & ~inc
@@ -149,7 +153,8 @@ def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
       lits:     (N, S, L) int32 0/1 — per-client literal planes.
       cls2:     (N, S, 2) int32 — per (client, sample): [target, negative].
       act:      (N, S, 2, m) int32 — 23-bit activation draws per role.
-      coin:     (N, S, 2, m, L) int8 — pre-compared Type-I coin flips.
+      coin:     (N, S, m, L) int8 — pre-compared Type-I coin flips, the
+                target role's on even rows, the negative's on odd.
 
     Returns ``(ta_state, weights)`` after the sample-sequential epoch,
     bit-identical to the reference ``tm.train_epoch`` per client.
@@ -178,8 +183,7 @@ def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
                          constant_values=1)[:, :, None, :]
         act_p = jnp.pad(act,
                         ((0, 0), (0, 0), (0, 0), (0, pad_m)))[..., None]
-        coin_p = jnp.pad(coin,
-                         ((0, 0), (0, 0), (0, 0), (0, pad_m), (0, pad_l)))
+        coin_p = jnp.pad(coin, ((0, 0), (0, 0), (0, pad_m), (0, pad_l)))
     table = draws.activation_thresholds(T)
     thr = jnp.zeros((1, _ceil_to(table.size, _LANES)), jnp.int32)
     thr = thr.at[0, :table.size].set(table)
@@ -194,7 +198,7 @@ def train_epoch_pallas(ta_state: jax.Array, weights: jax.Array,
             hbm,
             pl.BlockSpec((1, 1, 1, L), lambda n, s, c: (n, s, 0, 0)),
             pl.BlockSpec((1, 1, 2, m, 1), lambda n, s, c: (n, s, 0, 0, 0)),
-            pl.BlockSpec((1, 1, 2, m, L), lambda n, s, c: (n, s, 0, 0, 0)),
+            pl.BlockSpec((1, 1, m, L), lambda n, s, c: (n, s, 0, 0)),
             pl.BlockSpec(thr.shape, lambda n, s, c: (0, 0)),
         ],
         out_specs=[hbm, hbm],

@@ -212,6 +212,28 @@ def test_pallas_tm_backend_refuses_bank_over_vmem_budget(data):
                RuntimeConfig(tm_backend="pallas"))
 
 
+@pytest.mark.parametrize("partitionable", [True, False],
+                         ids=["partitionable", "original"])
+def test_pallas_tm_backend_logs_coin_draws_once(partitionable, data,
+                                                caplog):
+    """An engine on the fused kernels says once, at init, whether the
+    epoch draws hash one coin plane a sample for both roles — on for the
+    partitionable threefry, off for the original stream; the reference
+    backend draws no coin planes and says nothing."""
+    import logging
+    caplog.set_level(logging.INFO, logger="repro.fl.runtime.engine")
+    with jax.threefry_partitionable(partitionable):
+        Engine(TPFLStrategy(TM_CFG, local_epochs=1), data,
+               RuntimeConfig(tm_backend="ref"))
+        assert not [r for r in caplog.records if "coin" in r.getMessage()]
+        Engine(TPFLStrategy(TM_CFG, local_epochs=1), data,
+               RuntimeConfig(tm_backend="pallas"))
+    said = [r.getMessage() for r in caplog.records
+            if "coin" in r.getMessage()]
+    assert len(said) == 1
+    assert ("merged" in said[0]) == partitionable
+
+
 # ---------------------------------------------------------------------------
 # conf_threshold byte metering: masked uploads ship nothing
 # ---------------------------------------------------------------------------

@@ -117,6 +117,37 @@ def test_int_threshold_matches_uniform_compare(p):
     assert (a == b).all()
 
 
+@pytest.mark.parametrize("partitionable", [True, False],
+                         ids=["partitionable", "original"])
+@pytest.mark.parametrize("m,L", [(6, 130), (7, 130)])
+def test_merged_coin_plane_matches_role_bits(m, L, partitionable):
+    """``epoch_draws`` hands the epoch kernel one coin plane a sample:
+    each even row is the target role's ``k_s1`` / ``k_s2`` words, each
+    odd row the negative role's, compared as the reference trainer
+    does.  ``offsets`` and ``act`` are the reference key discipline's
+    too.  Pinned for an odd and an even clause count, a lane-unaligned
+    L, and both threefry streams (hashed once vs drawn per role)."""
+    S, C, p_inc, p_dec = 5, 4, 0.9, 0.1
+    t_inc, t_dec = draws.int_threshold(p_inc), draws.int_threshold(p_dec)
+    key = jax.random.PRNGKey(11)
+    with jax.threefry_partitionable(partitionable):
+        assert draws.merged_coins() == partitionable
+        offs, act, coin = jax.jit(
+            lambda k: draws.epoch_draws(k, S, m, L, C, p_inc, p_dec))(key)
+        assert coin.shape == (S, m, L) and coin.dtype == jnp.int8
+        for i, k in enumerate(jax.random.split(key, S)):
+            k_neg, k_t, k_n = jax.random.split(k, 3)
+            assert offs[i] == jax.random.randint(k_neg, (), 1, C)
+            for role, kr in enumerate((k_t, k_n)):
+                k_act, k_s1, k_s2 = jax.random.split(kr, 3)
+                assert (act[i, role] == draws.act_bits(k_act, (m,))).all()
+                h1 = jax.random.bits(k_s1, (m, L), jnp.uint32) >> 9
+                h2 = jax.random.bits(k_s2, (m, L), jnp.uint32) >> 9
+                want = ((h1 < t_inc).astype(jnp.int8)
+                        + 2 * (h2 < t_dec).astype(jnp.int8))
+                assert (coin[i, role::2] == want[role::2]).all(), (i, role)
+
+
 @pytest.mark.parametrize("T", [1, 15, 40, 1000])
 def test_activation_table_matches_bernoulli(T):
     """Clause resampling compares the 23-bit draw against the host-built

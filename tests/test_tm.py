@@ -116,12 +116,17 @@ def test_kernel_path_equals_jnp_path():
     assert (pa.weights == pb.weights).all()
 
 
+@pytest.mark.parametrize("partitionable", [True, False],
+                         ids=["partitionable", "original"])
 @pytest.mark.parametrize("epochs", [1, 2])
 @pytest.mark.parametrize("seed", range(2))
-def test_kernel_train_bit_identical_at_unaligned_shapes(epochs, seed):
+def test_kernel_train_bit_identical_at_unaligned_shapes(epochs, seed,
+                                                         partitionable):
     """Full jit'd train through the fused epoch kernel at tile-unaligned
     shapes (L = 130, C·m = 99 — neither a multiple of 128): params must
-    equal the reference scan bit for bit, not just single-op parity."""
+    equal the reference scan bit for bit, not just single-op parity —
+    under both threefry streams (one merged coin plane a sample, or both
+    roles' planes drawn and picked by row parity)."""
     cfg = tm.TMConfig(n_classes=3, n_clauses=33, n_features=65,
                       n_states=63, s=3.0, T=15)
     kcfg = dataclasses.replace(cfg, use_kernel=True)
@@ -130,15 +135,19 @@ def test_kernel_train_bit_identical_at_unaligned_shapes(epochs, seed):
     p = tm.init_params(cfg, kp)
     x = (jax.random.uniform(kx, (23, cfg.n_features)) < 0.4).astype(jnp.int32)
     y = jax.random.randint(ky, (23,), 0, cfg.n_classes)
-    pa = tm.train(p, x, y, kt, cfg, epochs=epochs)
-    pb = tm.train(p, x, y, kt, kcfg, epochs=epochs)
+    with jax.threefry_partitionable(partitionable):
+        pa = tm.train(p, x, y, kt, cfg, epochs=epochs)
+        pb = tm.train(p, x, y, kt, kcfg, epochs=epochs)
     assert (pa.ta_state == pb.ta_state).all()
     assert (pa.weights == pb.weights).all()
 
 
-def test_batched_entry_points_bit_identical_to_vmap(seed=0):
+@pytest.mark.parametrize("partitionable", [True, False],
+                         ids=["partitionable", "original"])
+def test_batched_entry_points_bit_identical_to_vmap(partitionable, seed=0):
     """The client-batched kernel entry points (one launch for a stacked
-    cohort) must match the vmapped per-client reference bit for bit."""
+    cohort) must match the vmapped per-client reference bit for bit,
+    under both threefry streams."""
     cfg = tm.TMConfig(n_classes=3, n_clauses=33, n_features=65,
                       n_states=63, s=3.0, T=15)
     kcfg = dataclasses.replace(cfg, use_kernel=True)
@@ -151,8 +160,9 @@ def test_batched_entry_points_bit_identical_to_vmap(seed=0):
         jnp.int32)
     ys = jax.random.randint(ky, (N, S), 0, cfg.n_classes)
     keys = jax.random.split(kt, N)
-    pa = tm.train_batched(params, xs, ys, keys, cfg, epochs=2)
-    pb = tm.train_batched(params, xs, ys, keys, kcfg, epochs=2)
+    with jax.threefry_partitionable(partitionable):
+        pa = tm.train_batched(params, xs, ys, keys, cfg, epochs=2)
+        pb = tm.train_batched(params, xs, ys, keys, kcfg, epochs=2)
     assert (pa.ta_state == pb.ta_state).all()
     assert (pa.weights == pb.weights).all()
     xe = (jax.random.uniform(ke, (N, 9, cfg.n_features)) < 0.4).astype(

@@ -79,7 +79,7 @@ def test_train_epoch_compiles_within_vmem(one_chip):
         *a, n_states=63, T=40, interpret=False), one_chip,
         ((n, C, M, L), jnp.int32), ((n, C, M), jnp.int32),
         ((n, s, L), jnp.int32), ((n, s, 2), jnp.int32),
-        ((n, s, 2, M), jnp.int32), ((n, s, 2, M, L), jnp.int8))
+        ((n, s, 2, M), jnp.int32), ((n, s, M, L), jnp.int8))
 
 
 def test_train_batched_scopes_leave_the_chip_program_unchanged(
