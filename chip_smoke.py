@@ -27,9 +27,11 @@ Phases (one chip):
 
 ``--four-chips`` runs only the shard-mapped round (``--mesh clients:4
 --collective gather``) and the in-process round it must equal bit for
-bit.  The last line of standard output is one JSON object,
-``{"ok": true, "device": {...}}``; any failed phase exits nonzero
-before it.
+bit.  The shard-mapped engine places its population on the mesh at
+``init`` (client state and data five clients a chip, the server state
+replicated), and its rounds leave it there.  The last line of standard
+output is one JSON object, ``{"ok": true, "device": {...}}``; any
+failed phase exits nonzero before it.
 """
 from __future__ import annotations
 

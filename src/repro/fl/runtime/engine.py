@@ -65,7 +65,9 @@ plus client-major arrays (``client_state``, data, per-client keys) and
 hands them to the executor, which decides whether client-major means
 "vmapped on one device" or "one block per mesh shard".  Everything the
 engine reads back from an executor (server, counts, report scalars) is
-replicated/host-visible.
+replicated/host-visible.  On the shard-mapped backend the engine places
+the population once — data at construction, client state and server at
+``init`` (``ShardMapExecutor.place``) — so rounds find it on the mesh.
 """
 from __future__ import annotations
 
@@ -351,6 +353,10 @@ class Engine:
             self.executor = ShardMapExecutor(
                 mesh=mesh, axis=cfg.mesh_axis,
                 collective=cfg.mesh_collective)
+            if not self._streaming:
+                # the population's data lives on the mesh from here on,
+                # one block of clients a shard, where every round reads it
+                self.data = self.executor.place(data)
         else:
             self.executor = InProcessExecutor()
         # uniform full participation samples idx = arange(N): skip the
@@ -408,6 +414,11 @@ class Engine:
             ef = jnp.zeros((self.n, self.strategy.n_slots, d), jnp.float32)
         else:
             ef = jnp.zeros((0, 0, 0), jnp.float32)
+        if self.cfg.backend == "shardmap":
+            # placed where the round programs return them, so round 0
+            # compiles nothing that round 1 does not reuse
+            cs = self.executor.place(cs)
+            server = self.executor.place(server, replicated=True)
         return EngineState(
             round_idx=jnp.zeros((), jnp.int32),
             client_state=cs, server=server,

@@ -51,7 +51,11 @@ Sharding contract, program by program
 Client-major arrays (client state, per-client data, rng keys, slot
 ids, arrival masks, uploads) are sharded ``P(axis)`` — one contiguous
 block per shard; the server matrix, cluster counts, the async buffer
-lanes, and the round index are replicated ``P()``.
+lanes, and the round index are replicated ``P()``.  Under
+``backend="shardmap"`` the engine puts the population's client state
+and data on the mesh once, at init, and the server state replicated
+(:meth:`ShardMapExecutor.place`): the programs then take them where
+they lie, and round 0 runs the same executable as every later round.
 
 * ``_train_program``       — per-shard vmap of ``client_step``; slot
   matrix replicated in, per-shard (state, uploads) out.  No collective.
@@ -93,7 +97,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import clustering
 from repro.fl import masked_collectives
@@ -260,12 +264,15 @@ def _unpad(tree, n: int):
 def _sharded_masked_mean(vals, slots, n_slots, axis, collective, n_valid):
     """Per-shard uploads → replicated raw (mean, counts), one
     collective.  Empty-slot retention is ``server_update``'s decision —
-    this returns the bare per-slot mean (zeros where empty)."""
-    if collective == "gather":
-        return masked_collectives.clustered_mean_gathered(
-            vals, slots, n_slots, axis, n_valid=n_valid)
-    return masked_collectives.clustered_weighted_mean_sharded(
-        vals, slots, jnp.ones_like(slots, jnp.float32), n_slots, axis)
+    this returns the bare per-slot mean (zeros where empty).  Its device
+    ops carry the scope ``mesh.collective`` in their op_name, in the
+    fused round and the staged ``_agg_program`` alike."""
+    with jax.named_scope("mesh.collective"):
+        if collective == "gather":
+            return masked_collectives.clustered_mean_gathered(
+                vals, slots, n_slots, axis, n_valid=n_valid)
+        return masked_collectives.clustered_weighted_mean_sharded(
+            vals, slots, jnp.ones_like(slots, jnp.float32), n_slots, axis)
 
 
 def _sync_round_body(strategy, axis: str, collective: str,
@@ -615,6 +622,25 @@ class ShardMapExecutor:
         self.axis = axis
         self.collective = collective
         self.n_shards = int(mesh.shape[axis])
+
+    def place(self, tree, replicated: bool = False):
+        """Put ``tree`` on the mesh where the round programs keep it, so
+        that no round moves it and round 0 runs the executable of every
+        later round: client-major arrays one block of clients a shard
+        (``P(axis)``), a server pytree (``replicated=True``) whole on
+        every shard (``P()``).
+
+        A leading axis the mesh does not divide cannot be split in equal
+        blocks (JAX shards no axis unevenly), so such a tree is
+        replicated instead: the programs pad it per call
+        (``_pad_tree``) and slice their result back, and that slice of a
+        sharded array comes back replicated — the layout placed here."""
+        def put(a):
+            even = a.ndim > 0 and a.shape[0] % self.n_shards == 0
+            spec = P(self.axis) if even and not replicated else P()
+            return jax.device_put(a, NamedSharding(self.mesh, spec))
+
+        return jax.tree.map(put, tree)
 
     def train(self, strategy, sub_cs, server, sub_data, keys):
         k = keys.shape[0]
