@@ -276,23 +276,22 @@ def train_epoch(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
     """One sample-sequential pass over (xs, ys) — the paper's local epoch.
 
     On the kernel path the whole epoch is a single fused ``pallas_call``
-    (clause banks stay in VMEM across samples) with the randomness
-    pre-generated under the reference key discipline — bit-identical to
+    (clause banks stay in VMEM across samples) under the reference key
+    discipline, the Type-I coins hashed inside it — bit-identical to
     the scan below, pinned by ``tests/test_tm.py``.
     """
     if cfg.use_kernel and cfg.weighted:
         from repro.kernels import draws as kdraws
         from repro.kernels import ops as kops
         p_inc, p_dec = _feedback_probs(cfg)
-        offs, act, coin = kdraws.epoch_draws(
-            key, xs.shape[0], cfg.n_clauses, cfg.n_literals,
-            cfg.n_classes, p_inc, p_dec)
+        offs, act, coin_keys = kdraws.epoch_draws(
+            key, xs.shape[0], cfg.n_clauses, cfg.n_classes)
         ys32 = ys.astype(jnp.int32)
         cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes], axis=-1)
         ta, w = kops.train_epoch_fused(
             params.ta_state[None], params.weights[None],
-            literals(xs)[None], cls2[None], act[None], coin[None],
-            n_states=cfg.n_states, T=cfg.T)
+            literals(xs)[None], cls2[None], act[None], coin_keys[None],
+            n_states=cfg.n_states, T=cfg.T, p_inc=p_inc, p_dec=p_dec)
         return TMParams(ta_state=ta[0], weights=w[0])
 
     def step(p, inp):
@@ -344,17 +343,17 @@ def train_batched(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
 
     def epoch_body(carry, ek):
         ta, w = carry
-        # the scope names the epoch's randomness in the device trace
-        # (op metadata only: the compiled program is the same)
+        # the scope names the epoch's draws and coin keys in the device
+        # trace (op metadata only: the compiled program is the same)
         with jax.named_scope("tm.draws"):
-            offs, act, coin = jax.vmap(
+            offs, act, coin_keys = jax.vmap(
                 lambda k: kdraws.epoch_draws(k, n_samples, cfg.n_clauses,
-                                             cfg.n_literals, cfg.n_classes,
-                                             p_inc, p_dec))(ek)
+                                             cfg.n_classes))(ek)
             cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes],
                              axis=-1)
-        ta, w = kops.train_epoch_fused(ta, w, lits, cls2, act, coin,
-                                       n_states=cfg.n_states, T=cfg.T)
+        ta, w = kops.train_epoch_fused(ta, w, lits, cls2, act, coin_keys,
+                                       n_states=cfg.n_states, T=cfg.T,
+                                       p_inc=p_inc, p_dec=p_dec)
         return (ta, w), None
 
     (ta, w), _ = jax.lax.scan(epoch_body,

@@ -283,10 +283,9 @@ class Engine:
                     f"{train_epoch.VMEM_BUDGET} B budget — run "
                     f"tm_backend='ref' or fewer clauses")
             logging.getLogger(__name__).info(
-                "tm_backend='pallas': epoch coin planes %s",
-                "merged (one threefry plane a sample for both roles)"
-                if draws.merged_coins() else
-                "drawn per role (non-partitionable threefry)")
+                "tm_backend='pallas': Type-I coins hashed in the epoch "
+                "kernel, one threefry word an automaton a sample "
+                "(%s stream)", draws.threefry_stream())
             strategy = dataclasses.replace(
                 strategy, tm_cfg=dataclasses.replace(
                     strategy.tm_cfg, use_kernel=True))

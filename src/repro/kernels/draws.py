@@ -1,49 +1,51 @@
-"""Pre-generated feedback randomness for the fused TM epoch kernel.
+"""Feedback randomness for the fused TM epoch kernel.
 
 The reference trainer (:mod:`repro.core.tm`) draws its stochastic
-choices *inside* the per-sample scan — fine for jnp, but a Pallas kernel
-body cannot host the threefry hash portably (counter-based PRNG inside a
-Mosaic kernel is TPU-generation-specific).  So the fused epoch kernel
-consumes the whole epoch's randomness as plain arrays, generated here
-with exactly the reference key discipline:
+choices *inside* the per-sample scan, with exactly this key discipline:
 
 * per sample ``i``: ``k_neg, k_t, k_n = split(keys[i], 3)`` where
   ``keys = split(epoch_key, n_samples)`` — the negative class is
   ``(y + randint(k_neg, 1, C)) % C``;
 * per feedback role (target ``k_t`` / negative ``k_n``):
   ``k_act, k_s1, k_s2 = split(k, 3)`` — clause-activation draws from
-  ``k_act``, the Type-I increment/decrement coin flips from ``k_s1`` /
-  ``k_s2``.
+  ``k_act``, the Type-I increment/decrement coins from ``k_s1`` /
+  ``k_s2`` (``uniform(k_s1, (m, L))`` / ``uniform(k_s2, (m, L))``).
 
-The coin flips are stored pre-compared, two bits per (clause, literal)
-in one int8 plane (bit 1 = increment draw hit, bit 2 = decrement draw
-hit), via the **int-domain compare trick**: jax's float32
+:func:`epoch_draws` makes that discipline's small draws for a whole
+epoch outside the kernel — the negative-class offsets and the ``(2, m)``
+activation draws — and hands the kernel the *key words* of each
+sample's four coin keys in place of the coins themselves.  The kernel
+hashes the coins (:func:`coin_word`).
+
+**One word an automaton, the reference's word.**  Under both threefry
+streams every word of ``bits(k, (m, L))`` is a function of its own
+index, so the kernel can hash word ``(r, l)`` alone:
+
+* partitionable (``jax_threefry_partitionable``, JAX's default): word
+  ``j = r·L + l`` is ``x0 ^ x1`` of ``threefry2x32(k, (0, j))``;
+* original, ``n = m·L``, ``h = ⌈n/2⌉``: word ``j < h`` is ``x0`` of
+  ``threefry2x32(k, (j, j + h))``, word ``j ≥ h`` is ``x1`` of
+  ``threefry2x32(k, (j − h, j))`` (an odd ``n`` pads its last pair with
+  a zero counter).
+
+A Type-I automaton reads at most one of its two coins: the increment
+coin (``k_s1``) where its clause fired and its literal is true, the
+decrement coin (``k_s2``) everywhere else.  Type I goes to the even
+(positive-polarity) clauses on the target role and to the odd ones on
+the negative role, so row parity names the role, and the clause's
+``fired`` bit, known only inside the kernel, names the key.  So the
+kernel hashes one word an automaton a sample, with that key, and no
+coin the feedback does not read is drawn.  :func:`threefry_stream`
+says which stream, from ``jax.config`` at trace time.
+
+Both draws are compared in the integer domain: jax's float32
 ``uniform(k, shape)`` is exactly ``(bits(k) >> 9) * 2**-23``, so
 
     uniform(k, shape) < p   ⟺   (bits(k) >> 9) < ceil(float32(p) · 2²³)
 
 bit-for-bit (both sides of the float compare are exact f32 values;
 :func:`int_threshold` is pinned against ``jax.random.uniform`` by
-``tests/test_kernels.py``).  This skips the uint32→f32 convert and the
-f32 compare for the (m, L) coin planes — the dominant draw volume —
-while staying bit-identical to the reference path.
-
-**One coin plane a sample, not one a role.**  Both coin bits are read
-only on Type-I rows, and Type I goes to the even (positive-polarity)
-clauses on the target role and to the odd ones on the negative role.
-So a single (m, L) plane carries both roles: row ``r`` holds the coins
-of the role whose Type-I clauses own it — the target's ``k_s1`` /
-``k_s2`` words for even ``r``, the negative's for odd ``r``.  Under the
-partitionable threefry (``jax_threefry_partitionable``, the default),
-word ``(r, l)`` of ``bits(k, (m, L))`` is ``x0 ^ x1`` of
-``threefry2x32(k, (0, r·L + l))``, a function of its own index alone,
-so the plane is hashed once with each row's key chosen by parity: half
-the hashes of drawing both roles' planes, and every word the kernel
-reads is the word it would read from them.  Under the original threefry
-a word pairs with the one half a plane away, which need not share its
-row's parity; there both roles' planes are drawn in full and their rows
-picked by parity — the same plane, at the old cost.
-:func:`merged_coins` says which, from ``jax.config`` at trace time.
+``tests/test_kernels.py``).
 
 The clause-activation draws use the same trick.  Their probability
 ``p_act = (T ∓ v) / 2T`` depends on the clipped vote ``v``, so it can only
@@ -89,73 +91,68 @@ def activation_thresholds(T: int) -> np.ndarray:
     return np.array([int_threshold(float(q)) for q in p], dtype=np.int32)
 
 
-def merged_coins() -> bool:
-    """Whether :func:`epoch_draws` hashes each coin plane once for both
-    roles (partitionable threefry) or draws both roles' planes and picks
-    rows by parity (any other stream).  Read at trace time."""
-    return (jax.config.jax_threefry_partitionable
-            and jax.config.jax_default_prng_impl == "threefry2x32")
+def threefry_stream() -> str:
+    """The stream ``jax.random.bits`` follows: ``"partitionable"`` or
+    ``"original"`` threefry.  Read at trace time; any other PRNG
+    implementation is refused, since :func:`coin_word` hashes threefry's
+    words."""
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        raise NotImplementedError(
+            "the TM epoch kernel hashes threefry2x32 words; the default "
+            f"PRNG is {jax.config.jax_default_prng_impl!r}")
+    return ("partitionable" if jax.config.jax_threefry_partitionable
+            else "original")
 
 
-def _coin_words(k_even: jax.Array, k_odd: jax.Array, m: int, L: int
-                ) -> jnp.ndarray:
-    """(m, L) uint32: row ``r`` is row ``r`` of ``bits(k, (m, L))`` with
-    ``k = k_even`` for even ``r`` and ``k_odd`` for odd ``r`` — the
-    partitionable threefry's word ``(r, l)``, hashed once."""
-    if m * L >= 2 ** 32:
-        raise ValueError(f"a coin plane of {m}×{L} words needs the "
-                         "counter's high word, which is taken as 0")
-    row = jax.lax.broadcasted_iota(jnp.uint32, (m, L), 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, (m, L), 1)
-    even = row % 2 == 0
-    k_even = jax.random.key_data(k_even)
-    k_odd = jax.random.key_data(k_odd)
-    k1 = jnp.where(even, k_even[0], k_odd[0])
-    k2 = jnp.where(even, k_even[1], k_odd[1])
-    x0, x1 = threefry2x32_p.bind(k1, k2, jnp.zeros_like(row), row * L + col)
-    return x0 ^ x1
+def coin_word(k1, k2, j, n: int) -> jnp.ndarray:
+    """Word ``j`` of the ``n`` words of ``jax.random.bits(k, shape,
+    uint32)`` (``n = prod(shape)``, ``j`` a flat row-major int32 index)
+    for the key whose ``key_data`` is ``(k1, k2)`` (uint32, broadcast to
+    ``j``'s shape), under :func:`threefry_stream`.  The epoch kernel
+    hashes its coins with it."""
+    if n >= 2 ** 31:
+        raise ValueError(f"{n} words need counters past int32")
+    k1 = jnp.broadcast_to(k1, j.shape)
+    k2 = jnp.broadcast_to(k2, j.shape)
+    if threefry_stream() == "partitionable":
+        x0, x1 = threefry2x32_p.bind(k1, k2, jnp.zeros_like(j, jnp.uint32),
+                                     j.astype(jnp.uint32))
+        return x0 ^ x1
+    h = (n + 1) // 2
+    lo = j < h
+    c0 = jnp.where(lo, j, j - h)
+    c1 = jnp.where(lo, j + h, j)
+    if n % 2:                       # the odd count's pad counter is 0
+        c1 = jnp.where(c1 == n, 0, c1)
+    x0, x1 = threefry2x32_p.bind(k1, k2, c0.astype(jnp.uint32),
+                                 c1.astype(jnp.uint32))
+    return jnp.where(lo, x0, x1)
 
 
 def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
-                n_literals: int, n_classes: int,
-                p_inc: float, p_dec: float):
-    """One epoch's randomness, reference key discipline (see module doc).
+                n_classes: int):
+    """One epoch's draws outside the kernel, reference key discipline
+    (see module doc).
 
-    Returns ``(offsets, act, coin)``:
+    Returns ``(offsets, act, coin_keys)``:
 
-    * ``offsets`` (S,) int32 — negative-class offset in [1, C);
-    * ``act``     (S, 2, m) int32 — clause-activation draws as 23-bit
+    * ``offsets``   (S,) int32 — negative-class offset in [1, C);
+    * ``act``       (S, 2, m) int32 — clause-activation draws as 23-bit
       integers (:func:`act_bits`), role 0 = target, 1 = negative;
-    * ``coin``    (S, m, L) int8 — bit 1: Type-I increment draw hit
-      (``u < p_inc``), bit 2: decrement draw hit (``u < p_dec``); even
-      rows from the target role's coin keys, odd rows from the
-      negative role's.
+    * ``coin_keys`` (S, 8) uint32 — ``key_data`` of the target's
+      ``k_s1``, ``k_s2``, then the negative's ``k_s1``, ``k_s2``: the
+      keys the kernel hashes the Type-I coins from.
     """
-    m, L = n_clauses, n_literals
-    t_inc = int_threshold(p_inc)
-    t_dec = int_threshold(p_dec)
-    keys = jax.random.split(key, n_samples)
-    merged = merged_coins()
-    even = (jnp.arange(m) % 2 == 0)[:, None]
+    m = n_clauses
 
-    def plane(kt, kn):
-        """23-bit words: the target's (kt) on even rows, kn's on odd."""
-        if merged:
-            return _coin_words(kt, kn, m, L) >> 9
-        return jnp.where(even, jax.random.bits(kt, (m, L), jnp.uint32),
-                         jax.random.bits(kn, (m, L), jnp.uint32)) >> 9
-
-    def per_sample(_, k):
+    def per_sample(k):
         k_neg, k_t, k_n = jax.random.split(k, 3)
         ka_t, k1_t, k2_t = jax.random.split(k_t, 3)
         ka_n, k1_n, k2_n = jax.random.split(k_n, 3)
-        h1 = plane(k1_t, k1_n)
-        h2 = plane(k2_t, k2_n)
-        coin = ((h1 < t_inc).astype(jnp.int8)
-                + 2 * (h2 < t_dec).astype(jnp.int8))
         act = jnp.stack([act_bits(ka_t, (m,)), act_bits(ka_n, (m,))])
         off = jax.random.randint(k_neg, (), 1, n_classes)
-        return 0, (off.astype(jnp.int32), act, coin)
+        words = jnp.concatenate([jax.random.key_data(c)
+                                 for c in (k1_t, k2_t, k1_n, k2_n)])
+        return off.astype(jnp.int32), act, words
 
-    _, (offsets, act, coin) = jax.lax.scan(per_sample, 0, keys)
-    return offsets, act, coin
+    return jax.vmap(per_sample)(jax.random.split(key, n_samples))

@@ -55,12 +55,13 @@ def fused_votes_batched(include: jnp.ndarray, lits: jnp.ndarray,
 
 def train_epoch_fused(ta: jnp.ndarray, w: jnp.ndarray, lits: jnp.ndarray,
                       cls2: jnp.ndarray, act: jnp.ndarray,
-                      coin: jnp.ndarray, *, n_states: int, T: int
+                      coin_keys: jnp.ndarray, *, n_states: int, T: int,
+                      p_inc: float, p_dec: float
                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One fused training epoch over stacked clients; see train_epoch.py."""
-    return _te.train_epoch_pallas(ta, w, lits, cls2, act, coin,
-                                  n_states=n_states, T=T,
-                                  interpret=interpret_mode())
+    return _te.train_epoch_pallas(ta, w, lits, cls2, act, coin_keys,
+                                  n_states=n_states, T=T, p_inc=p_inc,
+                                  p_dec=p_dec, interpret=interpret_mode())
 
 
 def ta_update(ta: jnp.ndarray, lit: jnp.ndarray, fired: jnp.ndarray,

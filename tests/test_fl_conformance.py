@@ -216,10 +216,10 @@ def test_pallas_tm_backend_refuses_bank_over_vmem_budget(data):
                          ids=["partitionable", "original"])
 def test_pallas_tm_backend_logs_coin_draws_once(partitionable, data,
                                                 caplog):
-    """An engine on the fused kernels says once, at init, whether the
-    epoch draws hash one coin plane a sample for both roles — on for the
-    partitionable threefry, off for the original stream; the reference
-    backend draws no coin planes and says nothing."""
+    """An engine on the fused kernels says once, at init, that the
+    epoch kernel hashes the Type-I coins itself, and under which
+    threefry stream; the reference backend hashes no coins in a kernel
+    and says nothing."""
     import logging
     caplog.set_level(logging.INFO, logger="repro.fl.runtime.engine")
     with jax.threefry_partitionable(partitionable):
@@ -231,7 +231,9 @@ def test_pallas_tm_backend_logs_coin_draws_once(partitionable, data,
     said = [r.getMessage() for r in caplog.records
             if "coin" in r.getMessage()]
     assert len(said) == 1
-    assert ("merged" in said[0]) == partitionable
+    assert "hashed in the epoch kernel" in said[0]
+    stream = "partitionable" if partitionable else "original"
+    assert f"({stream} stream)" in said[0]
 
 
 # ---------------------------------------------------------------------------

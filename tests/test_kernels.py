@@ -107,7 +107,7 @@ def test_fused_votes_batched_kernel_vs_ref(C, m, L, B, N, wmax, seed):
 
 @pytest.mark.parametrize("p", [0.2, 1.0 / 3.0, 0.8, 2.0 / 3.0, 1e-7, 1.0])
 def test_int_threshold_matches_uniform_compare(p):
-    """The fused epoch kernel consumes pre-compared coin flips via the
+    """The fused epoch kernel compares its hashed coin words via the
     int-domain trick (bits >> 9 < ceil(f32(p)·2²³)); pin it against the
     f32 uniform compare the reference trainer performs, including
     non-representable thresholds like 1/3 and s=3's p_inc=2/3."""
@@ -120,32 +120,54 @@ def test_int_threshold_matches_uniform_compare(p):
 @pytest.mark.parametrize("partitionable", [True, False],
                          ids=["partitionable", "original"])
 @pytest.mark.parametrize("m,L", [(6, 130), (7, 130)])
-def test_merged_coin_plane_matches_role_bits(m, L, partitionable):
-    """``epoch_draws`` hands the epoch kernel one coin plane a sample:
-    each even row is the target role's ``k_s1`` / ``k_s2`` words, each
-    odd row the negative role's, compared as the reference trainer
-    does.  ``offsets`` and ``act`` are the reference key discipline's
-    too.  Pinned for an odd and an even clause count, a lane-unaligned
-    L, and both threefry streams (hashed once vs drawn per role)."""
-    S, C, p_inc, p_dec = 5, 4, 0.9, 0.1
-    t_inc, t_dec = draws.int_threshold(p_inc), draws.int_threshold(p_dec)
+def test_kernel_coin_word_is_the_reference_word(m, L, partitionable):
+    """``epoch_draws`` hands the epoch kernel each sample's coin keys,
+    and the kernel hashes the word of automaton ``(r, l)`` with
+    ``draws.coin_word``: for the target's and the negative's ``k_s1``
+    and ``k_s2``, that word is ``bits(k_s, (m, L))[r, l]``, the word
+    the reference trainer's uniform reads.  ``offsets`` and ``act`` are
+    the reference key discipline's too.  Pinned for an odd and an even
+    clause count, a lane-unaligned L, and both threefry streams."""
+    S, C = 5, 4
     key = jax.random.PRNGKey(11)
+    row = jax.lax.broadcasted_iota(jnp.int32, (m, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (m, L), 1)
+    j = row * L + col                   # automaton (r, l)'s counter
     with jax.threefry_partitionable(partitionable):
-        assert draws.merged_coins() == partitionable
-        offs, act, coin = jax.jit(
-            lambda k: draws.epoch_draws(k, S, m, L, C, p_inc, p_dec))(key)
-        assert coin.shape == (S, m, L) and coin.dtype == jnp.int8
+        assert draws.threefry_stream() == (
+            "partitionable" if partitionable else "original")
+        offs, act, coin_keys = jax.jit(
+            lambda k: draws.epoch_draws(k, S, m, C))(key)
+        assert coin_keys.shape == (S, 8) and coin_keys.dtype == jnp.uint32
+        word = jax.jit(lambda k1, k2: draws.coin_word(k1, k2, j, m * L))
         for i, k in enumerate(jax.random.split(key, S)):
             k_neg, k_t, k_n = jax.random.split(k, 3)
             assert offs[i] == jax.random.randint(k_neg, (), 1, C)
             for role, kr in enumerate((k_t, k_n)):
                 k_act, k_s1, k_s2 = jax.random.split(kr, 3)
                 assert (act[i, role] == draws.act_bits(k_act, (m,))).all()
-                h1 = jax.random.bits(k_s1, (m, L), jnp.uint32) >> 9
-                h2 = jax.random.bits(k_s2, (m, L), jnp.uint32) >> 9
-                want = ((h1 < t_inc).astype(jnp.int8)
-                        + 2 * (h2 < t_dec).astype(jnp.int8))
-                assert (coin[i, role::2] == want[role::2]).all(), (i, role)
+                for c, k_s in enumerate((k_s1, k_s2)):
+                    kd = coin_keys[i, 4 * role + 2 * c:4 * role + 2 * c + 2]
+                    assert (kd == jax.random.key_data(k_s)).all()
+                    want = jax.random.bits(k_s, (m, L), jnp.uint32)
+                    assert (word(kd[0], kd[1]) == want).all(), (i, role, c)
+
+
+def test_coin_word_covers_an_odd_count_and_refuses_other_prngs():
+    """Under the original stream an odd word count pads its last
+    counter pair with 0; ``coin_word`` forms it so.  A PRNG other than
+    threefry has no such word, and the kernel's stream is refused."""
+    m, L = 3, 5
+    key = jax.random.PRNGKey(3)
+    j = jnp.arange(m * L, dtype=jnp.int32).reshape(m, L)
+    kd = jax.random.key_data(key)
+    for partitionable in (True, False):
+        with jax.threefry_partitionable(partitionable):
+            assert (draws.coin_word(kd[0], kd[1], j, m * L)
+                    == jax.random.bits(key, (m, L), jnp.uint32)).all()
+    with jax.default_prng_impl("rbg"):
+        with pytest.raises(NotImplementedError, match="threefry"):
+            draws.threefry_stream()
 
 
 @pytest.mark.parametrize("T", [1, 15, 40, 1000])

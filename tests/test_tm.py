@@ -120,14 +120,16 @@ def test_kernel_path_equals_jnp_path():
                          ids=["partitionable", "original"])
 @pytest.mark.parametrize("epochs", [1, 2])
 @pytest.mark.parametrize("seed", range(2))
+@pytest.mark.parametrize("m,o", [(33, 65), (14, 100)],
+                         ids=["L130", "L200"])
 def test_kernel_train_bit_identical_at_unaligned_shapes(epochs, seed,
-                                                         partitionable):
+                                                         partitionable, m, o):
     """Full jit'd train through the fused epoch kernel at tile-unaligned
-    shapes (L = 130, C·m = 99 — neither a multiple of 128): params must
-    equal the reference scan bit for bit, not just single-op parity —
-    under both threefry streams (one merged coin plane a sample, or both
-    roles' planes drawn and picked by row parity)."""
-    cfg = tm.TMConfig(n_classes=3, n_clauses=33, n_features=65,
+    shapes (L = 130 or 200, padded to 256; m = 33 or 14, padded to 40 or
+    16): params must equal the reference scan bit for bit, not just
+    single-op parity — under both threefry streams, the kernel hashing
+    each Type-I coin word from the unpadded plane's counter."""
+    cfg = tm.TMConfig(n_classes=3, n_clauses=m, n_features=o,
                       n_states=63, s=3.0, T=15)
     kcfg = dataclasses.replace(cfg, use_kernel=True)
     key = jax.random.PRNGKey(seed)
