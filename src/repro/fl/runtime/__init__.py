@@ -13,10 +13,14 @@ The subsystem that replaces the monolithic ``federation.run`` loop:
 * :mod:`repro.fl.runtime.codec` — quantized (int8/int4) + sparse-delta
   wire encoding of the uploaded vectors, with byte-exact metering
   (``len(buffer)``, not arithmetic).
-* :mod:`repro.fl.runtime.engine` — the orchestrated round engine: sync
-  barrier or async buffered aggregation (fixed-capacity *device* buffer,
-  masked validity, staleness-discounted averaging), jit-friendly
-  static-K gather/scatter of the sampled client sub-pytrees.
+* :mod:`repro.fl.runtime.engine` — the orchestrated round engine: the
+  client half, then the server half (``Engine.serve_uploads``, shared
+  with the transport runner) — sync barrier or async buffered
+  aggregation (fixed-capacity *device* buffer, masked validity,
+  staleness-discounted averaging).
+* :mod:`repro.fl.runtime.rows` — where the client rows live, behind one
+  ``gather`` / ``commit`` seam: resident device rows (static-K gather /
+  scatter of the sampled sub-pytrees) or the mmap client store.
 * :mod:`repro.fl.runtime.executors` — where a round's compute runs: the
   in-process vmap backend, or the shard-mapped ``clients``-mesh backend
   whose aggregation — sync masked mean *and* the async buffered update —

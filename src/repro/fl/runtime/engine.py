@@ -5,38 +5,35 @@ scheduler (who participates), a :class:`~repro.fl.runtime.strategy.Strategy`
 (what a round means), the wire codec (what actually crosses the network,
 metered byte-exact), and round-granular checkpointing.
 
-Round anatomy (sync mode)
--------------------------
-1. ``scheduler.sample`` picks K-of-N clients (K static → the gather of the
-   sampled client sub-pytree keeps the round a single compiled program),
-   plus dropout and straggler draws.
-2. The K clients run ``strategy.client_step`` (vmapped).  Per-client rng
-   keys are ``split(round_key, N)[idx]``, so any participation pattern
-   draws from the same per-client key stream as the full-population
-   legacy loop — full participation reproduces it bit-for-bit.
+Round anatomy
+-------------
+One round is two halves around one row seam (``docs/architecture.md``):
+
+1. ``scheduler.sample`` picks K-of-N clients (K static, so the round's
+   programs compile once), plus dropout and straggler draws.  A sync
+   barrier treats uploads that miss the deadline (staleness > 0) like
+   drops.
+2. **Gather** through the rows object (:mod:`repro.fl.runtime.rows`):
+   resident device rows or the mmap store.  Per-client rng keys are
+   ``split(round_key, N)[idx]``, so any participation pattern draws from
+   the full-population key stream.
+3. **Client half**: the K clients run ``strategy.client_step`` from the
+   broadcast rows as they hold them, and each surviving upload is
+   *encoded to real bytes* and decoded back, so lossy codecs perturb the
+   math as in deployment and metering sees the client-proposed tags.
    *Where* the step runs is the executor's business
-   (:mod:`repro.fl.runtime.executors`): in-process vmap (default), or
-   shard-mapped over a ``clients`` mesh axis (``backend="shardmap"``)
-   with aggregation lowered to a single masked collective — one
-   compiled sharded program per round on the identity wire.  The
-   conformance suite pins both backends bit-identical.
-3. Each surviving upload is *encoded to real bytes* by the codec (and
-   decoded back before aggregation, so lossy codecs perturb the math
-   exactly as they would in deployment).  A sync barrier treats uploads
-   that miss the deadline (staleness > 0) like drops.
-4. **Server-side assignment** (server-state API v2): if the strategy
-   defines an ``assign`` hook, the slot id of every decoded upload is
-   recomputed here — FLIS derives cluster membership per round from
-   inference similarity on its probe set.  Metering (step 3) always
-   uses the *client-proposed* tags: what crossed the wire crossed the
-   wire.  Strategies without the hook keep their proposed ids.
-5. Per-slot masked mean aggregation (slot −1 contributes nothing),
-   folded into the strategy-owned :class:`ServerState` by its
-   ``server_update`` hook — the default keeps empty slots' previous
-   rows bit-for-bit, per Alg. 2.
-6. Broadcast: each surviving participant applies its slot's new server
-   row; dropped/straggling clients keep their pre-round state.  Download
-   bytes are metered from the encoded broadcast frames.
+   (:mod:`repro.fl.runtime.executors`): in-process vmap, or shard-mapped
+   over a ``clients`` mesh axis.  On the identity wire with the sync
+   barrier and the identity gather, the shard-mapped executor runs the
+   whole round as one compiled program instead (the fused shape).
+4. **Server half** (:meth:`Engine.serve_uploads`, which the transport
+   runner calls too): the strategy's ``assign`` hook if any, the
+   per-slot masked mean folded in by ``server_update`` (default: Alg. 2
+   retention), the metered broadcast of each aggregated slot row to the
+   clients it came from, and the sparse wire's reference tracking.
+5. **Commit** through the rows object: the merged cohort back to its
+   store, evaluation, the next :class:`EngineState`; then the
+   :class:`RoundReport`.
 
 Async buffered mode
 -------------------
@@ -55,8 +52,8 @@ insert/evict scan and the maturity gate live in
 whole update runs inside ``shard_map`` on the ``clients`` mesh axis
 with the staleness-discounted mean lowered through
 :mod:`repro.fl.masked_collectives`.  ``async_buffer="host"`` keeps the
-original numpy insert loop as the in-process reference the conformance
-suite pins the device path against, bit for bit.  See
+numpy insert (:func:`host_buffer_insert`) as the in-process reference
+the conformance suite pins the device path against, bit for bit.  See
 ``docs/async-runtime.md`` for the lane layout and design.
 
 Sharding contract: the engine itself never runs inside ``shard_map`` —
@@ -74,7 +71,6 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import logging
-import tempfile
 from typing import Any, NamedTuple
 
 import jax
@@ -89,10 +85,10 @@ from repro.fl.runtime.codec import CodecConfig, decode, ef_encode, encode
 from repro.fl.runtime import executors
 from repro.fl.runtime.executors import (COLLECTIVES, InProcessExecutor,
                                         ShardMapExecutor)
+from repro.fl.runtime.rows import Cohort, ResidentRows, StoreRows
 from repro.fl.runtime.scheduler import (Participation, Scheduler,
                                         SchedulerConfig)
 from repro.fl.runtime.strategy import (DOWNLOADS, ServerState,
-                                       ensure_server_state,
                                        resolve_server_update)
 from repro.fl.store.client_store import ClientStore
 
@@ -257,6 +253,62 @@ class RoundReport(NamedTuple):
     observed_staleness: Any = None
 
 
+class Served(NamedTuple):
+    """The round's server half, whichever shape ran it: what the commit,
+    the next state and the report read."""
+    server: ServerState
+    counts: jax.Array       # (n_slots,) uploads folded into each slot
+    applied: jax.Array      # (K, j) slot row each receiver applies, or −1
+    down_bc: int            # download bytes, one frame per populated slot
+    down_pc: int            # download bytes, summed over the receivers
+    refs: tuple             # the advanced (ref_vecs, ref_round)
+    buf: tuple              # the async buffer's six lanes
+    n_agg: int
+    n_buf: int
+    n_evict: int
+    merged: Any = None      # in-process: the cohort after the broadcast
+    acc: Any = None         # fused: the program's per-client accuracy
+
+
+def _engine_state(round_idx, cs, server, buf, refs, ef) -> EngineState:
+    """The one place an :class:`EngineState` is put together."""
+    return EngineState(round_idx, cs, server, *buf, *refs, ef)
+
+
+def _buffer(state: EngineState) -> tuple:
+    """The async buffer's six lanes, in :class:`EngineState` order."""
+    return (state.buf_vecs, state.buf_slots, state.buf_ready,
+            state.buf_weight, state.buf_valid, state.buf_seq)
+
+
+def host_buffer_insert(buf, entries) -> tuple[tuple, int]:
+    """Insert ``(vec, slot, ready, weight)`` entries, in order, into a
+    host copy of the async buffer's six lanes: the first free slot, else
+    evict the oldest *insertion*.  Returns the numpy lanes and the count
+    evicted.  The engine's scheduled uploads (``ready = r + staleness``)
+    and the transport runner's arrivals (``ready = r``) both insert
+    here; the compiled device insert is pinned against it."""
+    vecs, bslots, ready, weight, valid, seq = (np.array(a) for a in buf)
+    evicted = 0
+    next_seq = int(seq[valid].max()) + 1 if valid.any() else 0
+    for vec, slot, rdy, w in entries:
+        free = np.nonzero(~valid)[0]
+        if free.size:
+            i = free[0]
+        else:       # overflow: evict the oldest *insertion*
+            occupied = np.where(valid, seq, np.iinfo(np.int32).max)
+            i = int(np.argmin(occupied))
+            evicted += 1
+        vecs[i], bslots[i], ready[i], weight[i] = vec, slot, rdy, w
+        valid[i], seq[i] = True, next_seq
+        next_seq += 1
+    return (vecs, bslots, ready, weight, valid, seq), evicted
+
+
+# ---------------------------------------------------------------------------
+# the engine
+# ---------------------------------------------------------------------------
+
 class Engine:
     """Round orchestrator for one strategy over one client population."""
 
@@ -298,10 +350,8 @@ class Engine:
         n_clients = getattr(data, "n_clients", None)
         self.n = int(n_clients) if n_clients is not None \
             else int(data.x_train.shape[0])
-        self._mmap = cfg.client_store == "mmap"
-        self._streaming = hasattr(data, "gather_clients")
-        self.store: ClientStore | None = None
-        if self._streaming and not self._mmap:
+        streaming = hasattr(data, "gather_clients")
+        if streaming and cfg.client_store != "mmap":
             raise ValueError(
                 "streaming client data has no materialized population "
                 "for the resident engine to index — run it with "
@@ -331,15 +381,16 @@ class Engine:
         # rounds, so membership is recomputed when they are folded in,
         # not when they were sent.  The hook-less device/shardmap
         # programs hard-code the Alg. 2 fold and stay as they were.
-        self._async_hooks = cfg.aggregation == "async" and (
+        async_hooks = cfg.aggregation == "async" and (
             self._assign is not None
             or getattr(strategy, "server_update", None) is not None)
-        if self._async_hooks and cfg.backend == "shardmap":
+        if async_hooks and cfg.backend == "shardmap":
             raise ValueError(
                 "async + server-side assign/server_update hooks "
                 "aggregate on the in-process host buffer path — run "
                 "this strategy with backend='inprocess' (the shard-"
                 "mapped async program hard-codes the hook-less fold)")
+        self._host_buffer = async_hooks or cfg.async_buffer == "host"
         if client_weights is None and cfg.scheduler.sampling == "weighted":
             # weighted sampling defaults to the real per-client dataset
             # sizes the partitioner recorded (clients with more data are
@@ -352,20 +403,24 @@ class Engine:
             self.executor = ShardMapExecutor(
                 mesh=mesh, axis=cfg.mesh_axis,
                 collective=cfg.mesh_collective)
-            if not self._streaming:
+            if not streaming:
                 # the population's data lives on the mesh from here on,
                 # one block of clients a shard, where every round reads it
                 self.data = self.executor.place(data)
         else:
             self.executor = InProcessExecutor()
-        # uniform full participation samples idx = arange(N): skip the
-        # identity gather/scatter so the legacy-default path copies
-        # nothing (the dominant configuration for every benchmark).
-        # The mmap store always stages through gather/spill — its whole
-        # point is that the population is never resident.
-        self._identity = (self.scheduler.k == self.n
-                          and cfg.scheduler.sampling == "uniform"
-                          and not self._mmap)
+        # where the client rows live, chosen once: the round gathers and
+        # commits through this seam and never asks which store it is
+        self.rows = (StoreRows(self) if cfg.client_store == "mmap"
+                     else ResidentRows(self))
+        # identity wire + sync barrier + the identity gather: the
+        # executor may run the whole round (train → masked collective →
+        # apply → eval) as one compiled sharded program.  Strategies
+        # with a server-side assign hook always take the staged path:
+        # assignment is its own sharded stage there.
+        self._fusable = (cfg.aggregation == "sync" and self.rows.identity
+                         and self.wire_is_identity()
+                         and self._assign is None)
         # discount**staleness lookup for the async device buffer,
         # precomputed with Python double-precision pow and cast once —
         # the same double→float32 each host insert performs, so the
@@ -374,12 +429,19 @@ class Engine:
             [cfg.staleness_discount ** s
              for s in range(cfg.scheduler.max_staleness + 1)], np.float32))
         # (server, roundtripped rows) of the latest broadcast — reused
-        # by _wire_tx_server so lossy codecs roundtrip each server once
+        # by wire_tx_server so lossy codecs roundtrip each server once
         self._tx_cache = None
+
+    @property
+    def store(self) -> ClientStore | None:
+        """The mmap client store (None for resident rows)."""
+        return self.rows.store
 
     # -- lifecycle ---------------------------------------------------------
 
-    def _full_init(self, key: jax.Array):
+    def strategy_init(self, key: jax.Array):
+        """The strategy's ``init`` over the whole population: ``(client
+        state, server)``."""
         # v2 strategies take the client data (FLIS draws its server-side
         # probe set from the confidence split); a leftover v1 signature
         # still works, and a bare matrix return is coerced to ServerState.
@@ -397,109 +459,26 @@ class Engine:
         return self.strategy.init(key, self.n)
 
     def init(self, key: jax.Array) -> EngineState:
-        if self._mmap:
-            return self._init_mmap(key)
-        cs, server = self._full_init(key)
-        server = ensure_server_state(server)
+        """Round 0's state: the rows object's client rows, server and
+        lanes, around an empty async buffer."""
+        cs, server, refs, ef = self.rows.init(key)
         cap, d = self.cfg.buffer_capacity, self.strategy.vec_dim
-        if self.cfg.codec.sparse:
-            ref_vecs = jnp.zeros((self.n, self.strategy.n_slots, d),
-                                 jnp.float32)
-            ref_round = jnp.full((self.n,), -1, jnp.int32)
-        else:
-            ref_vecs = jnp.zeros((0, 0, 0), jnp.float32)
-            ref_round = jnp.zeros((0,), jnp.int32)
-        if self.cfg.codec.error_feedback:
-            ef = jnp.zeros((self.n, self.strategy.n_slots, d), jnp.float32)
-        else:
-            ef = jnp.zeros((0, 0, 0), jnp.float32)
-        if self.cfg.backend == "shardmap":
-            # placed where the round programs return them, so round 0
-            # compiles nothing that round 1 does not reuse
-            cs = self.executor.place(cs)
-            server = self.executor.place(server, replicated=True)
-        return EngineState(
-            round_idx=jnp.zeros((), jnp.int32),
-            client_state=cs, server=server,
-            buf_vecs=jnp.zeros((cap, d), jnp.float32),
-            buf_slots=jnp.full((cap,), -1, jnp.int32),
-            buf_ready=jnp.zeros((cap,), jnp.int32),
-            buf_weight=jnp.zeros((cap,), jnp.float32),
-            buf_valid=jnp.zeros((cap,), bool),
-            buf_seq=jnp.zeros((cap,), jnp.int32),
-            ref_vecs=ref_vecs, ref_round=ref_round, ef_residual=ef)
+        buf = (jnp.zeros((cap, d), jnp.float32),
+               jnp.full((cap,), -1, jnp.int32),
+               jnp.zeros((cap,), jnp.int32),
+               jnp.zeros((cap,), jnp.float32),
+               jnp.zeros((cap,), bool),
+               jnp.zeros((cap,), jnp.int32))
+        return _engine_state(jnp.zeros((), jnp.int32), cs, server, buf,
+                             refs, ef)
 
-    def _init_mmap(self, key: jax.Array) -> EngineState:
-        """Open the client store and return an O(K) engine state: the
-        population's rows live under ``cfg.store_dir``; the returned
-        state carries zero-row placeholders for ``client_state`` and
-        the sparse-codec ref lanes (they, too, live in the store).
-
-        Strategies exposing the O(K) init hooks (``init_cohort(key,
-        ids, n) == init(key, n)[0][ids]`` bit-for-bit, plus
-        ``init_server``) never materialize the population at all —
-        unwritten store rows are regenerated per sampled cohort.
-        Hookless strategies fall back to one full ``init`` whose rows
-        are served by index: O(N) host RAM once, still O(K) device per
-        round."""
-        strat = self.strategy
-        cohort = getattr(strat, "init_cohort", None)
-        init_server = getattr(strat, "init_server", None)
-        if cohort is not None and init_server is not None:
-            server = ensure_server_state(init_server(key, self.n))
-            row = jax.tree.map(lambda a: np.asarray(a)[0],
-                               cohort(key, np.asarray([0]), self.n))
-
-            def cs_init(ids):
-                return jax.tree.map(
-                    np.asarray, cohort(key, np.asarray(ids), self.n))
-        else:
-            cs, server = self._full_init(key)
-            server = ensure_server_state(server)
-            rows = jax.tree.map(np.asarray, cs)
-            row = jax.tree.map(lambda a: a[0], rows)
-
-            def cs_init(ids):
-                np_ids = np.asarray(ids)
-                return jax.tree.map(lambda a: a[np_ids], rows)
-
-        cap, d = self.cfg.buffer_capacity, strat.vec_dim
-        sparse = self.cfg.codec.sparse
-        template = {"cs": row}
-        if sparse:
-            # the per-client broadcast references ride in the store too:
-            # a never-synced client's reference is zeros / round −1,
-            # exactly the resident init
-            template["ref_vecs"] = np.zeros((strat.n_slots, d), np.float32)
-            template["ref_round"] = np.asarray(-1, np.int32)
-
-        def init_fn(ids):
-            np_ids = np.asarray(ids)
-            out = {"cs": cs_init(np_ids)}
-            if sparse:
-                out["ref_vecs"] = np.zeros(
-                    (np_ids.size, strat.n_slots, d), np.float32)
-                out["ref_round"] = np.full((np_ids.size,), -1, np.int32)
-            return out
-
-        root = self.cfg.store_dir or tempfile.mkdtemp(
-            prefix="client_store_")
-        self.store = ClientStore(root, self.n, template, init_fn=init_fn)
-        placeholder = jax.tree.map(
-            lambda a: jnp.zeros((0,) + np.asarray(a).shape,
-                                np.asarray(a).dtype), row)
-        return EngineState(
-            round_idx=jnp.zeros((), jnp.int32),
-            client_state=placeholder, server=server,
-            buf_vecs=jnp.zeros((cap, d), jnp.float32),
-            buf_slots=jnp.full((cap,), -1, jnp.int32),
-            buf_ready=jnp.zeros((cap,), jnp.int32),
-            buf_weight=jnp.zeros((cap,), jnp.float32),
-            buf_valid=jnp.zeros((cap,), bool),
-            buf_seq=jnp.zeros((cap,), jnp.int32),
-            ref_vecs=jnp.zeros((0, 0, 0), jnp.float32),
-            ref_round=jnp.zeros((0,), jnp.int32),
-            ef_residual=jnp.zeros((0, 0, 0), jnp.float32))
+    def next_state(self, state: EngineState, served: Served, cs,
+                   ef) -> EngineState:
+        """The state entering the next round: the served server, buffer
+        and references around the client rows ``cs`` and the
+        error-feedback lane ``ef``."""
+        return _engine_state(state.round_idx + 1, cs, served.server,
+                             served.buf, served.refs, ef)
 
     def run(self, key: jax.Array, state: EngineState | None = None,
             rounds: int | None = None
@@ -516,13 +495,8 @@ class Engine:
         k_init, k_rounds = jax.random.split(key)
         if state is None:
             state = self.init(k_init)
-        elif self._mmap:
-            # resuming over an existing store: (re)open it keyed by THIS
-            # run's k_init, so rows never sampled before the checkpoint
-            # fault in exactly as the uninterrupted run would have
-            # generated them (the `like` state a caller built for
-            # checkpointing.restore may have used a different key)
-            self.init(k_init)
+        else:
+            self.rows.resume(k_init)
         reports: list[RoundReport] = []
         start = int(state.round_idx)
         n_rounds = self.cfg.rounds if rounds is None else rounds
@@ -537,233 +511,183 @@ class Engine:
             reports.append(rep)
             every = self.cfg.checkpoint_every
             if self.cfg.checkpoint_dir and every and (r + 1) % every == 0:
-                if self._mmap:
-                    # the checkpoint is only the replicated state — the
-                    # population rows ARE the store, flushed alongside
-                    # so checkpoint + store dir resume together (valid
-                    # at the latest checkpoint: store rows advance past
-                    # older ones; see docs/client-store.md)
-                    self.store.flush()
                 checkpointing.save(
                     self.cfg.checkpoint_dir, state,
                     manifest=self.obs.manifest,
-                    store_manifest=(self.store.manifest
-                                    if self._mmap else None))
+                    store_manifest=self.rows.flush())
         return state, reports
 
     # -- one round ---------------------------------------------------------
 
     def run_round(self, state: EngineState, round_key: jax.Array
                   ) -> tuple[EngineState, RoundReport]:
+        """schedule → gather → the fused round, or the client half then
+        :meth:`serve_uploads` → commit and evaluate → report."""
         obs = self.obs            # telemetry spans/fences — no-ops when off
         r = int(state.round_idx)
         if obs.enabled:
             obs.begin_round(r)    # the round stat of the stage annotations
-        store = self.store
-        if self._mmap:
-            io0 = (store.io_read_bytes, store.io_written_bytes)
+        io0 = self.rows.io()
         with obs.span("schedule"):
             part = self.scheduler.sample(r, round_key)
-            sync = self.cfg.aggregation == "sync"
             arrive = np.asarray(part.active)
-            if sync:
+            if self.cfg.aggregation == "sync":
                 arrive = arrive & (np.asarray(part.staleness) == 0)
 
-        # gather the sampled sub-pytree (static K) + per-client keys
-        sub_refs = None
+        # (1) the sampled rows (static K) and their per-client keys
         with obs.span("gather"):
-            keys = jax.random.split(round_key, self.n)
-            if self._identity:
-                sub_cs, sub_data = state.client_state, self.data
-            elif self._mmap:
-                # the K sampled rows come off the host store (digest-
-                # verified; never-spilled rows regenerated by the
-                # strategy's deterministic init) — same per-client keys
-                # as the resident gather, so the round is bit-identical
-                np_ids = np.asarray(part.idx)
-                keys = keys[part.idx]
-                bundle = store.gather(np_ids)
-                sub_cs = jax.tree.map(jnp.asarray, bundle["cs"])
-                if self.cfg.codec.sparse:
-                    sub_refs = (jnp.asarray(bundle["ref_vecs"]),
-                                jnp.asarray(bundle["ref_round"]))
-                sub_data = (self.data.gather_clients(np_ids)
-                            if self._streaming else
-                            jax.tree.map(lambda a: a[part.idx], self.data))
-            else:
-                keys = keys[part.idx]
-                sub_cs = jax.tree.map(lambda a: a[part.idx],
-                                      state.client_state)
-                sub_data = jax.tree.map(lambda a: a[part.idx], self.data)
-            obs.fence(keys)
+            cohort = self.rows.gather(
+                state, part, jax.random.split(round_key, self.n))
+            obs.fence(cohort.keys)
 
-        # identity wire + sync barrier: the executor may run the whole
-        # round (train → masked collective → apply → eval) as one
-        # compiled sharded program; bytes are metered arithmetically
-        # (float32 frames are bit-exact, len = 4 + 4·d — codec-pinned).
-        # Strategies with a server-side assign hook always take the
-        # staged path: assignment is its own sharded stage there.
         fused = None
-        if sync and self._identity and self._wire_is_identity() \
-                and self._assign is None:
+        if self._fusable:
             with obs.span("fused_round"):
                 fused = self.executor.fused_sync_round(
-                    self.strategy, sub_cs, state.server, sub_data, keys,
-                    jnp.asarray(arrive))
+                    self.strategy, cohort.cs, state.server, cohort.data,
+                    cohort.keys, jnp.asarray(arrive))
                 obs.fence(fused)
             if fused is None:
                 obs.discard("fused_round")   # in-process: no fused form
-        refs = (state.ref_vecs, state.ref_round)
         ef = state.ef_residual      # EF needs a lossy wire: never fused
         if fused is not None:
-            merged, server, counts, applied, acc_sub, slots = fused
+            # bytes are metered arithmetically (float32 frames are
+            # bit-exact, len = 4 + 4·d — codec-pinned)
+            merged, server, counts, applied, acc, slots = fused
             with obs.span("downlink"):
                 up_bytes = self._identity_upload_bytes(
                     np.asarray(slots), np.asarray(part.active))
-                _, down_bc, down_pc = self._wire_downlink(
-                    server.slots, counts, arrive, applied)
+                _, *down = self.wire_downlink(server.slots, counts, arrive,
+                                              applied)
+            served = self._served(state, slots, arrive, server, counts,
+                                  applied, down,
+                                  (state.ref_vecs, state.ref_round),
+                                  merged=merged, acc=acc)
         else:
-            # (2) local work on the K sampled clients.  Training starts
-            # from the codec-roundtripped broadcast rows — what a client
-            # actually holds after a lossy downlink — not the
-            # aggregator's full-precision state (identity wire: same
-            # thing, zero cost).
+            # (2) the client half.  Training starts from the
+            # codec-roundtripped broadcast rows — what a client actually
+            # holds after a lossy downlink (identity wire: the same).
             with obs.span("broadcast_encode"):
-                tx_server = self._wire_tx_server(state.server.slots)
+                tx_server = self.wire_tx_server(state.server.slots)
                 obs.fence(tx_server)
             with obs.span("client_step"):
                 new_sub, vecs, slots = self.executor.train(
-                    self.strategy, sub_cs, tx_server, sub_data, keys)
+                    self.strategy, cohort.cs, tx_server, cohort.data,
+                    cohort.keys)
                 obs.fence(new_sub, vecs, slots)
-
-            # (3) the wire: encode → meter → decode (sparse deltas run
-            # against each client's tracked broadcast reference).
-            # Metering sees the client-proposed slot tags — the frames
-            # that crossed the wire — never the post-assign ids.
+            # the wire: encode → meter → decode (sparse deltas against
+            # each client's tracked broadcast reference)
             with obs.span("uplink_codec"):
-                dec, up_bytes, ef = self._wire_uplink(
-                    state, vecs, slots, part, sub_refs=sub_refs)
+                dec, up_bytes, ef = self.wire_uplink(
+                    state, vecs, slots, part, sub_refs=cohort.refs)
                 obs.fence(dec)
+            # (3) the server half; the cohort applies its broadcast
+            served = self.serve_uploads(state, part, dec, slots, arrive, r,
+                                        cohort=cohort, new_sub=new_sub)
 
-            # (3b) server-side assignment (v2): recompute every upload's
-            # slot id from the decoded payloads — FLIS's per-round
-            # dynamic clustering; absent hook = keep proposed ids.
-            # Async strategies skip this stage: their uploads cross
-            # rounds in the buffer, so `assign` runs over the *matured
-            # buffer contents* at aggregation time instead
-            # (:meth:`_aggregate_async_host`).
-            if self._assign is not None and sync:
+        # (4) the merged rows back to their store, and evaluation
+        new_state, acc, assignment = self.rows.commit(state, cohort, served,
+                                                      ef)
+        io1 = self.rows.io()
+        return new_state, self.report(
+            r, part, served, up_bytes, acc, assignment,
+            store_read_bytes=io1[0] - io0[0],
+            store_written_bytes=io1[1] - io0[1])
+
+    def serve_uploads(self, state: EngineState, part: Participation, dec,
+                      slots, arrive, r: int, *, entries=None,
+                      cohort: Cohort | None = None,
+                      new_sub=None) -> Served:
+        """The round's server half, from the decoded uploads to the next
+        server, the slots each receiver applies and the references that
+        track them; the in-process round and the transport runner both
+        call it.  ``arrive`` is the receiving mask (the sync barrier's
+        survivors, or every active client under async).
+
+        Sync: the strategy's ``assign`` hook if any (the proposed tags
+        were already metered), the masked mean, ``server_update``.
+        Async: the executor's compiled buffer update, or the host
+        reference buffer — under ``async_buffer="host"``, for strategies
+        with server hooks, and for a caller's own ``entries`` (the
+        runner's observed arrivals).  Then the metered broadcast of each
+        aggregated slot row to the clients it came from; in-process the
+        ``cohort``, trained as ``new_sub``, applies it here."""
+        obs, strat = self.obs, self.strategy
+        recv = jnp.asarray(arrive)
+        tally = None
+        if self.cfg.aggregation == "sync":
+            if self._assign is not None:
                 with obs.span("assign"):
-                    slots = self.executor.assign(
-                        self.strategy, state.server, dec, slots,
-                        jnp.asarray(arrive))
+                    slots = self.executor.assign(strat, state.server, dec,
+                                                 slots, recv)
                     obs.fence(slots)
+            with obs.span("aggregate"):
+                agg, counts = self.executor.masked_mean(strat, dec, slots,
+                                                        recv)
+                obs.fence(agg, counts)
+            with obs.span("server_update"):
+                server = self._server_update(state.server, agg, counts)
+                obs.fence(server)
+        else:
+            with obs.span("aggregate"):
+                if entries is None and not self._host_buffer:
+                    server, counts, tally = self._aggregate_async(
+                        state, dec, slots, part)
+                else:
+                    if entries is None:
+                        entries = self._scheduled_entries(dec, slots, part,
+                                                          r)
+                    server, counts, tally = self._aggregate_host(
+                        state, entries, r)
+                obs.fence(server, counts)
 
-            # (4) aggregation, folded into the strategy-owned server
-            # state by its server_update hook (default: Alg. 2
-            # retention — empty slots keep their previous row)
-            if sync:
-                with obs.span("aggregate"):
-                    agg, counts = self.executor.masked_mean(
-                        self.strategy, dec, slots, jnp.asarray(arrive))
-                    obs.fence(agg, counts)
-                with obs.span("server_update"):
-                    server = self._server_update(state.server, agg, counts)
-                    obs.fence(server)
-            elif self.cfg.async_buffer == "host" or self._async_hooks:
-                with obs.span("aggregate"):
-                    server, counts, n_agg, n_buf, n_evict, buf = \
-                        self._aggregate_async_host(state, dec, slots,
-                                                   part, r)
-                    obs.fence(server, counts)
-            else:
-                with obs.span("aggregate"):
-                    srv_mat, counts, n_agg, n_buf, n_evict, buf = \
-                        self._aggregate_async(state, dec, slots, part)
-                    server = state.server._replace(slots=srv_mat)
-                    obs.fence(server, counts)
-
-            # (5) broadcast + scatter + evaluate.  A slot row is only
-            # pushed to clients when it actually received an aggregate
-            # this round — otherwise (async round below the B threshold,
-            # or a never-fed cluster) the zero-initialized/stale server
-            # row would overwrite the client's freshly trained weights.
-            recv = jnp.asarray(arrive)
-            with obs.span("downlink"):
-                applied = executors.applied_slots(slots, counts, recv)
-                rx_server, down_bc, down_pc = self._wire_downlink(
-                    server.slots, counts, arrive, applied)
-                obs.fence(rx_server)
+        with obs.span("downlink"):
+            applied = executors.applied_slots(slots, counts, recv)
+            rx_server, *down = self.wire_downlink(server.slots, counts,
+                                                  arrive, applied)
+            obs.fence(rx_server)
+        merged = None
+        if new_sub is not None:
             with obs.span("apply_merge"):
                 merged = self.executor.apply_merge(
-                    self.strategy, new_sub, applied, rx_server, sub_cs,
-                    recv)
+                    strat, new_sub, applied, rx_server, cohort.cs, recv)
                 obs.fence(merged)
-            acc_sub = None
-            with obs.span("ref_track"):
-                if self._mmap:
-                    if self.cfg.codec.sparse:
-                        sub_ref_vecs = np.array(
-                            np.asarray(sub_refs[0], np.float32))
-                        sub_ref_rounds = np.array(np.asarray(sub_refs[1]))
-                        self._advance_ref_rows(
-                            sub_ref_vecs, sub_ref_rounds, arrive, applied,
-                            rx_server, r, self.strategy.downloads)
-                        sub_refs = (jnp.asarray(sub_ref_vecs),
-                                    jnp.asarray(sub_ref_rounds))
-                    refs = (state.ref_vecs, state.ref_round)  # placeholders
-                else:
-                    refs = self._update_refs(state, part, arrive, applied,
-                                             rx_server, r)
-                obs.fence(refs)
+        with obs.span("ref_track"):
+            refs = self.rows.track_refs(
+                state, part.idx, None if cohort is None else cohort.refs,
+                arrive, applied, rx_server, r)
+            obs.fence(refs)
+        return self._served(state, slots, arrive, server, counts, applied,
+                            down, refs, tally, merged=merged)
 
-            # spill the merged working set (and its advanced broadcast
-            # references) back to the host store — after this the round
-            # holds no per-client device state beyond the K rows
-            if self._mmap:
-                with obs.span("spill"):
-                    bundle = {"cs": jax.tree.map(np.asarray, merged)}
-                    if self.cfg.codec.sparse:
-                        bundle["ref_vecs"] = np.asarray(sub_refs[0],
-                                                        np.float32)
-                        bundle["ref_round"] = np.asarray(sub_refs[1],
-                                                         np.int32)
-                    store.spill(np_ids, bundle)
+    def _served(self, state, slots, arrive, server, counts, applied, down,
+                refs, tally=None, merged=None, acc=None) -> Served:
+        """Close the server half — staged or fused — into one
+        :class:`Served`.  Without an async ``tally`` the round had the
+        sync barrier: the uploads it let through were folded in, and
+        the buffer passes on untouched."""
+        if tally is None:
+            tally = (_buffer(state),
+                     int((np.asarray(slots)[arrive] >= 0).sum()), 0, 0)
+        return Served(server, counts, applied, *down, refs, *tally,
+                      merged, acc)
 
-        if sync:   # barrier bookkeeping, identical for fused and staged
-            n_agg = int((np.asarray(slots)[arrive] >= 0).sum())
-            buf = self._buf_of(state)
-            n_buf = n_evict = 0
-
-        with obs.span("eval"):
-            if self._mmap:
-                new_state, acc, assignment = self._store_eval(
-                    state, part.idx, merged, applied, server, buf, refs,
-                    ef, sub_data)
-            else:
-                new_state, acc, assignment = self._scatter_eval(
-                    state, part.idx, merged, applied, server, buf, refs,
-                    ef, acc_sub)
-            obs.fence(acc)
-
-        if self._mmap:
-            store_read = store.io_read_bytes - io0[0]
-            store_written = store.io_written_bytes - io0[1]
-        else:
-            store_read = store_written = 0
-        rep = RoundReport(
+    def report(self, r: int, part: Participation, served: Served,
+               up_bytes: int, acc, assignment, **gauges) -> RoundReport:
+        """The round's report, for the in-process round and the transport
+        runner alike; ``gauges`` are the store's or the wire's counters."""
+        return RoundReport(
             round_idx=r, mean_accuracy=acc.mean(),
             per_client_accuracy=acc, assignment=assignment,
-            cluster_counts=counts, participation=part,
-            upload_bytes=up_bytes, download_bytes_broadcast=down_bc,
-            download_bytes_per_client=down_pc, aggregated_uploads=n_agg,
-            buffered_uploads=n_buf, evicted_uploads=n_evict,
-            store_read_bytes=store_read, store_written_bytes=store_written)
-        return new_state, rep
+            cluster_counts=served.counts, participation=part,
+            upload_bytes=up_bytes, download_bytes_broadcast=served.down_bc,
+            download_bytes_per_client=served.down_pc,
+            aggregated_uploads=served.n_agg, buffered_uploads=served.n_buf,
+            evicted_uploads=served.n_evict, **gauges)
 
     # -- pieces ------------------------------------------------------------
 
-    def _wire_is_identity(self) -> bool:
+    def wire_is_identity(self) -> bool:
         """Dense float32 encode→decode is a bit-exact identity (pinned by
         the codec tests) — the round needs no host codec boundary."""
         return self.cfg.codec.name == "float32" and not self.cfg.codec.sparse
@@ -782,19 +706,13 @@ class Engine:
     def _identity_upload_bytes(self, np_slots, active) -> int:
         """Identity-wire metering: frame = 4-byte slot id + 4·d payload,
         one frame per shared slot of each active client.  The one
-        formula both the fused path and ``_wire_uplink``'s fast path
+        formula both the fused path and :meth:`wire_uplink`'s fast path
         meter with."""
         d = self.strategy.vec_dim
         return int((np_slots[active] >= 0).sum()) * (4 + 4 * d)
 
-    @staticmethod
-    def _buf_of(state: EngineState):
-        """The async buffer 6-tuple, passed through unchanged by sync."""
-        return (state.buf_vecs, state.buf_slots, state.buf_ready,
-                state.buf_weight, state.buf_valid, state.buf_seq)
-
-    def _wire_uplink(self, state: EngineState, vecs, slots,
-                     part: Participation, sub_refs=None):
+    def wire_uplink(self, state: EngineState, vecs, slots,
+                    part: Participation, sub_refs=None):
         """Encode every surviving upload to real bytes; decode what the
         aggregator would see.  Frame = slot id (<i4) + encoded vector.
         Slot −1 ("nothing shared", e.g. below ``conf_threshold``) sends
@@ -819,7 +737,7 @@ class Engine:
         cfg = self.cfg.codec
         np_slots = np.asarray(slots)
         active = np.asarray(part.active)
-        if self._wire_is_identity():
+        if self.wire_is_identity():
             # bit-exact identity wire: skip the host round-trip, meter
             # arithmetically.  Keeps the default round free of
             # per-frame Python.
@@ -863,84 +781,44 @@ class Engine:
             ef = ef.at[jnp.asarray(part.idx)].set(jnp.asarray(sub_ef))
         return jnp.asarray(dec), total, ef
 
-    def _update_refs(self, state: EngineState, part: Participation,
-                     arrive, applied, rx_server, r: int):
-        """Advance the per-client broadcast references: every receiving
-        participant now holds the roundtripped rows it was just sent —
-        its applied slots under ``downloads="assigned"``, the whole
-        server matrix under ``"all_slots"`` (mirroring exactly what
-        :meth:`_wire_downlink` billed).  Non-participants, drops, and
-        stragglers keep their old references — that is the point."""
-        if not self.cfg.codec.sparse:
-            return state.ref_vecs, state.ref_round
-        # work on the K sampled rows only (idx is without-replacement,
-        # so the device scatter below touches each row once); the
-        # untouched population rows never cross the host boundary
-        idx = jnp.asarray(part.idx)
-        sub = np.array(state.ref_vecs[idx])          # K rows, writable
-        sub_rounds = np.array(state.ref_round[idx])
-        self._advance_ref_rows(sub, sub_rounds, arrive, applied,
-                               rx_server, r, self.strategy.downloads)
-        return (state.ref_vecs.at[idx].set(jnp.asarray(sub)),
-                state.ref_round.at[idx].set(jnp.asarray(sub_rounds)))
-
-    @staticmethod
-    def _advance_ref_rows(sub, sub_rounds, arrive, applied, rx_server, r,
-                          downloads):
-        """Advance K sampled reference rows in place — the one update
-        both the resident scatter (:meth:`_update_refs`) and the mmap
-        spill share, so their reference streams cannot diverge."""
-        np_applied = np.asarray(applied)
-        rx = np.asarray(rx_server, np.float32)
-        for c in range(sub.shape[0]):
-            if not arrive[c]:
-                continue
-            if downloads == "all_slots":
-                sub[c] = rx
-                sub_rounds[c] = r
-            else:
-                got = False
-                for j in range(np_applied.shape[1]):
-                    s = int(np_applied[c, j])
-                    if s >= 0:
-                        sub[c, s] = rx[s]
-                        got = True
-                if got:
-                    sub_rounds[c] = r
-        return sub, sub_rounds
+    def row_frames(self, server) -> list[bytes]:
+        """The server matrix as dense codec frames (delta coding is
+        upload-only): what any broadcast of it carries over a real
+        wire.  Deterministic, so both ends of a wire agree on them."""
+        np_server = np.asarray(server, np.float32)
+        if self.wire_is_identity():
+            return [row.tobytes() for row in np_server]
+        dense = CodecConfig(self.cfg.codec.name, sparse=False)
+        return [encode(row, dense) for row in np_server]
 
     def _roundtrip_rows(self, server):
-        """Encode→decode every server row through the *dense* wire codec
-        (delta coding is upload-only) — what any receiver of a broadcast
-        actually holds.  Returns ``(rx_rows, frame_lengths)``; float32
-        is a bit-exact identity, so it skips the host round-trip and
-        meters arithmetically (frame = 4·d bytes, codec-pinned)."""
+        """Every server row through the dense wire codec and back — what
+        any receiver of a broadcast actually holds.  Returns ``(rx_rows,
+        frame_lengths)``; float32 is a bit-exact identity, so it skips
+        the host round-trip and meters arithmetically (frame = 4·d
+        bytes, codec-pinned)."""
+        n_rows, d = int(server.shape[0]), int(server.shape[1])
+        if self.cfg.codec.name == "float32":
+            return server, [4 * d] * n_rows
         dense = CodecConfig(self.cfg.codec.name, sparse=False)
-        if dense.name == "float32":
-            return server, [4 * int(server.shape[1])] * int(server.shape[0])
-        np_server = np.asarray(server, np.float32)
-        rx = np.zeros_like(np_server)
-        frame_len = []
-        for s in range(np_server.shape[0]):
-            frame = encode(np_server[s], dense)
-            frame_len.append(len(frame))
-            rx[s] = decode(frame, np_server.shape[1], dense)
-        return jnp.asarray(rx), frame_len
+        frames = self.row_frames(server)
+        rx = np.asarray([decode(f, d, dense) for f in frames], np.float32)
+        return jnp.asarray(rx), [len(f) for f in frames]
 
-    def _wire_tx_server(self, server):
+    def wire_tx_server(self, server):
         """The server matrix as the *clients* hold it: every row
         roundtripped through the dense codec, because the rows a client
         trains from arrived over last round's (possibly lossy)
         broadcast.  Metering is unaffected — download bytes are billed
-        by :meth:`_wire_downlink` when the rows are pushed; this only
+        by :meth:`wire_downlink` when the rows are pushed; this only
         stops ``client_step`` reading precision the wire never carried
         (see docs/async-runtime.md, byte metering).
 
         ``state.server`` entering round r+1 is the very array
-        :meth:`_wire_downlink` roundtripped at the end of round r, so
+        :meth:`wire_downlink` roundtripped at the end of round r, so
         the downlink's result is cached by identity and the host
         encode/decode loop runs once per server matrix, not twice."""
-        if self._wire_is_identity():
+        if self.wire_is_identity():
             return server
         cached = self._tx_cache
         if cached is not None and cached[0] is server:
@@ -949,7 +827,7 @@ class Engine:
         self._tx_cache = (server, rx)
         return rx
 
-    def _wire_downlink(self, server, counts, arrive, applied):
+    def wire_downlink(self, server, counts, arrive, applied):
         """Run the broadcast through the wire too: every slot row is
         encoded (dense — delta coding is upload-only), metered, and
         decoded, and it is the *decoded* rows clients apply — a lossy
@@ -959,7 +837,7 @@ class Engine:
         actually apply (legacy §6.7 accounting)."""
         np_counts = np.asarray(counts)
         rx_arr, frame_len = self._roundtrip_rows(server)
-        if not self._wire_is_identity():
+        if not self.wire_is_identity():
             self._tx_cache = (server, rx_arr)   # next round trains from it
         down_bc = sum(frame_len[s] for s in range(len(frame_len))
                       if np_counts[s] > 0)
@@ -978,8 +856,9 @@ class Engine:
         executor's one compiled insert→gate→mean program.  In-process
         that is a single jitted update; shard-mapped the uploads stay
         sharded on the mesh axis and the mean is a masked collective.
-        Bit-identical to :meth:`_aggregate_async_host`, pinned by the
-        conformance suite."""
+        Bit-identical to :meth:`_aggregate_host`, pinned by the
+        conformance suite.  Returns ``(server, counts, (buf, n_agg,
+        n_buf, n_evict))``."""
         k, j = slots.shape
         active = jnp.asarray(part.active)
         stale = jnp.asarray(part.staleness, jnp.int32)
@@ -989,20 +868,30 @@ class Engine:
               state.round_idx + flat(stale),
               self._discount[flat(stale)],
               flat(active) & (slots.reshape(-1) >= 0))
-        server, counts, n_agg, n_buf, n_evict, buf = \
+        srv, counts, n_agg, n_buf, n_evict, buf = \
             self.executor.async_update(
-                self.strategy, self._buf_of(state), up, state.round_idx,
+                self.strategy, _buffer(state), up, state.round_idx,
                 state.server.slots, self.cfg.async_min_uploads)
-        return (server, counts, int(n_agg), int(n_buf), int(n_evict), buf)
+        return (state.server._replace(slots=srv), counts,
+                (buf, int(n_agg), int(n_buf), int(n_evict)))
 
-    def _aggregate_async_host(self, state, dec, slots, part: Participation,
-                              r):
-        """Host-buffered aggregation (``async_buffer="host"``, and the
-        path every async strategy with server-side hooks takes): the
-        original numpy insert loop, kept verbatim as the executable
-        reference the device path is pinned against — insert this
-        round's uploads, then fold in every matured entry once
-        ``async_min_uploads`` are available.
+    def _scheduled_entries(self, dec, slots, part: Participation, r: int):
+        """This round's uploads as host-buffer entries under the injected
+        schedule: each matures at ``r + staleness``, weighted
+        ``discount**staleness``."""
+        np_dec, np_slots = np.asarray(dec), np.asarray(slots)
+        active, stale = np.asarray(part.active), np.asarray(part.staleness)
+        disc = self.cfg.staleness_discount
+        return [(np_dec[c, j], np_slots[c, j], r + int(stale[c]),
+                 disc ** int(stale[c]))
+                for c in range(np_dec.shape[0]) if active[c]
+                for j in range(np_dec.shape[1]) if np_slots[c, j] >= 0]
+
+    def _aggregate_host(self, state, entries, r: int):
+        """Host-buffered aggregation — the numpy reference the device
+        path is pinned against: insert ``entries``
+        (:func:`host_buffer_insert`), then fold in every matured entry
+        once ``async_min_uploads`` are available.
 
         Strategies with an ``assign`` hook have it re-run here over the
         matured buffer contents *at aggregation time* (buffer rows as
@@ -1011,61 +900,16 @@ class Engine:
         actually being folded in — not from stale send-time tags.  The
         fold then goes through the strategy's ``server_update`` (the
         Alg. 2 default reproduces the legacy in-place write bit for
-        bit).  Returns a full :class:`ServerState`."""
-        cfg = self.cfg
-        vecs = np.asarray(state.buf_vecs).copy()
-        bslots = np.asarray(state.buf_slots).copy()
-        ready = np.asarray(state.buf_ready).copy()
-        weight = np.asarray(state.buf_weight).copy()
-        valid = np.asarray(state.buf_valid).copy()
-        seq = np.asarray(state.buf_seq).copy()
-
-        np_dec = np.asarray(dec)
-        np_slots = np.asarray(slots)
-        active = np.asarray(part.active)
-        stale = np.asarray(part.staleness)
-        evicted = 0
-        next_seq = int(seq[valid].max()) + 1 if valid.any() else 0
-        for c in range(np_dec.shape[0]):
-            if not active[c]:
-                continue
-            for j in range(np_dec.shape[1]):
-                if np_slots[c, j] < 0:
-                    continue
-                free = np.nonzero(~valid)[0]
-                if free.size:
-                    i = free[0]
-                else:       # overflow: evict the oldest *insertion*
-                    occupied = np.where(valid, seq, np.iinfo(np.int32).max)
-                    i = int(np.argmin(occupied))
-                    evicted += 1
-                vecs[i] = np_dec[c, j]
-                bslots[i] = np_slots[c, j]
-                ready[i] = r + int(stale[c])
-                weight[i] = cfg.staleness_discount ** int(stale[c])
-                valid[i] = True
-                seq[i] = next_seq
-                next_seq += 1
-
-        server, counts, n_agg, n_buf, buf = self._fold_host_buffer(
-            state, vecs, bslots, ready, weight, valid, seq, r)
-        return server, counts, n_agg, n_buf, evicted, buf
-
-    def _fold_host_buffer(self, state, vecs, bslots, ready, weight, valid,
-                          seq, r):
-        """Fold the matured host-buffer entries into the server (the
-        tail of :meth:`_aggregate_async_host`, shared with the real
-        transport's arrival-driven insert path — same maturity gate,
-        same assign-at-aggregation hook, same ``server_update`` fold).
-        Returns ``(server, counts, n_agg, n_buf, buf)``."""
-        cfg = self.cfg
+        bit).  Returns ``(server, counts, (buf, n_agg, n_buf,
+        n_evict))``."""
+        (vecs, bslots, ready, weight, valid, seq), n_evict = \
+            host_buffer_insert(_buffer(state), entries)
         # an entry whose staleness discount rounds to zero weight can never
         # contribute to the weighted mean — treat it as consumed noise so
         # its slot isn't wrongly marked populated (and then broadcast)
         mature = valid & (ready <= r)
         contrib = mature & (weight > 0.0)
-        n_mature = int(mature.sum())
-        if n_mature >= cfg.async_min_uploads:
+        if int(mature.sum()) >= self.cfg.async_min_uploads:
             w = jnp.asarray(np.where(contrib, weight, 0.0), jnp.float32)
             s = jnp.asarray(np.where(contrib, bslots, -1), jnp.int32)
             if self._assign is not None:
@@ -1089,82 +933,6 @@ class Engine:
             server = state.server
             counts = jnp.zeros((self.strategy.n_slots,), jnp.float32)
             n_agg = 0
-        buf = (jnp.asarray(vecs), jnp.asarray(bslots), jnp.asarray(ready),
-               jnp.asarray(weight), jnp.asarray(valid), jnp.asarray(seq))
-        return server, counts, n_agg, int(valid.sum()), buf
-
-    def _scatter_eval(self, state: EngineState, idx, merged, applied,
-                      server, buf, refs, ef, acc_sub):
-        """Scatter the merged sub-pytree back into the population,
-        evaluate everyone, build the next state.  ``acc_sub`` is the
-        fused program's per-client accuracy (full population when the
-        identity gather was in effect), saving the separate eval pass."""
-        if self._identity:
-            cs = merged
-            assignment = applied
-        else:
-            cs = jax.tree.map(lambda a, s: a.at[idx].set(s),
-                              state.client_state, merged)
-            assignment = jnp.full((self.n, self.strategy.j_slots), -1,
-                                  jnp.int32).at[idx].set(applied)
-
-        if acc_sub is not None and self._identity:
-            acc = acc_sub
-        else:
-            acc = self.executor.evaluate(
-                self.strategy, cs, self.data.x_test, self.data.y_test)
-        # commit to a single device before any reduction: a mean over a
-        # mesh-sharded accuracy vector reduces in device order, which is
-        # ULP-different from the in-process sequential reduction (the
-        # conformance suite pins the report bit-for-bit across backends)
-        acc = jnp.asarray(np.asarray(acc))
-        new_state = EngineState(
-            round_idx=state.round_idx + 1, client_state=cs, server=server,
-            buf_vecs=buf[0], buf_slots=buf[1], buf_ready=buf[2],
-            buf_weight=buf[3], buf_valid=buf[4], buf_seq=buf[5],
-            ref_vecs=refs[0], ref_round=refs[1], ef_residual=ef)
-        return new_state, acc, assignment
-
-    def _store_eval(self, state: EngineState, idx, merged, applied,
-                    server, buf, refs, ef, sub_data):
-        """mmap counterpart of :meth:`_scatter_eval`: the population
-        already lives in the store (the round spilled the merged rows
-        before this), so the next state keeps its zero-row placeholders.
-
-        ``store_eval="full"`` re-gathers the whole population in
-        ``store_eval_chunk`` blocks and evaluates each — per-client
-        evaluation is an independent vmap lane on both executors, so
-        the chunked accuracy vector is bit-identical to the resident
-        monolithic eval.  ``"sampled"`` (the simulated-scale setting)
-        evaluates only the K merged rows: the report's accuracy /
-        assignment then cover the cohort, not the population."""
-        if self.cfg.store_eval == "sampled":
-            acc = self.executor.evaluate(
-                self.strategy, merged, sub_data.x_test, sub_data.y_test)
-            assignment = applied
-        else:
-            def gather_cs(ids):
-                return jax.tree.map(jnp.asarray,
-                                    self.store.gather(ids)["cs"])
-
-            def gather_xy(ids):
-                if self._streaming:
-                    d = self.data.gather_clients(ids)
-                    return d.x_test, d.y_test
-                jids = jnp.asarray(ids)
-                return self.data.x_test[jids], self.data.y_test[jids]
-
-            acc = executors.evaluate_population(
-                self.executor, self.strategy, gather_cs, gather_xy,
-                self.n, self.cfg.store_eval_chunk)
-            assignment = jnp.full((self.n, self.strategy.j_slots), -1,
-                                  jnp.int32).at[jnp.asarray(idx)].set(
-                applied)
-        acc = jnp.asarray(np.asarray(acc))
-        new_state = EngineState(
-            round_idx=state.round_idx + 1,
-            client_state=state.client_state, server=server,
-            buf_vecs=buf[0], buf_slots=buf[1], buf_ready=buf[2],
-            buf_weight=buf[3], buf_valid=buf[4], buf_seq=buf[5],
-            ref_vecs=refs[0], ref_round=refs[1], ef_residual=ef)
-        return new_state, acc, assignment
+        buf = tuple(jnp.asarray(a)
+                    for a in (vecs, bslots, ready, weight, valid, seq))
+        return server, counts, (buf, n_agg, int(valid.sum()), n_evict)

@@ -60,7 +60,7 @@ reference loops the conformance suite pins the engine against.
 The ``slots`` matrix a ``client_step`` receives is what the client
 *holds*, not what the aggregator stores: under a lossy wire codec the
 engine hands in the codec-roundtripped broadcast rows
-(``Engine._wire_tx_server``), so strategies that warm-start from global
+(``Engine.wire_tx_server``), so strategies that warm-start from global
 state (FedAvg/FedProx/IFCA) train from exactly the precision the wire
 carried.  TPFL, FLIS and FedTM delete it unread — their clients train
 from their own state (which already holds last round's broadcast).

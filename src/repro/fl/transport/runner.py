@@ -7,10 +7,10 @@ downlink → eval — but with every client-side stage executed by worker
 peers behind a wire: the broadcast rows go out as encoded frames inside
 WORK messages, the uplink comes back as the workers' actual codec
 frames inside UPLOAD messages, and the block evaluations return as EVAL
-messages.  The server keeps the server-owned halves (scheduler,
-assignment, aggregation, server state, sparse-ref tracking for decode)
-and reuses the engine's own helpers for them, so the two
-implementations cannot drift.
+messages.  The server half — assignment, aggregation, server state,
+the metered downlink, sparse-ref tracking for decode — is the engine's
+own :meth:`~repro.fl.runtime.engine.Engine.serve_uploads`, called with
+the uploads decoded off the wire, so the two rounds cannot drift.
 
 Conformance contract: with ``transport="loopback"`` and the identity
 wire (dense float32), a run is **bit-identical** to the in-process
@@ -36,8 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl.runtime import executors
-from repro.fl.runtime.codec import CodecConfig, decode, encode
+from repro.fl.runtime.codec import decode
 from repro.fl.runtime.engine import Engine, EngineState, RoundReport
 from repro.fl.runtime.scheduler import arrival_participation
 from repro.fl.transport import framing
@@ -71,7 +70,6 @@ class TransportEngine:
         self.faults = faults or FaultPlan()
         self.retry = retry or RetryPolicy()
         self.spec = spec
-        self._dense = CodecConfig(cfg.codec.name, sparse=False)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -185,17 +183,6 @@ class TransportEngine:
             return kind, payload, nbytes
         raise last
 
-    def _row_frames(self, server) -> list[bytes]:
-        """The server matrix as dense codec frames — what WORK and
-        DOWNLINK actually carry.  Deterministic encode: the bytes equal
-        the engine's roundtrip encode of the same matrix."""
-        np_server = np.asarray(server, np.float32)
-        if self.eng._wire_is_identity():
-            return [np_server[s].tobytes()
-                    for s in range(np_server.shape[0])]
-        return [encode(np_server[s], self._dense)
-                for s in range(np_server.shape[0])]
-
     # -- one round -----------------------------------------------------------
 
     def _round(self, transport, state: EngineState, round_key, r: int
@@ -216,7 +203,7 @@ class TransportEngine:
         keys = np.asarray(jax.random.split(round_key, eng.n))[np_idx]
 
         with obs.span("broadcast_encode"):
-            rows = self._row_frames(state.server.slots)
+            rows = eng.row_frames(state.server.slots)
             d = strategy.vec_dim
 
         # cohort → worker blocks (position k in cohort order per rank)
@@ -285,62 +272,33 @@ class TransportEngine:
                         received[k] = True
                         recv_stale[k] = e.staleness
 
-        observed_summary = None
+        observed = entries = None
         if sync:
             # the sync barrier: an upload counts only if it arrived in
             # its own round (without faults this equals the scheduled
             # active & staleness==0 mask, which is the conformance pin)
             arrive = received & (recv_stale == 0)
-            dec_j = jnp.asarray(dec)
-            slots_j = jnp.asarray(slots)
-            if eng._assign is not None:
-                with obs.span("assign"):
-                    slots_j = eng.executor.assign(
-                        strategy, state.server, dec_j, slots_j,
-                        jnp.asarray(arrive))
-                    obs.fence(slots_j)
-            with obs.span("aggregate"):
-                agg, counts = eng.executor.masked_mean(
-                    strategy, dec_j, slots_j, jnp.asarray(arrive))
-                obs.fence(agg, counts)
-            with obs.span("server_update"):
-                server = eng._server_update(state.server, agg, counts)
-                obs.fence(server)
-            n_agg = int((np.asarray(slots_j)[arrive] >= 0).sum())
-            buf = eng._buf_of(state)
-            n_buf = n_evict = 0
-            recv_mask = arrive
         else:
-            with obs.span("aggregate"):
-                server, counts, n_agg, n_buf, n_evict, buf = \
-                    self._buffer_arrivals(state, arrivals, r)
-                obs.fence(server, counts)
-            slots_j = jnp.asarray(slots)
-            # every active client trained and applies the broadcast,
-            # matching the engine's async recv = active
-            recv_mask = active
-            lags = [lag for _, _, _, lag in arrivals]
-            observed_summary = arrival_participation(
-                [g for g, _, _, _ in arrivals], lags).summary()
+            # whatever arrived is mature now, weighted by its observed
+            # lag; every active client trained and applies the
+            # broadcast, matching the engine's async receivers
+            arrive = active
+            entries = [(vec, s, r, cfg.staleness_discount ** int(lag))
+                       for _, s, vec, lag in arrivals]
+            observed = arrival_participation(
+                [g for g, _, _, _ in arrivals],
+                [lag for _, _, _, lag in arrivals]).summary()
+        served = eng.serve_uploads(state, part, jnp.asarray(dec),
+                                   jnp.asarray(slots), arrive, r,
+                                   entries=entries)
 
-        recv = jnp.asarray(recv_mask)
-        with obs.span("downlink"):
-            applied = executors.applied_slots(slots_j, counts, recv)
-            rx_server, down_bc, down_pc = eng._wire_downlink(
-                server.slots, counts, recv_mask, applied)
-            obs.fence(rx_server)
-            down_rows = self._row_frames(server.slots)
-        with obs.span("ref_track"):
-            refs = eng._update_refs(state, part, recv_mask, applied,
-                                    rx_server, r)
-            obs.fence(refs)
-
-        np_applied = np.asarray(applied)
+        np_applied = np.asarray(served.applied)
         with obs.span("wire_tx"):
+            down_rows = eng.row_frames(served.server.slots)
             for rank in transport.ranks:
                 clients = tuple(
                     DownClient(gidx=int(np_idx[k]),
-                               arrive=bool(recv_mask[k]),
+                               arrive=bool(arrive[k]),
                                applied=tuple(int(s)
                                              for s in np_applied[k]))
                     for k in by_rank[rank])
@@ -360,61 +318,12 @@ class TransportEngine:
             acc = jnp.asarray(np.concatenate(accs))
             obs.fence(acc)
 
-        if eng._identity:
-            assignment = applied
-        else:
-            assignment = jnp.full((eng.n, strategy.j_slots), -1,
-                                  jnp.int32).at[jnp.asarray(np_idx)].set(
-                applied)
-
-        new_state = EngineState(
-            round_idx=state.round_idx + 1,
-            client_state=state.client_state,   # worker-owned; see run()
-            server=server,
-            buf_vecs=buf[0], buf_slots=buf[1], buf_ready=buf[2],
-            buf_weight=buf[3], buf_valid=buf[4], buf_seq=buf[5],
-            ref_vecs=refs[0], ref_round=refs[1],
-            ef_residual=state.ef_residual)
-        rep = RoundReport(
-            round_idx=r, mean_accuracy=acc.mean(),
-            per_client_accuracy=acc, assignment=assignment,
-            cluster_counts=counts, participation=part,
-            upload_bytes=up_bytes, download_bytes_broadcast=down_bc,
-            download_bytes_per_client=down_pc, aggregated_uploads=n_agg,
-            buffered_uploads=n_buf, evicted_uploads=n_evict,
+        # the client rows and error-feedback residuals are worker-owned;
+        # see run()
+        new_state = eng.next_state(state, served, state.client_state,
+                                   state.ef_residual)
+        return new_state, eng.report(
+            r, part, served, up_bytes, acc,
+            eng.rows.assignment(part.idx, served.applied),
             wire_tx_bytes=wire_tx, wire_rx_bytes=wire_rx,
-            observed_staleness=observed_summary)
-        return new_state, rep
-
-    def _buffer_arrivals(self, state: EngineState, arrivals, r: int):
-        """Arrival-driven async aggregation: insert whatever actually
-        landed this round into the host buffer — mature immediately,
-        weighted by the *observed* lag — then run the engine's shared
-        fold (maturity gate, assign-at-aggregation, server_update)."""
-        cfg = self.eng.cfg
-        vecs = np.asarray(state.buf_vecs).copy()
-        bslots = np.asarray(state.buf_slots).copy()
-        ready = np.asarray(state.buf_ready).copy()
-        weight = np.asarray(state.buf_weight).copy()
-        valid = np.asarray(state.buf_valid).copy()
-        seq = np.asarray(state.buf_seq).copy()
-        evicted = 0
-        next_seq = int(seq[valid].max()) + 1 if valid.any() else 0
-        for _, slot, vec, lag in arrivals:
-            free = np.nonzero(~valid)[0]
-            if free.size:
-                i = free[0]
-            else:            # overflow: evict the oldest insertion
-                occupied = np.where(valid, seq, np.iinfo(np.int32).max)
-                i = int(np.argmin(occupied))
-                evicted += 1
-            vecs[i] = vec
-            bslots[i] = slot
-            ready[i] = r                       # it arrived: mature now
-            weight[i] = cfg.staleness_discount ** int(lag)
-            valid[i] = True
-            seq[i] = next_seq
-            next_seq += 1
-        server, counts, n_agg, n_buf, buf = self.eng._fold_host_buffer(
-            state, vecs, bslots, ready, weight, valid, seq, r)
-        return server, counts, n_agg, n_buf, evicted, buf
+            observed_staleness=observed)

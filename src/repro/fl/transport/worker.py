@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.fl.runtime.codec import CodecConfig, decode, ef_encode, encode
 from repro.fl.runtime.engine import Engine, RuntimeConfig
+from repro.fl.runtime.rows import advance_ref_rows
 from repro.fl.runtime.executors import InProcessExecutor
 from repro.fl.runtime.scheduler import SchedulerConfig
 from repro.fl.transport import framing
@@ -192,13 +193,9 @@ class ClientWorker:
             lambda a, s: a.at[jloc].set(s), self.block_cs, merged)
         if self.cfg.codec.sparse:
             local = np.asarray(jloc)
-            sub = self.ref_vecs[local].copy()
-            sub_rounds = self.ref_round[local].copy()
-            Engine._advance_ref_rows(sub, sub_rounds, arrive, applied,
-                                     np.asarray(rx_server), r,
-                                     self.strategy.downloads)
-            self.ref_vecs[local] = sub
-            self.ref_round[local] = sub_rounds
+            self.ref_vecs[local], self.ref_round[local] = advance_ref_rows(
+                self.ref_vecs[local], self.ref_round[local], arrive,
+                applied, np.asarray(rx_server), r, self.strategy.downloads)
         acc = self.executor.evaluate(
             self.strategy, self.block_cs,
             self.block_data.x_test, self.block_data.y_test)

@@ -568,7 +568,7 @@ def test_flis_prev_slot_follows_applied_assignment(data):
         sub_cs = jax.tree.map(lambda a: a[part.idx], prev.client_state)
         sub_data = jax.tree.map(lambda a: a[part.idx], data)
         _, _, slots = engine.executor.train(
-            strat, sub_cs, engine._wire_tx_server(prev.server.slots),
+            strat, sub_cs, engine.wire_tx_server(prev.server.slots),
             sub_data, keys)
         assert (np.asarray(slots)[:, 0]
                 == np.asarray(prev.client_state.prev_slot)[idx]).all()
@@ -601,7 +601,7 @@ def test_flis_sparse_uplink_encodes_against_prev_slot_reference(data):
         sub_cs = jax.tree.map(lambda a: a[part.idx], prev.client_state)
         sub_data = jax.tree.map(lambda a: a[part.idx], data)
         _, vecs, slots = engine.executor.train(
-            strat, sub_cs, engine._wire_tx_server(prev.server.slots),
+            strat, sub_cs, engine.wire_tx_server(prev.server.slots),
             sub_data, keys)
         np_vecs, np_slots = np.asarray(vecs), np.asarray(slots)
         expect = 0
@@ -800,7 +800,7 @@ def test_engine_metered_bytes_equal_reencoded_buffer_lengths(data):
         keys = jax.random.split(jax.random.PRNGKey(1), N_CLIENTS)
         _, vecs, slots = engine.executor.train(
             strat, state.client_state, state.server.slots, data, keys)
-        _, up_bytes, _ = engine._wire_uplink(state, vecs, slots, part)
+        _, up_bytes, _ = engine.wire_uplink(state, vecs, slots, part)
         expect = 0
         np_vecs, np_slots = np.asarray(vecs), np.asarray(slots)
         for c in range(N_CLIENTS):
@@ -870,7 +870,7 @@ def test_sparse_uplink_encodes_against_tracked_reference(data):
         sub_cs = jax.tree.map(lambda a: a[part.idx], prev.client_state)
         sub_data = jax.tree.map(lambda a: a[part.idx], data)
         _, vecs, slots = engine.executor.train(
-            strat, sub_cs, engine._wire_tx_server(prev.server.slots),
+            strat, sub_cs, engine.wire_tx_server(prev.server.slots),
             sub_data, keys)
         np_vecs, np_slots = np.asarray(vecs), np.asarray(slots)
         expect = 0

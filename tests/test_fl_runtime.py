@@ -309,6 +309,45 @@ def test_async_entries_can_outlive_max_staleness_ungated():
     assert (weight <= 1.0).all()                # never from buffer age
 
 
+@pytest.mark.parametrize("form", ["scheduled", "arrival"])
+def test_host_buffer_insert_takes_free_slots_then_evicts_oldest(form):
+    """The one host-buffer insert both async host paths use: free lanes
+    are taken first, in order; a full buffer evicts the oldest
+    *insertion* (not the oldest lane) and counts it; each entry keeps
+    its own ``ready`` and ``weight``.  ``scheduled`` entries are the
+    engine's injected schedule (mature at ``r + staleness``, weight
+    ``discount**staleness``); ``arrival`` entries are the transport
+    runner's (mature on arrival, weighted by the observed lag)."""
+    from repro.fl.runtime.engine import host_buffer_insert
+    cap, d, r, disc = 3, 2, 5, 0.5
+    # lane 1 already holds an older upload (insertion seq 7)
+    buf = (jnp.zeros((cap, d), jnp.float32).at[1].set(9.0),
+           jnp.asarray([-1, 9, -1], jnp.int32),
+           jnp.asarray([0, 4, 0], jnp.int32),
+           jnp.asarray([0.0, 1.0, 0.0], jnp.float32),
+           jnp.asarray([False, True, False]),
+           jnp.asarray([0, 7, 0], jnp.int32))
+    lags = [0, 1, 2, 1]
+    entries = [(np.full((d,), float(i), np.float32), i,
+                r + lag if form == "scheduled" else r, disc ** lag)
+               for i, lag in enumerate(lags)]
+    (vecs, slots, ready, weight, valid, seq), evicted = \
+        host_buffer_insert(buf, entries)
+    # entry 0 → free lane 0, entry 1 → free lane 2; then the buffer is
+    # full: entry 2 evicts lane 1 (seq 7), entry 3 evicts lane 0 (seq 8)
+    lane_of = {3: 0, 2: 1, 1: 2}
+    assert evicted == 2
+    assert valid.all()
+    assert seq.tolist() == [11, 10, 9]
+    for i, lane in lane_of.items():
+        assert slots[lane] == i
+        assert (vecs[lane] == float(i)).all()
+        assert ready[lane] == (r + lags[i] if form == "scheduled" else r)
+        assert weight[lane] == np.float32(disc ** lags[i])
+    # the carried buffer is copied, never written in place
+    assert np.asarray(buf[4]).tolist() == [False, True, False]
+
+
 def test_client_step_consumes_codec_roundtripped_broadcast():
     """ROADMAP fix: local training must start from the broadcast rows a
     client would actually hold after a lossy downlink, not the
@@ -344,7 +383,7 @@ def test_wire_tx_server_is_identity_for_dense_float32():
     data = _data(n_clients=4)
     eng = _tpfl_engine(data, RuntimeConfig(rounds=1))
     server = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
-    assert eng._wire_tx_server(server) is server
+    assert eng.wire_tx_server(server) is server
 
 
 def test_engine_run_rounds_override_completes_remainder():

@@ -22,7 +22,7 @@ from repro.fl import masked_collectives, obs
 from repro.fl.obs import events as ev
 from repro.fl.obs.summarize import main as obs_cli_main
 from repro.fl.obs.tracer import NullTracer, PhaseTracer
-from repro.fl.runtime import (Engine, RuntimeConfig, Scheduler,
+from repro.fl.runtime import (CodecConfig, Engine, RuntimeConfig, Scheduler,
                               SchedulerConfig, TPFLStrategy, checkpointing)
 
 TM_CFG = tm.TMConfig(n_classes=10, n_clauses=20, n_features=100,
@@ -459,3 +459,73 @@ def test_fed_train_telemetry_dir_end_to_end(tmp_path):
     buf = io.StringIO()
     obs.summarize(run_dir, out=buf)
     assert "rounds: 2" in buf.getvalue()
+
+
+# ---------------------------------------------------------------------------
+# the round's span sequence, pinned per round shape
+# ---------------------------------------------------------------------------
+
+class _SpanLog(PhaseTracer):
+    """A tracer that also logs every span it opens, indented by its
+    nesting depth, and every discard as ``-name``."""
+
+    def __init__(self):
+        super().__init__()
+        self.log = []
+
+    def span(self, name):
+        self.log.append("  " * len(self._stack) + name)
+        return super().span(name)
+
+    def discard(self, name):
+        self.log.append("-" + name)
+        super().discard(name)
+
+    def on_round(self, report):
+        pass
+
+
+_STAGED = ["round", "  schedule", "  gather", "  broadcast_encode",
+           "  client_step", "  uplink_codec", "  aggregate",
+           "  server_update", "  downlink", "  apply_merge", "  ref_track"]
+SPAN_SEQUENCES = {
+    "staged-resident": _STAGED + ["  eval"],
+    "staged-identity": _STAGED[:3] + ["  fused_round", "-fused_round"]
+    + _STAGED[3:] + ["  eval"],
+    "staged-mmap": _STAGED + ["  spill", "  eval"],
+    "fused-shardmap": ["round", "  schedule", "  gather", "  fused_round",
+                       "  downlink", "  eval"],
+    "loopback-sync": ["round", "  schedule", "  broadcast_encode",
+                      "  wire_tx", "  wire_rx", "  uplink_codec",
+                      "  aggregate", "  server_update", "  downlink",
+                      "  ref_track", "  wire_tx", "  eval", "    wire_rx"],
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SPAN_SEQUENCES))
+def test_round_span_sequence_is_pinned(shape, data, tmp_path):
+    """One round under each of the round's shapes opens exactly these
+    spans, in this order and nesting: the stage names the benchmark and
+    the profiler-trace reader key on."""
+    from repro.fl.transport import TransportEngine
+    from repro.launch.mesh import make_clients_mesh
+    log = _SpanLog()
+    strat = TPFLStrategy(TM_CFG, local_epochs=1)
+    if shape == "loopback-sync":
+        cfg = RuntimeConfig(rounds=1, transport="loopback", workers=2)
+        TransportEngine(strat, data, cfg, telemetry=log).run(
+            jax.random.PRNGKey(0))
+    else:
+        kw = {"staged-resident": dict(scheduler=SchedulerConfig(
+                  participation=0.5)),
+              "staged-mmap": dict(
+                  client_store="mmap", store_dir=str(tmp_path / "store"),
+                  codec=CodecConfig("int8", sparse=True),
+                  scheduler=SchedulerConfig(participation=0.5)),
+              "staged-identity": {},
+              "fused-shardmap": dict(backend="shardmap")}[shape]
+        mesh = make_clients_mesh() if shape == "fused-shardmap" else None
+        Engine(strat, data, RuntimeConfig(rounds=1, **kw), mesh=mesh,
+               telemetry=log).run(jax.random.PRNGKey(0))
+    log.close()
+    assert log.log == SPAN_SEQUENCES[shape]
