@@ -236,13 +236,12 @@ def _feedback_one_class(ta: jnp.ndarray, w: jnp.ndarray, lits: jnp.ndarray,
     return ta, w
 
 
-def _train_one_sample(params: TMParams, x: jnp.ndarray, y: jnp.ndarray,
+def _train_one_sample(params: TMParams, lits: jnp.ndarray, y: jnp.ndarray,
                       key: jax.Array, cfg: TMConfig) -> TMParams:
-    lits = literals(x[None])                 # (1, 2o)
-    cl = clause_outputs(params, lits, cfg)   # (1, C, m)
-    votes = class_votes(params, cl, cfg)     # (1, C)
+    """One sample's feedback, from its literal row ``lits`` (2o,)."""
+    cl = clause_outputs(params, lits[None], cfg)   # (1, C, m)
+    votes = class_votes(params, cl, cfg)           # (1, C)
     cl, votes = cl[0], votes[0]
-    lits = lits[0]
 
     k_neg, k_t, k_n = jax.random.split(key, 3)
     # sample a negative class uniformly from the other C-1 classes
@@ -284,22 +283,23 @@ def train_epoch(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
         from repro.kernels import draws as kdraws
         from repro.kernels import ops as kops
         p_inc, p_dec = _feedback_probs(cfg)
-        offs, act, coin_keys = kdraws.epoch_draws(
+        offs, act, coin_keys, order = kdraws.epoch_draws(
             key, xs.shape[0], cfg.n_clauses, cfg.n_classes)
         ys32 = ys.astype(jnp.int32)
         cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes], axis=-1)
-        ta, w = kops.train_epoch_fused(
+        ta, w, _ = kops.train_epoch_fused(
             params.ta_state[None], params.weights[None],
             literals(xs)[None], cls2[None], act[None], coin_keys[None],
-            n_states=cfg.n_states, T=cfg.T, p_inc=p_inc, p_dec=p_dec)
+            order[None], n_states=cfg.n_states, T=cfg.T, p_inc=p_inc,
+            p_dec=p_dec)
         return TMParams(ta_state=ta[0], weights=w[0])
 
     def step(p, inp):
-        x, y, k = inp
-        return _train_one_sample(p, x, y, k, cfg), None
+        lits, y, k = inp
+        return _train_one_sample(p, lits, y, k, cfg), None
 
     keys = jax.random.split(key, xs.shape[0])
-    params, _ = jax.lax.scan(step, params, (xs, ys, keys))
+    params, _ = jax.lax.scan(step, params, (literals(xs), ys, keys))
     return params
 
 
@@ -321,15 +321,25 @@ def train(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
 # is the fast shape (vmap of a pallas_call serializes clients via grid
 # batching).  Outputs are bit-identical either way.
 
+def type1_rows(cfg: TMConfig, n_samples: int, epochs: int) -> int:
+    """The Type-I rows one client's training could hash: each sample
+    gives each of its two roles ⌈m/2⌉ Type-I rows."""
+    return epochs * n_samples * 2 * ((cfg.n_clauses + 1) // 2)
+
+
 @partial(jax.jit, static_argnames=("cfg", "epochs"))
 def train_batched(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
-                  keys: jnp.ndarray, cfg: TMConfig,
-                  epochs: int = 1) -> TMParams:
-    """params stacked (N, ...); xs (N,S,o); ys (N,S); keys (N,2)."""
+                  keys: jnp.ndarray, cfg: TMConfig, epochs: int = 1
+                  ) -> tuple[TMParams, jnp.ndarray | None]:
+    """params stacked (N, ...); xs (N,S,o); ys (N,S); keys (N,2).
+
+    Returns the trained params and, on the kernel path, the (N,) int32
+    Type-I rows each client's epochs hashed (of :func:`type1_rows`);
+    ``None`` on the reference path, which draws every coin."""
     if not (cfg.use_kernel and cfg.weighted):
         return jax.vmap(
             lambda p, x, y, k: train(p, x, y, k, cfg, epochs)
-        )(params, xs, ys, keys)
+        )(params, xs, ys, keys), None
 
     from repro.kernels import draws as kdraws
     from repro.kernels import ops as kops
@@ -342,23 +352,26 @@ def train_batched(params: TMParams, xs: jnp.ndarray, ys: jnp.ndarray,
         jax.vmap(lambda k: jax.random.split(k, epochs))(keys), 0, 1)
 
     def epoch_body(carry, ek):
-        ta, w = carry
-        # the scope names the epoch's draws and coin keys in the device
-        # trace (op metadata only: the compiled program is the same)
+        ta, w, hashed = carry
+        # the scope names the epoch's draws, coin keys and row orders in
+        # the device trace (op metadata only: the compiled program is
+        # the same)
         with jax.named_scope("tm.draws"):
-            offs, act, coin_keys = jax.vmap(
+            offs, act, coin_keys, order = jax.vmap(
                 lambda k: kdraws.epoch_draws(k, n_samples, cfg.n_clauses,
                                              cfg.n_classes))(ek)
             cls2 = jnp.stack([ys32, (ys32 + offs) % cfg.n_classes],
                              axis=-1)
-        ta, w = kops.train_epoch_fused(ta, w, lits, cls2, act, coin_keys,
-                                       n_states=cfg.n_states, T=cfg.T,
-                                       p_inc=p_inc, p_dec=p_dec)
-        return (ta, w), None
+        ta, w, h = kops.train_epoch_fused(ta, w, lits, cls2, act,
+                                          coin_keys, order,
+                                          n_states=cfg.n_states, T=cfg.T,
+                                          p_inc=p_inc, p_dec=p_dec)
+        return (ta, w, hashed + h), None
 
-    (ta, w), _ = jax.lax.scan(epoch_body,
-                              (params.ta_state, params.weights), ekeys)
-    return TMParams(ta_state=ta, weights=w)
+    hashed = jnp.zeros(ys.shape[:1], jnp.int32)
+    (ta, w, hashed), _ = jax.lax.scan(
+        epoch_body, (params.ta_state, params.weights, hashed), ekeys)
+    return TMParams(ta_state=ta, weights=w), hashed
 
 
 @partial(jax.jit, static_argnames=("cfg", "weighted"))

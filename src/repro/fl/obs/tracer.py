@@ -19,6 +19,11 @@ A live span does three things:
   open span: a ``jax.monitoring`` listener adds each trace, lowering
   and backend-compile event to it (``"(none)"`` outside any span).
 
+Beside the spans, ``count(counters)`` charges the round the program's
+own counters, each ``{name: per-client values}`` read off the device as
+its mean over the clients (the TM kernel's ``tm.type1_hash_share``);
+the null tracer's ``count`` reads nothing.
+
 ``PhaseTracer(fence=True)`` (the default) makes ``fence(values)`` a
 ``jax.block_until_ready``, so a span's wall time covers the device
 work it launched, not just the Python dispatch.  ``fence=False`` keeps
@@ -45,6 +50,7 @@ import time
 import weakref
 
 import jax
+import numpy as np
 
 
 class _NullSpan:
@@ -76,6 +82,9 @@ class NullTracer:
     def discard(self, name: str):
         pass
 
+    def count(self, counters):
+        pass
+
     def take(self) -> dict:
         return {}
 
@@ -94,12 +103,15 @@ OUTSIDE = "(none)"      # where compiles outside any open span are charged
 class Spans(dict):
     """One round's ``{span: seconds}``, with the compiles charged to each
     span: ``compiles`` (backend compiles) and ``compile_s`` (trace +
-    lowering + backend-compile seconds), keyed by span or ``"(none)"``."""
+    lowering + backend-compile seconds), keyed by span or ``"(none)"``;
+    and ``counts``, the round's program counters (``{name: value}``)."""
 
-    def __init__(self, seconds: dict, compiles: dict, compile_s: dict):
+    def __init__(self, seconds: dict, compiles: dict, compile_s: dict,
+                 counts: dict | None = None):
         super().__init__(seconds)
         self.compiles = compiles
         self.compile_s = compile_s
+        self.counts = {} if counts is None else counts
 
 
 class _Span:
@@ -162,6 +174,7 @@ class PhaseTracer:
         self._stats: dict[str, int] = {}
         self._compiles: dict[str, int] = {}
         self._compile_s: dict[str, float] = {}
+        self._counts: dict[str, float] = {}
         listener = _compile_listener(weakref.ref(self))
         jax.monitoring.register_event_duration_secs_listener(listener)
         self._unregister = weakref.finalize(
@@ -199,9 +212,19 @@ class PhaseTracer:
         path") so events report only phases that really ran."""
         self._spans.pop(name, None)
 
+    def count(self, counters) -> None:
+        """Charge the round each ``{name: per-client values}`` counter's
+        mean over the clients (a device read: the null tracer's ``count``
+        reads nothing).  ``None`` is no counters."""
+        for name, values in (counters or {}).items():
+            value = float(np.mean(np.asarray(values)))
+            self._counts[name] = self._counts.get(name, 0.0) + value
+
     def take(self) -> Spans:
-        spans = Spans(self._spans, self._compiles, self._compile_s)
+        spans = Spans(self._spans, self._compiles, self._compile_s,
+                      self._counts)
         self._spans, self._compiles, self._compile_s = {}, {}, {}
+        self._counts = {}
         return spans
 
     def close(self) -> None:

@@ -251,6 +251,10 @@ class RoundReport(NamedTuple):
     # the uploads the transport server took in this round; None on the
     # in-process engine (staleness there is an injected schedule)
     observed_staleness: Any = None
+    # {counter: (n,) per-client value} the client step measured (the TM
+    # kernel path: ``tm.type1_hash_share``); None where it measures
+    # nothing.  Device arrays: only the telemetry plane reads them
+    client_stats: Any = None
 
 
 class Served(NamedTuple):
@@ -336,8 +340,8 @@ class Engine:
                     f"tm_backend='ref' or fewer clauses")
             logging.getLogger(__name__).info(
                 "tm_backend='pallas': Type-I coins hashed in the epoch "
-                "kernel, one threefry word an automaton a sample "
-                "(%s stream)", draws.threefry_stream())
+                "kernel, one threefry word an automaton of an active "
+                "Type-I row a sample (%s stream)", draws.threefry_stream())
             strategy = dataclasses.replace(
                 strategy, tm_cfg=dataclasses.replace(
                     strategy.tm_cfg, use_kernel=True))
@@ -553,7 +557,7 @@ class Engine:
         if fused is not None:
             # bytes are metered arithmetically (float32 frames are
             # bit-exact, len = 4 + 4·d — codec-pinned)
-            merged, server, counts, applied, acc, slots = fused
+            merged, server, counts, applied, acc, slots, stats = fused
             with obs.span("downlink"):
                 up_bytes = self._identity_upload_bytes(
                     np.asarray(slots), np.asarray(part.active))
@@ -571,9 +575,10 @@ class Engine:
                 tx_server = self.wire_tx_server(state.server.slots)
                 obs.fence(tx_server)
             with obs.span("client_step"):
-                new_sub, vecs, slots = self.executor.train(
+                new_sub, up = self.executor.client_step(
                     self.strategy, cohort.cs, tx_server, cohort.data,
                     cohort.keys)
+                vecs, slots, stats = up
                 obs.fence(new_sub, vecs, slots)
             # the wire: encode → meter → decode (sparse deltas against
             # each client's tracked broadcast reference)
@@ -589,10 +594,11 @@ class Engine:
         new_state, acc, assignment = self.rows.commit(state, cohort, served,
                                                       ef)
         io1 = self.rows.io()
+        obs.count(stats)          # the client step's counters, read if on
         return new_state, self.report(
             r, part, served, up_bytes, acc, assignment,
             store_read_bytes=io1[0] - io0[0],
-            store_written_bytes=io1[1] - io0[1])
+            store_written_bytes=io1[1] - io0[1], client_stats=stats)
 
     def serve_uploads(self, state: EngineState, part: Participation, dec,
                       slots, arrive, r: int, *, entries=None,

@@ -189,9 +189,14 @@ def evaluate_population(executor, strategy, gather_cs, gather_data,
 class InProcessExecutor:
     """Eager vmap backend — every round is host-orchestrated jax ops."""
 
+    def client_step(self, strategy, sub_cs, server, sub_data, keys):
+        """The cohort's client step: ``(new_sub, Upload)``, the upload's
+        ``stats`` the step's per-client counters."""
+        return _client_step_block(strategy)(sub_cs, server, sub_data, keys)
+
     def train(self, strategy, sub_cs, server, sub_data, keys):
-        new_sub, upload = _client_step_block(strategy)(
-            sub_cs, server, sub_data, keys)
+        new_sub, upload = self.client_step(strategy, sub_cs, server,
+                                           sub_data, keys)
         return new_sub, upload.vecs, upload.slots     # (K,j,d), (K,j)
 
     def assign(self, strategy, server, dec, slots, arrive):
@@ -301,7 +306,7 @@ def _sync_round_body(strategy, axis: str, collective: str,
                                         server2.slots, sub_cs, arrive)
         acc = _evaluate_block(strategy)(
             merged, sub_data.x_test, sub_data.y_test)
-        return merged, server2, counts, applied, acc, up.slots
+        return merged, server2, counts, applied, acc, up.slots, up.stats
 
     return body
 
@@ -311,7 +316,8 @@ def build_sharded_round(strategy, mesh, axis_name: str = "clients",
                         n_clients: int | None = None):
     """One full sync round as a single shard-mappable callable —
     ``(sub_cs, server_state, sub_data, keys, arrive) → (new_cs,
-    server_state, counts, applied, per_client_acc, slots)`` with clients
+    server_state, counts, applied, per_client_acc, slots, stats)`` (the
+    last the client step's per-client counters) with clients
     sharded over ``axis_name`` and the
     :class:`~repro.fl.runtime.strategy.ServerState` pytree replicated
     both ways (the strategy's ``server_update`` runs inside the
@@ -331,7 +337,8 @@ def build_sharded_round(strategy, mesh, axis_name: str = "clients",
     return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, P(), spec, spec, spec),
-        out_specs=(spec, P(), P(), spec, spec, spec), check_vma=False)
+        out_specs=(spec, P(), P(), spec, spec, spec, spec),
+        check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -429,7 +436,8 @@ def _fused_program(strategy, mesh, axis, collective, n_valid,
     body = _sync_round_body(strategy, axis, collective, n_valid)
     return jax.shard_map(body, mesh=mesh,
                          in_specs=(spec, P(), spec, spec, spec),
-                         out_specs=(spec, P(), P(), spec, spec, spec),
+                         out_specs=(spec, P(), P(), spec, spec, spec,
+                                    spec),
                          check_vma=False)(
         sub_cs, server, sub_data, keys, arrive)
 
@@ -642,15 +650,19 @@ class ShardMapExecutor:
 
         return jax.tree.map(put, tree)
 
-    def train(self, strategy, sub_cs, server, sub_data, keys):
+    def client_step(self, strategy, sub_cs, server, sub_data, keys):
         k = keys.shape[0]
         new_sub, upload = _train_program(
             strategy, self.mesh, self.axis,
             _pad_tree(sub_cs, self.n_shards), server,
             _pad_tree(sub_data, self.n_shards),
             _pad_rows(keys, self.n_shards))
-        new_sub = _unpad(new_sub, k)
-        return new_sub, upload.vecs[:k], upload.slots[:k]
+        return _unpad(new_sub, k), _unpad(upload, k)
+
+    def train(self, strategy, sub_cs, server, sub_data, keys):
+        new_sub, upload = self.client_step(strategy, sub_cs, server,
+                                           sub_data, keys)
+        return new_sub, upload.vecs, upload.slots
 
     def assign(self, strategy, server, dec, slots, arrive):
         """Shard-mapped server-side assignment: uploads sharded over
@@ -724,6 +736,6 @@ class ShardMapExecutor:
             _pad_tree(sub_data, self.n_shards),
             _pad_rows(keys, self.n_shards),
             _pad_rows(jnp.asarray(arrive), self.n_shards, fill=False))
-        merged, server2, counts, applied, acc, slots = out
+        merged, server2, counts, applied, acc, slots, stats = out
         return (_unpad(merged, k), server2, counts, applied[:k], acc[:k],
-                slots[:k])
+                slots[:k], _unpad(stats, k))

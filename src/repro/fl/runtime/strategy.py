@@ -107,6 +107,9 @@ DOWNLOADS = ("assigned", "all_slots")
 class Upload(NamedTuple):
     vecs: jnp.ndarray    # (j, d) float32 — what goes on the wire
     slots: jnp.ndarray   # (j,)   int32   — target server slot, −1 = none
+    # {counter: per-client value} the client step measured, for the
+    # telemetry plane; never on the wire.  None when it measures nothing
+    stats: Any = None
 
 
 class ServerState(NamedTuple):
@@ -158,6 +161,15 @@ class Strategy(Protocol):
     #          arrive (K,)) -> (K,j) int32
     #   server_update(server: ServerState, agg (C,d), counts (C,))
     #          -> ServerState
+
+
+def _hash_stats(hashed, cfg: tm.TMConfig, d: ClientData, epochs: int):
+    """The fused TM step's counter: the share of its Type-I rows each
+    client's epoch kernel hashed (``tm.type1_hash_share``)."""
+    if hashed is None:
+        return None
+    total = tm.type1_rows(cfg, d.y_train.shape[1], epochs)
+    return {"tm.type1_hash_share": hashed.astype(jnp.float32) / total}
 
 
 # ---------------------------------------------------------------------------
@@ -274,8 +286,8 @@ class TPFLStrategy:
                           d: ClientData, keys: jnp.ndarray):
         del slots
         cfg = self.tm_cfg
-        params = tm.train_batched(cs, d.x_train, d.y_train, keys, cfg,
-                                  epochs=self.local_epochs)
+        params, hashed = tm.train_batched(cs, d.x_train, d.y_train, keys,
+                                          cfg, epochs=self.local_epochs)
         conf = tm.confidence_scores_batched(
             params, d.x_conf, cfg, weighted=self.weighted_confidence)
         vals, c_top = jax.lax.top_k(conf, self.top_classes)     # (N, j)
@@ -284,7 +296,8 @@ class TPFLStrategy:
         rows = jnp.arange(c_top.shape[0])[:, None]
         vecs = params.weights[rows, jnp.clip(c_top, 0)].astype(jnp.float32)
         vecs = jnp.where((c_top >= 0)[..., None], vecs, 0.0)
-        return params, Upload(vecs, c_top.astype(jnp.int32))
+        return params, Upload(vecs, c_top.astype(jnp.int32),
+                              _hash_stats(hashed, cfg, d, self.local_epochs))
 
     def fused_evaluate(self, cs: tm.TMParams, x: jnp.ndarray,
                        y: jnp.ndarray) -> jnp.ndarray:
@@ -766,11 +779,14 @@ class FedTMStrategy:
     def fused_client_step(self, cs: tm.TMParams, slots: jnp.ndarray,
                           d: ClientData, keys: jnp.ndarray):
         del slots
-        params = tm.train_batched(cs, d.x_train, d.y_train, keys,
-                                  self.tm_cfg, epochs=self.local_epochs)
+        params, hashed = tm.train_batched(cs, d.x_train, d.y_train, keys,
+                                          self.tm_cfg,
+                                          epochs=self.local_epochs)
         n = d.y_train.shape[0]
         vecs = params.weights.astype(jnp.float32).reshape(n, 1, -1)
-        return params, Upload(vecs, jnp.zeros((n, 1), jnp.int32))
+        return params, Upload(vecs, jnp.zeros((n, 1), jnp.int32),
+                              _hash_stats(hashed, self.tm_cfg, d,
+                                          self.local_epochs))
 
     def fused_evaluate(self, cs: tm.TMParams, x: jnp.ndarray,
                        y: jnp.ndarray) -> jnp.ndarray:

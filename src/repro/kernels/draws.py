@@ -14,8 +14,9 @@ choices *inside* the per-sample scan, with exactly this key discipline:
 :func:`epoch_draws` makes that discipline's small draws for a whole
 epoch outside the kernel — the negative-class offsets and the ``(2, m)``
 activation draws — and hands the kernel the *key words* of each
-sample's four coin keys in place of the coins themselves.  The kernel
-hashes the coins (:func:`coin_word`).
+sample's four coin keys in place of the coins themselves, with each
+role's Type-I rows sorted by activation draw.  The kernel hashes the
+coins (:func:`coin_word`) of the active rows alone.
 
 **One word an automaton, the reference's word.**  Under both threefry
 streams every word of ``bits(k, (m, L))`` is a function of its own
@@ -33,8 +34,9 @@ coin (``k_s1``) where its clause fired and its literal is true, the
 decrement coin (``k_s2``) everywhere else.  Type I goes to the even
 (positive-polarity) clauses on the target role and to the odd ones on
 the negative role, so row parity names the role, and the clause's
-``fired`` bit, known only inside the kernel, names the key.  So the
-kernel hashes one word an automaton a sample, with that key, and no
+``fired`` bit, known only inside the kernel, names the key.  A row
+whose clause is not activated reads neither coin.  So the kernel hashes
+one word an automaton of an active Type-I row, with that key, and no
 coin the feedback does not read is drawn.  :func:`threefry_stream`
 says which stream, from ``jax.config`` at trace time.
 
@@ -68,6 +70,8 @@ from jax.extend.random import threefry2x32_p
 
 # f32 uniforms carry exactly 23 mantissa bits: u = (bits >> 9) * 2^-23
 _MANTISSA = float(1 << 23)
+# a draw no threshold exceeds: every threshold is at most 2^23
+NEVER = 1 << 23
 
 
 def int_threshold(p: float) -> int:
@@ -134,16 +138,25 @@ def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
     """One epoch's draws outside the kernel, reference key discipline
     (see module doc).
 
-    Returns ``(offsets, act, coin_keys)``:
+    Returns ``(offsets, act, coin_keys, order)``:
 
     * ``offsets``   (S,) int32 — negative-class offset in [1, C);
     * ``act``       (S, 2, m) int32 — clause-activation draws as 23-bit
       integers (:func:`act_bits`), role 0 = target, 1 = negative;
     * ``coin_keys`` (S, 8) uint32 — ``key_data`` of the target's
       ``k_s1``, ``k_s2``, then the negative's ``k_s1``, ``k_s2``: the
-      keys the kernel hashes the Type-I coins from.
+      keys the kernel hashes the Type-I coins from;
+    * ``order``     (S, 2, ⌈m/2⌉) int32 — each role's Type-I rows (the
+      target's even rows, the negative's odd ones) ascending by their
+      activation draw.  A row is active iff its draw is under the
+      sample's threshold, so for every threshold the active rows are a
+      prefix of ``order``, however ties fall.  An odd ``m`` leaves the
+      negative one row short: its last slot holds row ``m``, past the
+      clauses, drawn :data:`NEVER`, so it sorts last and is never
+      active.
     """
     m = n_clauses
+    half = (m + 1) // 2
 
     def per_sample(k):
         k_neg, k_t, k_n = jax.random.split(k, 3)
@@ -153,6 +166,12 @@ def epoch_draws(key: jax.Array, n_samples: int, n_clauses: int,
         off = jax.random.randint(k_neg, (), 1, n_classes)
         words = jnp.concatenate([jax.random.key_data(c)
                                  for c in (k1_t, k2_t, k1_n, k2_n)])
-        return off.astype(jnp.int32), act, words
+        # role r's Type-I rows are 2i + r: row pairs, drawn by the role
+        pairs = jnp.pad(act, ((0, 0), (0, 2 * half - m)),
+                        constant_values=NEVER).reshape(2, half, 2)
+        by_role = jnp.stack([pairs[0, :, 0], pairs[1, :, 1]])
+        rank = jnp.argsort(by_role, axis=-1).astype(jnp.int32)
+        order = 2 * rank + jnp.arange(2, dtype=jnp.int32)[:, None]
+        return off.astype(jnp.int32), act, words, order
 
     return jax.vmap(per_sample)(jax.random.split(key, n_samples))

@@ -55,11 +55,12 @@ def fused_votes_batched(include: jnp.ndarray, lits: jnp.ndarray,
 
 def train_epoch_fused(ta: jnp.ndarray, w: jnp.ndarray, lits: jnp.ndarray,
                       cls2: jnp.ndarray, act: jnp.ndarray,
-                      coin_keys: jnp.ndarray, *, n_states: int, T: int,
-                      p_inc: float, p_dec: float
-                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One fused training epoch over stacked clients; see train_epoch.py."""
-    return _te.train_epoch_pallas(ta, w, lits, cls2, act, coin_keys,
+                      coin_keys: jnp.ndarray, order: jnp.ndarray, *,
+                      n_states: int, T: int, p_inc: float, p_dec: float
+                      ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One fused training epoch over stacked clients, and the Type-I rows
+    each client hashed; see train_epoch.py."""
+    return _te.train_epoch_pallas(ta, w, lits, cls2, act, coin_keys, order,
                                   n_states=n_states, T=T, p_inc=p_inc,
                                   p_dec=p_dec, interpret=interpret_mode())
 

@@ -352,7 +352,7 @@ def test_client_step_consumes_codec_roundtripped_broadcast():
     """ROADMAP fix: local training must start from the broadcast rows a
     client would actually hold after a lossy downlink, not the
     aggregator's full-precision state.  Spy on the server matrix the
-    engine hands the executor's train stage."""
+    engine hands the executor's client step."""
     from repro.fl.runtime import codec as codec_mod
     data = _data(n_clients=4)
     wire = CodecConfig("int8")
@@ -361,13 +361,13 @@ def test_client_step_consumes_codec_roundtripped_broadcast():
                  data, RuntimeConfig(rounds=1, codec=wire))
     state = eng.init(jax.random.PRNGKey(0))
     seen = {}
-    orig = eng.executor.train
+    orig = eng.executor.client_step
 
     def spy(strategy, cs, server, d, keys):
         seen["server"] = np.asarray(server)
         return orig(strategy, cs, server, d, keys)
 
-    eng.executor.train = spy
+    eng.executor.client_step = spy
     eng.run_round(state, jax.random.PRNGKey(1))
 
     full = np.asarray(state.server.slots, np.float32)

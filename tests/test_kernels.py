@@ -6,6 +6,7 @@ and both int/bool-ish dtype inputs.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels import clause_eval, draws, ops, ref, ta_update
@@ -136,7 +137,7 @@ def test_kernel_coin_word_is_the_reference_word(m, L, partitionable):
     with jax.threefry_partitionable(partitionable):
         assert draws.threefry_stream() == (
             "partitionable" if partitionable else "original")
-        offs, act, coin_keys = jax.jit(
+        offs, act, coin_keys, _ = jax.jit(
             lambda k: draws.epoch_draws(k, S, m, C))(key)
         assert coin_keys.shape == (S, 8) and coin_keys.dtype == jnp.uint32
         word = jax.jit(lambda k1, k2: draws.coin_word(k1, k2, j, m * L))
@@ -194,3 +195,30 @@ def test_clause_kernel_tile_invariance(bt, ct, lt):
     out = clause_eval.clause_outputs_pallas(include, lits, bt=bt, ct=ct,
                                             lt=lt, **INTERPRET)
     assert (base == out).all()
+
+
+@pytest.mark.parametrize("m", [6, 7, 300])
+def test_epoch_draws_order_sorts_each_roles_type1_rows(m):
+    """``order`` holds, for each sample and role, the role's real Type-I
+    rows (the target's even rows, the negative's odd ones) ascending by
+    activation draw; an odd ``m`` gives the negative's last slot to the
+    padded row ``m``, drawn past every threshold.  For every threshold,
+    ``act < thr`` picks exactly a prefix of ``order``."""
+    S, C = 4, 5
+    offs, act, coin_keys, order = jax.jit(
+        lambda k: draws.epoch_draws(k, S, m, C))(jax.random.PRNGKey(m))
+    half = (m + 1) // 2
+    assert order.shape == (S, 2, half) and order.dtype == jnp.int32
+    act, order = np.asarray(act), np.asarray(order)
+    thresholds = list(draws.activation_thresholds(40)) + [0, 1 << 23]
+    for i in range(S):
+        for role in (0, 1):
+            rows = np.arange(role, m, 2)
+            o = order[i, role]
+            assert sorted(o[:rows.size]) == list(rows)
+            assert (o[rows.size:] == m).all()     # the odd m's pad slot
+            drawn = act[i, role, o[:rows.size]]
+            assert (np.diff(drawn) >= 0).all()
+            for thr in thresholds:
+                active = set(rows[act[i, role, rows] < thr])
+                assert set(o[:len(active)]) == active

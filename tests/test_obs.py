@@ -529,3 +529,81 @@ def test_round_span_sequence_is_pinned(shape, data, tmp_path):
                telemetry=log).run(jax.random.PRNGKey(0))
     log.close()
     assert log.log == SPAN_SEQUENCES[shape]
+
+
+# ---------------------------------------------------------------------------
+# program counters: the TM kernel's Type-I hash share
+# ---------------------------------------------------------------------------
+
+def test_phase_tracer_counts_client_means_per_round():
+    """``count`` charges each counter's mean over the clients, adds a
+    re-charged name within the round, and drains with the spans; the
+    null tracer's ``count`` reads nothing."""
+    tr = PhaseTracer()
+    tr.count({"c": np.array([0.25, 0.75])})
+    tr.count({"c": np.array([0.5]), "d": np.array([2.0, 4.0])})
+    tr.count(None)
+    spans = tr.take()
+    assert spans.counts == {"c": 1.0, "d": 3.0}
+    assert tr.take().counts == {}
+    NullTracer().count({"c": object()})                  # never read
+
+
+def _pallas_engine(data, telemetry, strategy_cls=TPFLStrategy, **kw):
+    cfg = dataclasses.replace(TM_CFG, use_kernel=True)
+    mesh = None
+    if kw.get("backend") == "shardmap":
+        from repro.launch.mesh import make_clients_mesh
+        mesh = make_clients_mesh()
+    return Engine(strategy_cls(cfg, local_epochs=1), data,
+                  RuntimeConfig(rounds=1, tm_backend="pallas", **kw),
+                  mesh=mesh, telemetry=telemetry)
+
+
+@pytest.mark.parametrize("backend", ["inprocess", "shardmap"])
+def test_type1_hash_share_is_counted_under_telemetry(backend, data):
+    """On the pallas path a round charges ``tm.type1_hash_share``: the
+    Type-I rows the epoch kernel hashed over the rows it could have
+    (K · E · S · 2 · ⌈m/2⌉), the mean of the report's per-client
+    shares — staged in-process and fused shard-mapped alike."""
+    rec = obs.RunRecorder()
+    engine = _pallas_engine(data, rec, backend=backend)
+    state = engine.init(jax.random.PRNGKey(0))
+    _, rep = engine.run_round(state, jax.random.PRNGKey(1))
+    share = np.asarray(rep.client_stats["tm.type1_hash_share"])
+    assert share.shape == (N_CLIENTS,)
+    assert ((share > 0) & (share <= 1)).all()
+    (name,) = rec.take().counts
+    assert name == "tm.type1_hash_share"
+    assert rec._counts == {}
+    rec.close()
+
+
+class _Unreadable:
+    """A counter value whose every read raises."""
+
+    def __array__(self, *a, **k):
+        raise AssertionError("a counter was read")
+
+    __float__ = __array__
+
+
+@dataclasses.dataclass(frozen=True)
+class _UnreadableStats(TPFLStrategy):
+    def fused_client_step(self, cs, slots, d, keys):
+        params, up = super().fused_client_step(cs, slots, d, keys)
+        return params, up._replace(
+            stats={"tm.type1_hash_share": _Unreadable()})
+
+
+def test_type1_hash_share_costs_no_read_with_telemetry_off(data):
+    """Telemetry off, the round hands the client step's counters to its
+    report unread (no device-to-host copy); on, it reads them."""
+    engine = _pallas_engine(data, None, _UnreadableStats)
+    state = engine.init(jax.random.PRNGKey(0))
+    _, rep = engine.run_round(state, jax.random.PRNGKey(1))
+    assert isinstance(rep.client_stats["tm.type1_hash_share"], _Unreadable)
+    engine.obs = rec = obs.RunRecorder()
+    with pytest.raises(AssertionError, match="a counter was read"):
+        engine.run_round(state, jax.random.PRNGKey(1))
+    rec.close()

@@ -163,10 +163,13 @@ def test_batched_entry_points_bit_identical_to_vmap(partitionable, seed=0):
     ys = jax.random.randint(ky, (N, S), 0, cfg.n_classes)
     keys = jax.random.split(kt, N)
     with jax.threefry_partitionable(partitionable):
-        pa = tm.train_batched(params, xs, ys, keys, cfg, epochs=2)
-        pb = tm.train_batched(params, xs, ys, keys, kcfg, epochs=2)
+        pa, none = tm.train_batched(params, xs, ys, keys, cfg, epochs=2)
+        pb, hashed = tm.train_batched(params, xs, ys, keys, kcfg, epochs=2)
     assert (pa.ta_state == pb.ta_state).all()
     assert (pa.weights == pb.weights).all()
+    # the kernel counts the Type-I rows it hashed; the reference, none
+    assert none is None and hashed.shape == (N,)
+    assert ((hashed > 0) & (hashed <= tm.type1_rows(cfg, S, 2))).all()
     xe = (jax.random.uniform(ke, (N, 9, cfg.n_features)) < 0.4).astype(
         jnp.int32)
     ye = jax.random.randint(jax.random.fold_in(ke, 1), (N, 9), 0,
@@ -252,3 +255,112 @@ def test_train_batched_names_draws_and_pads_and_keeps_its_program(
     without = bare.compile().as_text()
     assert "FileNames" not in _program(with_scopes)
     assert _program(with_scopes) == _program(without)
+
+
+# ---------------------------------------------------------------------------
+# the packed Type-I pass: only active rows hashed, bit for bit
+# ---------------------------------------------------------------------------
+
+def _ref_epoch(p, lits, ys, key, cfg):
+    """The reference scan over a literal plane, and per sample the active
+    Type-I rows its pre-sample votes give each role: the target's even
+    rows whose draw is under the (T − v) / 2T threshold, the negative's
+    odd rows under (T + v) / 2T."""
+    from repro.kernels import draws
+    S, m = ys.shape[0], cfg.n_clauses
+    _, act, _, _ = draws.epoch_draws(key, S, m, cfg.n_classes)
+    table = jnp.asarray(draws.activation_thresholds(cfg.T))
+    row = jnp.arange(m)
+
+    def step(p, inp):
+        lit, y, k, a = inp
+        cl = tm.clause_outputs(p, lit[None], cfg)
+        v = jnp.clip(tm.class_votes(p, cl, cfg)[0], -cfg.T, cfg.T)
+        k_neg = jax.random.split(k, 3)[0]
+        ybar = (y + jax.random.randint(k_neg, (), 1, cfg.n_classes)) \
+            % cfg.n_classes
+        n = (jnp.sum((a[0] < table[cfg.T - v[y]]) & (row % 2 == 0))
+             + jnp.sum((a[1] < table[cfg.T + v[ybar]]) & (row % 2 == 1)))
+        return tm._train_one_sample(p, lit, y, k, cfg), n
+
+    keys = jax.random.split(key, S)
+    return jax.lax.scan(step, p, (lits, ys, keys, act))
+
+
+def _kernel_epoch(p, lits, ys, key, cfg):
+    """``tm.train_epoch``'s kernel path over a literal plane (N = 1)."""
+    from repro.kernels import draws, ops
+    offs, act, coin_keys, order = draws.epoch_draws(
+        key, ys.shape[0], cfg.n_clauses, cfg.n_classes)
+    cls2 = jnp.stack([ys, (ys + offs) % cfg.n_classes], axis=-1)
+    p_inc, p_dec = tm._feedback_probs(cfg)
+    ta, w, hashed = ops.train_epoch_fused(
+        p.ta_state[None], p.weights[None], lits[None], cls2[None],
+        act[None], coin_keys[None], order[None], n_states=cfg.n_states,
+        T=cfg.T, p_inc=p_inc, p_dec=p_dec)
+    return tm.TMParams(ta[0], w[0]), hashed[0]
+
+
+def _saturated(p, ys, cfg, target_sign):
+    """Every clause empty (so every clause fires) and weights that pin
+    each vote to ±T: the label class's at ``target_sign``·T, every
+    other class's at the opposite.  With +1 no sample activates a row,
+    so nothing moves; with −1 the first sample activates every row."""
+    m = cfg.n_clauses
+    big = jnp.where(jnp.arange(m) % 2 == 0, 1, 0) * 4 * cfg.T
+    flip = jnp.where(jnp.arange(m) % 2 == 0, 0, 1) * 4 * cfg.T
+    label = ys[0]
+    w = jnp.where((jnp.arange(cfg.n_classes) == label)[:, None],
+                  big if target_sign > 0 else flip,
+                  flip if target_sign > 0 else big)
+    ta = jnp.full_like(p.ta_state, cfg.n_states)
+    return tm.TMParams(ta, w.astype(jnp.int32)), jnp.full_like(ys, label)
+
+
+PACKED_CASES = {
+    # (m, L, literal plane): m = 300 pads to 304 rows; m = 32 pads
+    # none; L = 131 is odd (a literal plane no o gives)
+    "m300-L130": (300, 130, "random"),
+    "m32-L131": (32, 131, "random"),
+    "m33-L64": (33, 64, "random"),
+    "no-active-row": (20, 64, "saturated-none"),
+    "every-row-active": (20, 64, "saturated-all"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_packed_type1_pass_matches_reference_scan(case):
+    """The epoch kernel hashes only each role's active Type-I rows,
+    gathered eight to a tile in activation-draw order, and still equals
+    the reference scan bit for bit (TA states and weights); the rows it
+    reports hashed are the active Type-I rows the reference's votes
+    give.  Cases: padded and unpadded clause counts, an odd literal
+    width, active counts that are not whole groups of eight, a sample
+    with no active Type-I row and one with every row active."""
+    m, L, kind = PACKED_CASES[case]
+    cfg = tm.TMConfig(n_classes=3, n_clauses=m, n_features=L // 2,
+                      n_states=63, s=3.0, T=15)
+    S = 6
+    key = jax.random.PRNGKey(len(case))
+    kp, kx, ky, kt = jax.random.split(key, 4)
+    p = tm.init_params(cfg, kp)
+    p = p._replace(ta_state=jax.random.randint(kp, (3, m, L), 1, 127))
+    lits = (jax.random.uniform(kx, (S, L)) < 0.5).astype(jnp.int32)
+    ys = jax.random.randint(ky, (S,), 0, cfg.n_classes)
+    if kind != "random":
+        p, ys = _saturated(p, ys, cfg, 1 if kind == "saturated-none" else -1)
+    want, per_sample = jax.jit(_ref_epoch, static_argnums=4)(
+        p, lits, ys, kt, cfg)
+    got, hashed = jax.jit(_kernel_epoch, static_argnums=4)(
+        p, lits, ys, kt, cfg)
+    assert (got.ta_state == want.ta_state).all()
+    assert (got.weights == want.weights).all()
+    assert int(hashed) == int(per_sample.sum())
+    if kind == "saturated-none":
+        assert int(hashed) == 0
+        assert (got.ta_state == p.ta_state).all()
+    elif kind == "saturated-all":
+        assert int(per_sample[0]) == m            # ⌈m/2⌉ + ⌊m/2⌋ rows
+    else:
+        assert (per_sample % 8 != 0).any()        # a part-filled group
+        assert 0 < int(hashed) < S * m

@@ -73,9 +73,11 @@ def test_fused_votes_compiles(one_chip, n):
 
 
 def test_train_epoch_compiles_within_vmem(one_chip):
-    """No coin plane goes in: each sample's eight coin key words do, and
-    the kernel hashes the Type-I coins (threefry lowered by Mosaic)
-    within the VMEM ``vmem_bytes`` asks for."""
+    """No coin plane goes in: each sample's eight coin key words and its
+    two roles' row orders do, and the kernel hashes the active Type-I
+    rows' coins (threefry lowered by Mosaic; the active count a scalar,
+    the rows gathered one at a time) within the VMEM ``vmem_bytes`` asks
+    for."""
     n, s = 1, 8
     assert train_epoch.vmem_bytes(C, M, L) <= train_epoch.VMEM_BUDGET
     _compile(lambda *a: train_epoch.train_epoch_pallas(
@@ -83,7 +85,22 @@ def test_train_epoch_compiles_within_vmem(one_chip):
         one_chip,
         ((n, C, M, L), jnp.int32), ((n, C, M), jnp.int32),
         ((n, s, L), jnp.int32), ((n, s, 2), jnp.int32),
-        ((n, s, 2, M), jnp.int32), ((n, s, 8), jnp.uint32))
+        ((n, s, 2, M), jnp.int32), ((n, s, 8), jnp.uint32),
+        ((n, s, 2, (M + 1) // 2), jnp.int32))
+
+
+def test_train_epoch_compiles_for_a_round_of_clients(one_chip):
+    """A round's launch, 40 clients over 8 samples: every per-client
+    block — the hashed-row count among them — tiles a client axis that
+    is not 1."""
+    n, s = 40, 8
+    _compile(lambda *a: train_epoch.train_epoch_pallas(
+        *a, n_states=127, T=1000, p_inc=0.9, p_dec=0.1, interpret=False),
+        one_chip,
+        ((n, C, M, L), jnp.int32), ((n, C, M), jnp.int32),
+        ((n, s, L), jnp.int32), ((n, s, 2), jnp.int32),
+        ((n, s, 2, M), jnp.int32), ((n, s, 8), jnp.uint32),
+        ((n, s, 2, (M + 1) // 2), jnp.int32))
 
 
 def test_train_batched_scopes_leave_the_chip_program_unchanged(
